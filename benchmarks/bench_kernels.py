@@ -15,7 +15,8 @@ from repro.core.deform import deformable_conv2d, init_deformable_conv
 from repro.kernels import ref
 from repro.kernels.dcn_bli import bli_gather_reference, bli_tile_matmul
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ops import coords_to_idx_coeff, deformable_conv2d_pallas
+from repro.kernels.ops import (coords_to_idx_coeff, deformable_conv2d_pallas,
+                               resolve_interpret)
 from repro.obs import Stopwatch
 
 
@@ -45,13 +46,15 @@ def run(csv=print):
     t_gather = _time(gather, x_tile, idx, coeff)
     csv(f"kernel,bli_gather_xla,{t_gather:.0f},us_per_tile_allclose_ok")
 
+    interp = resolve_interpret(None)
     t_matmul = _time(lambda x, i, cf: bli_tile_matmul(x, i, cf,
-                                                      interpret=True),
+                                                      interpret=interp),
                      x_tile, idx, coeff)
-    out = bli_tile_matmul(x_tile, idx, coeff, interpret=True)
+    out = bli_tile_matmul(x_tile, idx, coeff, interpret=interp)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
-    csv(f"kernel,bli_matmul_pallas_interpret,{t_matmul:.0f},"
-        "us_per_tile_allclose_ok(interpret-mode timing, structural only)")
+    mode = "interpret" if interp else jax.default_backend()
+    csv(f"kernel,bli_matmul_pallas_{mode},{t_matmul:.0f},"
+        f"us_per_tile_allclose_ok(device={mode})")
 
     # --- full deformable conv: XLA vs fused-Pallas paths
     params = init_deformable_conv(jax.random.fold_in(key, 2), 64, 64)
@@ -69,13 +72,14 @@ def run(csv=print):
     q = jax.random.normal(ks[0], (1, 256, 8, 64))
     k = jax.random.normal(ks[1], (1, 256, 2, 64))
     v = jax.random.normal(ks[2], (1, 256, 2, 64))
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v, interpret=interp)
     np.testing.assert_allclose(out, ref.attention_ref(q, k, v),
                                rtol=2e-4, atol=2e-4)
     t_ref = _time(jax.jit(lambda q, k, v: ref.attention_ref(q, k, v)),
                   q, k, v)
     csv(f"kernel,attention_xla_ref,{t_ref:.0f},us_allclose_ok")
-    csv("kernel,flash_attention_pallas,validated,interpret=True vs oracle")
+    csv(f"kernel,flash_attention_pallas,validated,interpret={interp} "
+        "vs oracle")
 
 
 if __name__ == "__main__":

@@ -253,6 +253,9 @@ def run_scaleout(csv=print, device_counts=(1, 2, 4), n_requests=12,
         env = dict(os.environ)
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                             f"{d}")
+        # The parent has imported jax: a child that reached for a chip
+        # would contend with it, and forced devices are CPU devices.
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(root, "src"), root])
         proc = subprocess.run(

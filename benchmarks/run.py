@@ -15,6 +15,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (bench_access_pattern, bench_fusion,
@@ -45,4 +47,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -4,7 +4,7 @@
 
 1. builds a deformable conv (Eq. 1-3) and runs the XLA reference path,
 2. runs the SAME layer through the fused Pallas kernel (BLI-as-matmul on
-   the MXU, interpret=True on CPU) and checks they agree,
+   the MXU; interpret mode on CPU) and checks they agree,
 3. builds the Tile Dependency Table from the layer's real offsets, runs
    Algorithm 1, and prints the DRAM-traffic win over the naive order,
 4. runs a small DCN network through the network-graph executor
@@ -22,6 +22,7 @@ from repro.core import (deformable_conv2d, init_deformable_conv,
                         simulate_strategies, tdt_from_coords)
 from repro.core.deform import conv2d, offsets_to_coords
 from repro.kernels.ops import deformable_conv2d_pallas
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dcn_models import DcnNetConfig, dcn_net_apply, init_dcn_net
 from repro.runtime import GraphConfig, build_graph, run_graph
 from repro.runtime.fused_exec import network_sim_specs
@@ -87,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

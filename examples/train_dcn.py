@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro import checkpoint as ckpt
 from repro.data import DataConfig, image_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dcn_models import DcnNetConfig, dcn_net_apply, init_dcn_net
 from repro.optim import AdamWConfig, adamw_update, init_opt_state
 
@@ -73,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

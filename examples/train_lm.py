@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.configs.base import ShapeCell
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import train_loop
 from repro.optim import AdamWConfig
@@ -39,4 +40,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

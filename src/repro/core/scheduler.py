@@ -259,11 +259,11 @@ def schedule_tiles_device(B, buffer_tiles: int,
     import jax
 
     from repro.kernels.dcn_schedule import greedy_schedule_arrays
+    from repro.kernels.ops import resolve_interpret
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     oid_seq, klass, ovl = greedy_schedule_arrays(
-        jax.numpy.asarray(B), int(buffer_tiles), interpret=bool(interpret))
+        jax.numpy.asarray(B), int(buffer_tiles),
+        interpret=resolve_interpret(interpret))
     return assemble_device_schedule(np.asarray(oid_seq), np.asarray(klass),
                                     np.asarray(ovl))
 
@@ -380,14 +380,13 @@ def schedule_arrays_device(B, m: int, *, k_pad: int | None = None,
 
     from repro.kernels.dcn_schedule import (dispatch_arrays_from_klass,
                                             greedy_schedule_arrays)
+    from repro.kernels.ops import resolve_interpret
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     B = jax.numpy.asarray(B)
     n_in = B.shape[1]
     if k_pad is None:
         k_pad = pow2_pad(n_in)
     oid_seq, klass, ovl = greedy_schedule_arrays(
-        B, int(m), interpret=bool(interpret))
+        B, int(m), interpret=resolve_interpret(interpret))
     oid, dep_tbl, cnt = dispatch_arrays_from_klass(oid_seq, klass, k_pad)
     return DeviceSchedule(oid, dep_tbl, cnt, ovl.reshape(-1))

@@ -5,12 +5,16 @@ the paper's fusion keeps it on-chip. Here the fused kernel materializes the
 deformed patch matrix (bp, KK*C_in) **only in VMEM/VREGs** and immediately
 contracts it with the main-conv weights:
 
-    deformed (bp*KK, C)  = 4-hot(idx, coeff) (bp*KK, S) @ x_tile (S, C)
-    out      (bp, O)     = reshape(deformed, (bp, KK*C)) @ w (KK*C, O) + b
+    deformed (KK*bp, C)  = 4-hot(idx, coeff) (KK*bp, S) @ x_tile (S, C)
+    patches  (bp, KK*C)  = concat_t deformed[t*bp:(t+1)*bp]  (lane axis)
+    out      (bp, O)     = patches @ w (KK*C, O) + b
 
 Two chained MXU matmuls per block; HBM traffic is x_tile + indices +
 weights + out — the deformed intermediate never leaves the core. This is
-the TPU-native form of the paper's Fig. 18 fusion.
+the TPU-native form of the paper's Fig. 18 fusion. The deformed rows are
+tap-major, so the patch matrix is a lane concatenation of row slices:
+a ``(bp*KK, C) -> (bp, KK*C)`` reshape would move sublanes into lanes,
+which Mosaic cannot lower unless C is a multiple of 128.
 
 Two entry points: ``dcn_fused_tile`` computes ONE output tile per call
 (the per-tile dispatch loop), ``dcn_fused_schedule`` runs a whole
@@ -29,9 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.compat import shard_map
 from repro.obs import get_tracer
 
 
@@ -39,8 +42,8 @@ def _fused_kernel(idx_ref, coeff_ref, x_ref, w_ref, b_ref, o_ref,
                   *, s_pixels: int, kk: int):
     """One bp-pixel output block, full C_out.
 
-    idx_ref:   (bp*KK, 4) int32
-    coeff_ref: (bp*KK, 4) f32
+    idx_ref:   (KK*bp, 4) int32, tap-major (see :func:`_tap_major`)
+    coeff_ref: (KK*bp, 4) f32
     x_ref:     (S, C)
     w_ref:     (KK*C, O)
     b_ref:     (1, O)
@@ -48,8 +51,7 @@ def _fused_kernel(idx_ref, coeff_ref, x_ref, w_ref, b_ref, o_ref,
     """
     idx = idx_ref[...]
     coeff = coeff_ref[...].astype(jnp.float32)
-    rows = idx.shape[0]                      # bp * KK
-    bp = rows // kk
+    rows = idx.shape[0]                      # KK * bp
 
     cols = jax.lax.broadcasted_iota(jnp.int32, (rows, s_pixels), 1)
     w_bli = jnp.zeros((rows, s_pixels), jnp.float32)
@@ -59,10 +61,31 @@ def _fused_kernel(idx_ref, coeff_ref, x_ref, w_ref, b_ref, o_ref,
 
     x = x_ref[...].astype(jnp.float32)       # (S, C)
     deformed = jnp.dot(w_bli, x, preferred_element_type=jnp.float32)
-    patches = deformed.reshape(bp, kk * x.shape[1])
+    o_ref[...] = _contract_taps(deformed, w_ref, b_ref,
+                                kk).astype(o_ref.dtype)
+
+
+def _contract_taps(deformed, w_ref, b_ref, kk: int):
+    """Main conv over a tap-major deformed block of (KK*bp, C) rows, tap
+    ``t`` owning rows ``[t*bp, (t+1)*bp)``: their lane concatenation is
+    the (bp, KK*C) patch matrix, contracted with ``w`` (KK*C, O) in one
+    matmul (see the module docstring)."""
+    bp = deformed.shape[0] // kk
+    patches = jnp.concatenate(
+        [deformed[t * bp:(t + 1) * bp] for t in range(kk)], axis=1)
     w = w_ref[...].astype(jnp.float32)       # (KK*C, O)
     acc = jnp.dot(patches, w, preferred_element_type=jnp.float32)
-    o_ref[...] = (acc + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+    return acc + b_ref[...].astype(jnp.float32)
+
+
+def _tap_major(a: jax.Array, bp: int) -> jax.Array:
+    """(..., P, KK, 4) pixel-major operand -> (..., P*KK, 4) rows ordered
+    (pixel block, tap, pixel): each ``bp``-pixel block's ``KK*bp`` rows
+    are tap-major, as :func:`_contract_taps` reads them."""
+    *lead, p, kk, four = a.shape
+    a = a.reshape(*lead, p // bp, bp, kk, four)
+    a = jnp.swapaxes(a, -3, -2)
+    return a.reshape(*lead, p * kk, four)
 
 
 @functools.partial(jax.jit,
@@ -87,8 +110,8 @@ def _dcn_fused_tile_jit(
     if p % bp:
         raise ValueError(f"P={p} must tile by {bp}; pad upstream")
 
-    idx2 = idx.reshape(p * kk, 4)
-    coeff2 = coeff.reshape(p * kk, 4)
+    idx2 = _tap_major(idx, bp)
+    coeff2 = _tap_major(coeff, bp)
     w2 = w.reshape(kk * c, o)
     b2 = b.reshape(1, o)
 
@@ -123,13 +146,14 @@ def _sched_kernel(dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref, w_ref, b_ref,
                beyond it are padding and skip the matmul entirely (the x
                index map clamps to the last real dep, so consecutive
                padding slots keep the same block and the DMA is elided).
-    idx_ref:   (1, bp*KK, 4) int32 packed-buffer addresses of the tile
-    coeff_ref: (1, bp*KK, 4) f32
+    idx_ref:   (1, KK*bp, 4) int32 packed-buffer addresses of the tile,
+               tap-major (see :func:`_tap_major`)
+    coeff_ref: (1, KK*bp, 4) f32
     x_ref:     (1, tp, C) — input tile ``dep[t, k]``, DMA'd by the grid
     w_ref:     (KK*C, O)
     b_ref:     (1, O)
     o_ref:     (1, bp, O) — written on the last dep slot
-    acc_ref:   (bp*KK, C) f32 VMEM scratch — the deformed patch block
+    acc_ref:   (KK*bp, C) f32 VMEM scratch — the deformed patch block
 
     The BLI contraction is decomposed over dep slots: slot k owns packed
     addresses [k*tp, (k+1)*tp), so its partial 4-hot matmul sees only the
@@ -148,7 +172,7 @@ def _sched_kernel(dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref, w_ref, b_ref,
     def _accumulate():
         idx = idx_ref[0]
         coeff = coeff_ref[0].astype(jnp.float32)
-        rows = idx.shape[0]                  # bp * KK
+        rows = idx.shape[0]                  # KK * bp
         local = idx - k * tp                 # in [0, tp) iff owned by slot k
         cols = jax.lax.broadcasted_iota(jnp.int32, (rows, tp), 1)
         w_bli = jnp.zeros((rows, tp), jnp.float32)
@@ -161,12 +185,8 @@ def _sched_kernel(dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref, w_ref, b_ref,
 
     @pl.when(k == k_pad - 1)
     def _flush():
-        rows, c = acc_ref.shape
-        bp = rows // kk
-        patches = acc_ref[...].reshape(bp, kk * c)
-        w = w_ref[...].astype(jnp.float32)
-        acc = jnp.dot(patches, w, preferred_element_type=jnp.float32)
-        o_ref[0] = (acc + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+        o_ref[0] = _contract_taps(acc_ref[...], w_ref, b_ref,
+                                  kk).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -206,8 +226,8 @@ def _dcn_fused_schedule_jit(
     if t == 0:          # empty schedule: nothing to dispatch
         return jnp.zeros((0, p, o), x_tiles.dtype)
 
-    idx2 = idx.reshape(t, p * kk, 4)
-    coeff2 = coeff.reshape(t, p * kk, 4)
+    idx2 = _tap_major(idx, bp)
+    coeff2 = _tap_major(coeff, bp)
     w2 = w.reshape(kk * c, o)
     b2 = b.reshape(1, o)
 
@@ -231,7 +251,7 @@ def _dcn_fused_schedule_jit(
         ],
         out_specs=pl.BlockSpec((1, bp, o),
                                lambda ti, j, k, dep, cnt: (ti, j, 0)),
-        scratch_shapes=[pltpu.VMEM((bp * kk, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kk * bp, c), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_sched_kernel, tp=tp, kk=kk, k_pad=k_pad),
@@ -261,11 +281,11 @@ def _batch_kernel(row_ref, dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref,
                elided across image boundaries.
     cnt_ref:   (G,) int32 true dep count; 0 marks a ragged-padding row,
                whose compute is skipped entirely.
-    idx_ref:   (1, bp*KK, 4) int32 plane-global packed addresses
+    idx_ref:   (1, KK*bp, 4) int32 plane-global packed addresses
                ``tile_id * tp + offset`` (schedule-independent: packed
-               once per image in plane order).
+               once per image in plane order), tap-major.
     x_ref:     (1, tp, C) — input tile ``dep[g, k]`` of image ``img``.
-    acc_ref:   (bp*KK, C) f32 VMEM scratch.
+    acc_ref:   (KK*bp, C) f32 VMEM scratch.
 
     Same §IV-D fusion as ``_sched_kernel``; the only difference is the
     addressing: idx is global to the image's tile array, so slot k's
@@ -283,7 +303,7 @@ def _batch_kernel(row_ref, dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref,
     def _accumulate():
         idx = idx_ref[0]
         coeff = coeff_ref[0].astype(jnp.float32)
-        rows = idx.shape[0]                  # bp * KK
+        rows = idx.shape[0]                  # KK * bp
         dep_local = dep_ref[g, k] % t_in     # image-local dep tile id
         local = idx - dep_local * tp         # in [0, tp) iff in this tile
         cols = jax.lax.broadcasted_iota(jnp.int32, (rows, tp), 1)
@@ -297,12 +317,8 @@ def _batch_kernel(row_ref, dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref,
 
     @pl.when(k == k_pad - 1)
     def _flush():
-        rows, c = acc_ref.shape
-        bp = rows // kk
-        patches = acc_ref[...].reshape(bp, kk * c)
-        w = w_ref[...].astype(jnp.float32)
-        acc = jnp.dot(patches, w, preferred_element_type=jnp.float32)
-        o_ref[0] = (acc + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+        o_ref[0] = _contract_taps(acc_ref[...], w_ref, b_ref,
+                                  kk).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -351,8 +367,8 @@ def _dcn_fused_batch_jit(
     if g == 0:          # empty batch grid: nothing to dispatch
         return jnp.zeros((0, p, o), x_tiles.dtype)
 
-    idx2 = idx.reshape(g_rows, p * kk, 4)
-    coeff2 = coeff.reshape(g_rows, p * kk, 4)
+    idx2 = _tap_major(idx, bp)
+    coeff2 = _tap_major(coeff, bp)
     w2 = w.reshape(kk * c, o)
     b2 = b.reshape(1, o)
 
@@ -377,7 +393,7 @@ def _dcn_fused_batch_jit(
         ],
         out_specs=pl.BlockSpec((1, bp, o),
                                lambda gi, j, k, row, dep, cnt: (gi, j, 0)),
-        scratch_shapes=[pltpu.VMEM((bp * kk, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kk * bp, c), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_batch_kernel, tp=tp, kk=kk, k_pad=k_pad,
@@ -438,10 +454,12 @@ def _dcn_fused_batch_sharded_jit(
                                  block_p=block_p, interpret=interpret)
         return y[None]
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(spec,) * 6 + (PartitionSpec(),
-                                          PartitionSpec()),
-                  out_specs=spec)
+    # check_vma=False: the body has no collective, and the pallas_call
+    # output carries no varying-axes annotation for the checker to read.
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(spec,) * 6 + (PartitionSpec(),
+                                              PartitionSpec()),
+                      out_specs=spec, check_vma=False)
     return f(x_tiles, row_id, dep_glb, dep_cnt, idx, coeff, w, b)
 
 
@@ -536,6 +554,13 @@ def dcn_fused_batch_sharded(x_tiles, row_id, dep_glb, dep_cnt, idx, coeff,
                         shards=int(d),
                         grid_rows=int(row_id.shape[0] * row_id.shape[1]),
                         c_out=int(w.shape[-1]))
+    # Place each shard on its own mesh device and replicate the weights
+    # explicitly: operands may arrive committed to the default device.
+    split = NamedSharding(mesh, PartitionSpec(axis))
+    full = NamedSharding(mesh, PartitionSpec())
+    x_tiles, row_id, dep_glb, dep_cnt, idx, coeff = jax.device_put(
+        (x_tiles, row_id, dep_glb, dep_cnt, idx, coeff), split)
+    w, b = jax.device_put((w, b), full)
     if sp is None:
         return _dcn_fused_batch_sharded_jit(
             x_tiles, row_id, dep_glb, dep_cnt, idx, coeff, w, b,

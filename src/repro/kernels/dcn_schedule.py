@@ -18,8 +18,8 @@ Pallas kernels so scheduling runs on-device like the paper's hardware:
   * :func:`greedy_schedule_arrays` — Algorithm 1. The grid dimension IS
     the scheduling step; VMEM scratch carries the executed-tile bitmask
     and the FIFO residency state (per-input-tile last-load sequence
-    numbers) across steps, SMEM carries the current tile id and the
-    global load counter. Each step computes every candidate's overlap
+    numbers), the current tile id and the global load counter across
+    steps. Each step computes every candidate's overlap
     with the current tile as one vector AND + popcount (the paper's
     adder tree), argmaxes (the pipelined comparator, first-max ties like
     the host), classifies the next tile's inputs into Algorithm 1's
@@ -60,34 +60,41 @@ _NEVER_LOADED = -(1 << 30)
 
 
 def _tdt_kernel(rc_ref, o_ref, *, h: int, w: int, th: int, tw: int,
-                cols: int, n_in: int):
+                cols: int):
     """One output tile's TDT row from its pixel block's coordinates.
 
-    rc_ref: (1, 2, tpkk) f32 — row 0 the sample row coords, row 1 the
-            column coords, flattened over (tile pixel, kernel tap).
-    o_ref:  (1, n_in) int32 — the tile's dependency row (0/1).
+    rc_ref: (1, tpkk, 2) f32 — per (tile pixel, kernel tap) the sample's
+            row and column coordinate.
+    o_ref:  (T, n_in) int32 — the whole table, resident in VMEM across
+            the grid; step ``i`` writes row ``i`` (0/1).
 
     Fig. 9's circuit: each coordinate's 4 BLI neighbours are clipped to
     the plane, decoded to an input-tile id, and OR-reduced over the
     block into the row — a masked segment reduction replacing the host
-    scatter.
+    scatter. Coordinates arrive as columns and the row is a lane vector,
+    so the decode needs no in-kernel transpose.
     """
-    rc = rc_ref[0]                                         # (2, tpkk)
-    r = rc[0:1, :]
-    c = rc[1:2, :]
+    i = pl.program_id(0)
+    rc = rc_ref[0]                                         # (tpkk, 2)
+    r = rc[:, 0:1]
+    c = rc[:, 1:2]
     r0 = jnp.clip(jnp.floor(r).astype(jnp.int32), 0, h - 1)
     c0 = jnp.clip(jnp.floor(c).astype(jnp.int32), 0, w - 1)
     r1 = jnp.clip(r0 + 1, 0, h - 1)
     c1 = jnp.clip(c0 + 1, 0, w - 1)
 
-    tpkk = rc.shape[1]
+    tpkk = rc.shape[0]
+    n_in = o_ref.shape[1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (tpkk, n_in), 1)
     row = jnp.zeros((1, n_in), jnp.int32)
     for rr, cc in ((r0, c0), (r0, c1), (r1, c0), (r1, c1)):
-        tid = (rr // th) * cols + cc // tw                 # (1, tpkk)
-        hit = (lane == tid.reshape(tpkk, 1)).astype(jnp.int32)
+        # Coordinates are clipped non-negative: truncating division is
+        # the floor the host decoder takes.
+        tid = (jax.lax.div(rr, th) * cols
+               + jax.lax.div(cc, tw))                      # (tpkk, 1)
+        hit = (lane == tid).astype(jnp.int32)
         row = jnp.maximum(row, jnp.max(hit, axis=0, keepdims=True))
-    o_ref[...] = row
+    o_ref[pl.ds(i, 1), :] = row
 
 
 @functools.partial(jax.jit,
@@ -116,18 +123,16 @@ def tdt_from_coords_device(coords: jax.Array, in_grid, out_grid,
     r_idx = jnp.minimum(jnp.arange(rows * th, dtype=jnp.int32), h - 1)
     c_idx = jnp.minimum(jnp.arange(cols * tw, dtype=jnp.int32), w - 1)
     ct = coords.astype(jnp.float32)[r_idx][:, c_idx]
-    ct = (ct.reshape(rows, th, cols, tw, kk, 2)
+    rc = (ct.reshape(rows, th, cols, tw, kk, 2)
           .transpose(0, 2, 1, 3, 4, 5)
           .reshape(t_out, tpkk, 2))
-    rc = ct.transpose(0, 2, 1)                             # (T, 2, tpkk)
 
     out = pl.pallas_call(
         functools.partial(_tdt_kernel, h=in_grid.h, w=in_grid.w,
-                          th=in_grid.th, tw=in_grid.tw, cols=in_grid.cols,
-                          n_in=n_in),
+                          th=in_grid.th, tw=in_grid.tw, cols=in_grid.cols),
         grid=(t_out,),
-        in_specs=[pl.BlockSpec((1, 2, tpkk), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, n_in), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((1, tpkk, 2), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((t_out, n_in), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t_out, n_in), jnp.int32),
         interpret=interpret,
     )(rc)
@@ -140,19 +145,25 @@ def tdt_from_coords_device(coords: jax.Array, in_grid, out_grid,
 
 
 def _greedy_kernel(b_ref, oid_ref, klass_ref, ovl_ref,
-                   exec_ref, seq_ref, sm_ref, *, m: int):
+                   exec_ref, seq_ref, tot_ref, cur_ref, *, m: int):
     """One Algorithm-1 scheduling step (the grid dimension is the step).
 
     b_ref:     (n_out, n_in) int32 0/1 TDT — full block every step.
-    oid_ref:   (1, 1)     int32 — tile scheduled this step (-1 = done).
-    klass_ref: (1, n_in)  int32 — input priority class per input tile:
-               0 = loadedVec, 1 = seqLoadVec, 2 = lastLoadVec, 3 = not a
-               dependency. The host reconstructs the load order as
-               ids(0) asc ++ ids(1) asc ++ ids(2) asc.
-    ovl_ref:   (1, 1)     int32 — |B[curr] & B[next]| reuse overlap.
+    oid_ref:   (n_out, 1)    int32 — tile scheduled per step (-1 = done).
+    klass_ref: (n_out, n_in) int32 — per step, the input priority class
+               per input tile: 0 = loadedVec, 1 = seqLoadVec, 2 =
+               lastLoadVec, 3 = not a dependency. The host reconstructs
+               the load order as ids(0) asc ++ ids(1) asc ++ ids(2) asc.
+    ovl_ref:   (n_out, 1)    int32 — per step, |B[curr] & B[next]|.
+               The three outputs stay resident in VMEM across the grid;
+               step ``i`` writes their row ``i``.
     exec_ref:  VMEM (n_out, 1) int32 scratch — executed-tile bitmask.
     seq_ref:   VMEM (1, n_in) int32 scratch — FIFO last-load seq numbers.
-    sm_ref:    SMEM (2,) int32 scratch — [loads_total, curr tile id].
+    tot_ref:   VMEM (1, 1) int32 scratch — loads so far.
+    cur_ref:   VMEM (1, 1) int32 scratch — the current tile id.
+
+    All state is vector-valued and every update is a select on ``take``,
+    so no value moves from a vector register into a scalar one.
     """
     i = pl.program_id(0)
     n_out, n_in = b_ref.shape
@@ -161,15 +172,15 @@ def _greedy_kernel(b_ref, oid_ref, klass_ref, ovl_ref,
     def _init():
         exec_ref[...] = jnp.zeros_like(exec_ref)
         seq_ref[...] = jnp.full_like(seq_ref, _NEVER_LOADED)
-        sm_ref[0] = 0
-        sm_ref[1] = 0
+        tot_ref[...] = jnp.zeros_like(tot_ref)
+        cur_ref[...] = jnp.zeros_like(cur_ref)
 
     b = b_ref[...]
     executed = exec_ref[...]                               # (n_out, 1)
     seqs = seq_ref[...]                                    # (1, n_in)
-    loads_total = sm_ref[0]
-    curr = sm_ref[1]
-    is_first = i == 0
+    loads_total = tot_ref[...]                             # (1, 1)
+    curr = cur_ref[...]                                    # (1, 1)
+    is_first = jnp.full((1, 1), i, jnp.int32) == 0
 
     # Candidate scores: dependency count on the first step (Algorithm 1
     # line 2), overlap with the current tile (AND + adder tree) after.
@@ -184,11 +195,15 @@ def _greedy_kernel(b_ref, oid_ref, klass_ref, ovl_ref,
     masked = jnp.where(valid, score, -1)
     # First maximum wins ties — the paper's pipelined comparator and the
     # host np.argmax agree on this.
-    nxt = jnp.argmax(masked).astype(jnp.int32)
+    tile_iota = jax.lax.broadcasted_iota(jnp.int32, (n_out, 1), 0)
+    best = jnp.max(masked, axis=0, keepdims=True)          # (1, 1)
+    nxt = jnp.min(jnp.where(masked == best, tile_iota, n_out),
+                  axis=0, keepdims=True)                   # (1, 1)
     # The host schedules its argmax pick unconditionally on the first
     # step (even a dependency-free tile 0 when the TDT is empty); later
     # steps only run while un-executed dependent tiles remain.
-    take = is_first | jnp.any(valid)
+    any_valid = jnp.max(valid.astype(jnp.int32), axis=0, keepdims=True)
+    take = is_first | (any_valid > 0)                      # (1, 1)
 
     nxtdep = jnp.sum(jnp.where(row_iota == nxt, b, 0),
                      axis=0, keepdims=True) > 0            # (1, n_in)
@@ -209,8 +224,8 @@ def _greedy_kernel(b_ref, oid_ref, klass_ref, ovl_ref,
                        preferred_element_type=jnp.float32)
     rank_last = jnp.dot(lastf, tri,
                         preferred_element_type=jnp.float32)
-    n_seq = jnp.sum(seqf).astype(jnp.int32)
-    n_last = jnp.sum(lastf).astype(jnp.int32)
+    n_seq = jnp.sum(seqv.astype(jnp.int32), axis=1, keepdims=True)
+    n_last = jnp.sum(lastv.astype(jnp.int32), axis=1, keepdims=True)
     new_seqs = jnp.where(
         seqv, loads_total + rank_seq.astype(jnp.int32),
         jnp.where(lastv, loads_total + n_seq + rank_last.astype(jnp.int32),
@@ -218,20 +233,17 @@ def _greedy_kernel(b_ref, oid_ref, klass_ref, ovl_ref,
 
     klass = jnp.where(loaded, 0,
                       jnp.where(seqv, 1, jnp.where(lastv, 2, 3)))
-    oid_ref[...] = jnp.where(take, nxt, -1).reshape(1, 1)
-    klass_ref[...] = jnp.where(take, klass, 3).astype(jnp.int32)
-    ovl_ref[...] = jnp.where(
-        take, jnp.sum(((currdep > 0) & nxtdep).astype(jnp.int32)),
-        0).reshape(1, 1)
+    ovl = jnp.sum(((currdep > 0) & nxtdep).astype(jnp.int32), axis=1,
+                  keepdims=True)
+    oid_ref[pl.ds(i, 1), :] = jnp.where(take, nxt, -1)
+    klass_ref[pl.ds(i, 1), :] = jnp.where(take, klass, 3).astype(jnp.int32)
+    ovl_ref[pl.ds(i, 1), :] = jnp.where(take, ovl, 0)
 
-    @pl.when(take)
-    def _advance():
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (n_out, 1), 0)
-                  == nxt).astype(jnp.int32)
-        exec_ref[...] = executed + onehot
-        seq_ref[...] = new_seqs
-        sm_ref[0] = loads_total + n_seq + n_last
-        sm_ref[1] = nxt
+    exec_ref[...] = jnp.where(take & (tile_iota == nxt), 1, executed)
+    seq_ref[...] = jnp.where(take, new_seqs, seqs)
+    tot_ref[...] = jnp.where(take, loads_total + n_seq + n_last,
+                             loads_total)
+    cur_ref[...] = jnp.where(take, nxt, curr)
 
 
 @functools.partial(jax.jit, static_argnames=("k_pad",))
@@ -313,15 +325,14 @@ def greedy_schedule_arrays(
     n_out, n_in = b.shape
     if m < 1:
         raise ValueError("buffer capacity must be >= 1 tile")
+    whole = [pl.BlockSpec((n_out, 1), lambda i: (0, 0)),
+             pl.BlockSpec((n_out, n_in), lambda i: (0, 0)),
+             pl.BlockSpec((n_out, 1), lambda i: (0, 0))]
     return pl.pallas_call(
         functools.partial(_greedy_kernel, m=m),
         grid=(n_out,),
         in_specs=[pl.BlockSpec((n_out, n_in), lambda i: (0, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_in), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        out_specs=whole,
         out_shape=[
             jax.ShapeDtypeStruct((n_out, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_out, n_in), jnp.int32),
@@ -330,7 +341,8 @@ def greedy_schedule_arrays(
         scratch_shapes=[
             pltpu.VMEM((n_out, 1), jnp.int32),
             pltpu.VMEM((1, n_in), jnp.int32),
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.VMEM((1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(b)

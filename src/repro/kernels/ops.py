@@ -24,6 +24,14 @@ def round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def resolve_interpret(flag: bool | None) -> bool:
+    """None = auto-detect: Pallas interpret mode only off-accelerator, so
+    GPU/TPU runs compile the kernels without a config change."""
+    if flag is None:
+        return jax.default_backend() == "cpu"
+    return bool(flag)
+
+
 def coords_to_idx_coeff(coords: jax.Array, h: int, w: int):
     """(..., 2) float coords -> flat 4-neighbour idx + coeffs (..., 4).
 
@@ -42,7 +50,7 @@ def coords_to_idx_coeff(coords: jax.Array, h: int, w: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bli_pallas(x: jax.Array, coords: jax.Array, *,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Stage 2 (Eq. 2) via the MXU 4-hot matmul kernel.
 
     x: (N, H, W, C); coords: (N, H, W, KK, 2) -> (N, H, W, KK, C).
@@ -64,7 +72,8 @@ def bli_pallas(x: jax.Array, coords: jax.Array, *,
         idx_f = jnp.pad(idx_f, ((0, 0), (0, p_pad - p), (0, 0)))
         coeff_f = jnp.pad(coeff_f, ((0, 0), (0, p_pad - p), (0, 0)))
 
-    fn = functools.partial(bli_tile_matmul, interpret=interpret)
+    fn = functools.partial(bli_tile_matmul,
+                           interpret=resolve_interpret(interpret))
     out = jax.vmap(fn)(x_flat, idx_f, coeff_f)          # (N, P_pad, C_pad)
     return out[:, :p, :c].reshape(n, h, w, kk, c)
 
@@ -79,7 +88,7 @@ def deformable_conv2d_pallas(
     kernel_size: int = 3,
     variant: str = "dcn2",
     max_displacement: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Full deformable conv: XLA stage-1 conv + fused Pallas stages 2+3.
 
@@ -108,7 +117,7 @@ def deformable_conv2d_pallas(
     w2 = params.w.reshape(kk, c, o)
 
     fn = functools.partial(dcn_fused_tile, kernel_size=kernel_size,
-                           interpret=interpret)
+                           interpret=resolve_interpret(interpret))
     out = jax.vmap(fn, in_axes=(0, 0, 0, None, None))(
         x_flat, idx_f, coeff_f, w2, params.b)            # (N,P_pad,O)
     return out[:, :p].reshape(n, h, w, o)

@@ -14,7 +14,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axis types: sharding stays implicit
+    (``with_sharding_constraint`` / ``NamedSharding``), where the
+    installed jax would default new meshes to Explicit axes."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -24,9 +31,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over however many (fake) host devices exist — used by
-    smoke/distributed tests (8 fake devices), the scale-out executor
-    path (``data_parallel=``) and single-device runs.
+    """Small mesh over the first ``data * model`` local devices (chips,
+    or forced host devices on a CPU) — used by smoke/distributed tests
+    (8 forced devices), the scale-out executor path (``data_parallel=``)
+    and single-device runs. Each mesh position is a distinct device.
 
     Validates the request against the live device count up front: the
     raw ``make_mesh`` reshape error ("cannot reshape array of size 1
@@ -38,13 +46,18 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
                          f"model={model}")
     have = jax.device_count()
     if data * model > have:
+        platform = jax.default_backend()
+        if platform == "cpu":
+            hint = (f"launch with XLA_FLAGS=--xla_force_host_platform_"
+                    f"device_count={data * model} (set before jax "
+                    f"initialises) or shrink the mesh")
+        else:
+            hint = ("shrink the mesh or run on a host with more "
+                    "chips")
         raise ValueError(
             f"make_host_mesh(data={data}, model={model}) needs "
-            f"{data * model} devices but only {have} "
-            f"{'is' if have == 1 else 'are'} available — launch with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{data * model} (set before jax initialises) or shrink "
-            f"the mesh")
+            f"{data * model} devices but this host has {have} "
+            f"{platform} device{'' if have == 1 else 's'} — {hint}")
     return make_mesh((data, model), ("data", "model"))
 
 

@@ -258,7 +258,8 @@ def sdpa_any(q, k, v, cfg: AttnConfig, *, causal: bool):
 
 
 def attention_train(p, cfg: AttnConfig, x, *, positions=None, kv_src=None,
-                    use_flash: bool = False, flash_interpret: bool = True):
+                    use_flash: bool = False,
+                    flash_interpret: bool | None = None):
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
@@ -267,9 +268,10 @@ def attention_train(p, cfg: AttnConfig, x, *, positions=None, kv_src=None,
     causal = not cfg.cross
     if use_flash:
         from repro.kernels.flash_attention import flash_attention
+        from repro.kernels.ops import resolve_interpret
         out = flash_attention(q, k, v, causal=causal, window=cfg.window,
                               softcap=cfg.attn_softcap, scale=cfg.qk_scale,
-                              interpret=flash_interpret)
+                              interpret=resolve_interpret(flash_interpret))
     else:
         out = sdpa_any(q, k, v, cfg, causal=causal)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(out.dtype))

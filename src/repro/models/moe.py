@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.models.params import Maker
 
 
@@ -267,17 +266,17 @@ def moe_apply(p, cfg: MoeConfig, x, *, mesh: jax.sharding.Mesh | None = None,
             else:
                 out = psum(routed.astype(jnp.float32) + shared)
             out = out.astype(x_loc.dtype)
-            aux = pvary(aux, (dp + (ep_axis,)) if tp_f
-                                else (ep_axis,))
+            aux = jax.lax.pcast(aux, (dp + (ep_axis,)) if tp_f
+                                else (ep_axis,), to="varying")
             return out, jax.lax.pmean(aux, all_axes)
 
         out, aux = _moe_core(p_loc, cfg, x_loc, rank=rank,
                              wgather=wgather, psum=psum)
-        aux = pvary(aux, (dp + (ep_axis,)) if tp_f
-                            else (ep_axis,))
+        aux = jax.lax.pcast(aux, (dp + (ep_axis,)) if tp_f
+                            else (ep_axis,), to="varying")
         return out, jax.lax.pmean(aux, all_axes)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(wspec, batch_spec),
         out_specs=(batch_spec, P()),

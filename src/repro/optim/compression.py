@@ -18,8 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 def quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """fp -> (int8 values, fp32 scale). Symmetric per-tensor."""
@@ -55,7 +53,7 @@ def compressed_psum_tree(grads, err, axis_names):
         n = 1
         for a in (axis_names if isinstance(axis_names, tuple)
                   else (axis_names,)):
-            n *= axis_size(a)
+            n *= jax.lax.axis_size(a)
         decoded = summed.astype(jnp.float32) * scale / n
         new_err = gf - dequantize(q, scale)
         return decoded.astype(g.dtype), new_err

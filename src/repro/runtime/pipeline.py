@@ -48,7 +48,7 @@ from repro.kernels.dcn_fused import (dcn_fused_batch,
                                      dcn_fused_batch_sharded,
                                      dcn_fused_schedule, dcn_fused_tile)
 from repro.kernels.dcn_schedule import tdt_from_coords_device
-from repro.kernels.ops import round_up
+from repro.kernels.ops import resolve_interpret, round_up
 from repro.obs import Tracer, default_registry, get_tracer, use_tracer
 from repro.runtime.cache import coords_digest, default_schedule_cache
 from repro.runtime.packing import (NeighbourTables, build_neighbour_tables,
@@ -60,14 +60,6 @@ from repro.runtime.shard import (ShardPlan, allgather_nbytes,
                                  shard_batch_schedules, stack_rows,
                                  unstack_rows)
 from repro.runtime.trace import ImageTrace, PipelineTrace, TileRecord
-
-
-def resolve_interpret(flag: bool | None) -> bool:
-    """None = auto-detect: Pallas interpret mode only off-accelerator, so
-    GPU/TPU runs compile the kernels without a config change."""
-    if flag is None:
-        return jax.default_backend() == "cpu"
-    return bool(flag)
 
 
 # Process-wide like core.scheduler.host_schedule_builds: callers that

@@ -194,8 +194,13 @@ def unstack_rows(stacked: jax.Array, plan: ShardPlan,
     parts = [stacked[s, :(b - a) * rows]
              for s, (a, b) in enumerate(plan.spans) if b > a]
     if not parts:
-        return stacked.reshape((0,) + stacked.shape[2:])
-    return jnp.concatenate(parts)
+        out = stacked.reshape((0,) + stacked.shape[2:])
+    else:
+        out = jnp.concatenate(parts)
+    # Land on the default device, so every op after the gather runs
+    # with the single-device program (and its reduction order) instead
+    # of a GSPMD-partitioned one.
+    return jax.device_put(out, jax.devices()[0])
 
 
 def allgather_nbytes(arr: jax.Array) -> int:

@@ -17,6 +17,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(body: str, timeout: int = 420):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never a chip
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     script = textwrap.dedent(body)
     proc = subprocess.run([sys.executable, "-c", script], env=env,
@@ -177,7 +178,6 @@ class TestGradientCompression:
         _run("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from repro.compat import shard_map
             from repro.launch.mesh import make_host_mesh
             from repro.optim import compressed_psum_tree, init_error_state
 
@@ -190,7 +190,7 @@ class TestGradientCompression:
                 summed, new_err = compressed_psum_tree(grads, err, ("data",))
                 return summed["w"], new_err["w"][None]
 
-            out, err = jax.jit(shard_map(
+            out, err = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=P("data", None),
                 out_specs=(P(), P("data", None))))(g_global)
             want = g_global.mean(0)  # decoded psum is the DP mean

@@ -1,5 +1,11 @@
 """Launch machinery on the host: HLO analysis, step builder, rules."""
 
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -7,12 +13,16 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.configs.base import ShapeCell, cell_supported, input_specs
+from repro.launch.compile_cache import ENV_VAR as CACHE_ENV_VAR
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import make_host_mesh, mesh_chips
 from repro.launch.sharding import sharding_rules
 from repro.launch.steps import build_step
 from repro.models.params import LogicalAxes, resolve_spec
 from repro.optim import AdamWConfig
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 class TestHloAnalysis:
@@ -137,3 +147,48 @@ class TestHostMeshValidation:
     def test_full_device_count_is_valid(self):
         mesh = make_host_mesh(jax.device_count(), 1)
         assert dict(mesh.shape)["data"] == jax.device_count()
+
+    def test_chip_host_error_names_no_cpu_flag(self, monkeypatch):
+        """Forcing host devices cannot add chips: on a TPU host the
+        error must not point at XLA_FLAGS."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="more chips") as e:
+            make_host_mesh(jax.device_count() + 1, 1)
+        assert "XLA_FLAGS" not in str(e.value)
+
+
+class TestCompileCache:
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(CACHE_ENV_VAR, str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_unset_env_uses_the_checkout_dir(self, monkeypatch):
+        monkeypatch.delenv(CACHE_ENV_VAR, raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            got = enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+            assert enable_compile_cache() == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert Path(got) == ROOT / ".jax_cache"
+        assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
+
+
+@pytest.mark.parametrize("alone", [False, True])
+def test_chip_smoke_fails_without_a_tpu(tmp_path, alone):
+    """Off a TPU, or copied away from the repo, chip_smoke.py exits
+    nonzero and prints no result line."""
+    script = ROOT / "chip_smoke.py"
+    if alone:
+        script = Path(shutil.copy(script, tmp_path))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, str(script)], cwd=tmp_path,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok"' not in proc.stdout
+    assert "FAILED" in proc.stderr

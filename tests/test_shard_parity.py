@@ -39,6 +39,9 @@ def _run(body: str, timeout: int = 560, devices: int = 4):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{devices}")
+    # Forced host devices are CPU devices: the child must never reach
+    # for an accelerator the parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     # src for the package, the repo root so scripts can reuse the
     # test-suite case builders (tests.test_graph etc.).
     env["PYTHONPATH"] = os.pathsep.join(

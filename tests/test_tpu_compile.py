@@ -1,0 +1,147 @@
+"""Compile the serving path's Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler that ships with jax compiles for a
+``v5e:2x2`` topology that is described, not attached, so a kernel the
+Mosaic lowering would refuse (unaligned blocks, lane-changing reshapes,
+VMEM overflow) fails here instead of on the chip. Nothing runs, so these
+tests say nothing about results; the interpret-mode tests pin those.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and the test workers all import this file.
+"""
+
+import contextlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from repro.core.tiles import TileGrid
+from repro.kernels.dcn_fused import (_dcn_fused_batch_jit,
+                                     _dcn_fused_batch_sharded_jit,
+                                     _dcn_fused_schedule_jit)
+from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
+                                        tdt_from_coords_device)
+
+TILE = 8
+TP = TILE * TILE          # pixels per tile
+KK = 9                    # 3x3 taps
+C_OUT = 512
+T_IN = 16                 # a 28x28 plane in 8x8 tiles
+K_PAD = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2; the persistent compilation cache is off
+    meanwhile (a described chip's entry cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, precision=None, **static):
+    ctx = (jax.default_matmul_precision(precision) if precision
+           else contextlib.nullcontext())
+    with ctx:
+        text = fn.lower(*args, **static).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def _fused_operands(s, rows, c_in):
+    """idx/coeff/w/b operands of the fused kernels, ``rows`` tiles."""
+    return (_shape(s, (rows, TP, KK, 4), jnp.int32),
+            _shape(s, (rows, TP, KK, 4), jnp.float32),
+            _shape(s, (KK, c_in, C_OUT), jnp.float32),
+            _shape(s, (C_OUT,), jnp.float32))
+
+
+@pytest.mark.parametrize("precision", [None, "highest"])
+@pytest.mark.parametrize("c_in", [3, 64, 256, 512])
+class TestFusedKernels:
+    """C_in not a multiple of 128 used to fail Mosaic's shape cast."""
+
+    def test_batch_kernel(self, one_chip, c_in, precision):
+        n = 4
+        s = one_chip
+        _compile(_dcn_fused_batch_jit,
+                 _shape(s, (n * T_IN, TP, c_in), jnp.float32),
+                 _shape(s, (n * T_IN,), jnp.int32),
+                 _shape(s, (n * T_IN, K_PAD), jnp.int32),
+                 _shape(s, (n * T_IN,), jnp.int32),
+                 *_fused_operands(s, n * T_IN, c_in),
+                 precision=precision, t_in=T_IN, kernel_size=3,
+                 block_p=128, interpret=False)
+
+    def test_schedule_kernel(self, one_chip, c_in, precision):
+        s = one_chip
+        _compile(_dcn_fused_schedule_jit,
+                 _shape(s, (T_IN, TP, c_in), jnp.float32),
+                 _shape(s, (T_IN, K_PAD), jnp.int32),
+                 _shape(s, (T_IN,), jnp.int32),
+                 *_fused_operands(s, T_IN, c_in),
+                 precision=precision, kernel_size=3, block_p=128,
+                 interpret=False)
+
+
+@pytest.mark.parametrize("plane", [28, 224])
+def test_tdt_kernel(one_chip, plane):
+    grid = TileGrid(plane, plane, TILE, TILE)
+    _compile(tdt_from_coords_device,
+             _shape(one_chip, (plane, plane, KK, 2), jnp.float32),
+             in_grid=grid, out_grid=grid, interpret=False)
+
+
+@pytest.mark.parametrize("n", [16, 784])
+def test_greedy_kernel(one_chip, n):
+    _compile(greedy_schedule_arrays,
+             _shape(one_chip, (n, n), jnp.bool_), m=n, interpret=False)
+
+
+def test_sharded_batch_kernel(topo):
+    """The shard_map'd batch kernel over four described chips."""
+    d, c_in = 4, 256
+    mesh = Mesh(topo.devices, ("data",))
+    split = NamedSharding(mesh, PartitionSpec("data"))
+    full = NamedSharding(mesh, PartitionSpec())
+    rows = 2 * T_IN
+    _compile(_dcn_fused_batch_sharded_jit,
+             _shape(split, (d, rows, TP, c_in), jnp.float32),
+             _shape(split, (d, rows), jnp.int32),
+             _shape(split, (d, rows, K_PAD), jnp.int32),
+             _shape(split, (d, rows), jnp.int32),
+             _shape(split, (d, rows, TP, KK, 4), jnp.int32),
+             _shape(split, (d, rows, TP, KK, 4), jnp.float32),
+             _shape(full, (KK, c_in, C_OUT), jnp.float32),
+             _shape(full, (C_OUT,), jnp.float32),
+             mesh=mesh, axis="data", t_in=T_IN, kernel_size=3,
+             block_p=128, interpret=False)
