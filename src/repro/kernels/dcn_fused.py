@@ -468,9 +468,11 @@ def _dcn_fused_batch_sharded_jit(
 # span per host dispatch. Spans cannot live INSIDE the jitted functions
 # (they would fire once at trace time, not per call), so each entry
 # point is a thin host wrapper that opens ``dispatch.<mode>`` on the
-# current ``repro.obs`` tracer. Disabled tracer = one extra attribute
-# check per dispatch; calls from inside jit/vmap traces (``x`` is a JAX
-# tracer) skip the span entirely.
+# current ``repro.obs`` tracer. Dispatch is asynchronous: the span times
+# the host's enqueue of the call (and its compile on a first call), not
+# the kernel's run on the device, which a profiler trace shows. Disabled
+# tracer = one extra attribute check per dispatch; calls from inside
+# jit/vmap traces (``x`` is a JAX tracer) skip the span entirely.
 # ---------------------------------------------------------------------------
 
 

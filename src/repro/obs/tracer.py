@@ -21,6 +21,17 @@ Two entry points with different disabled-path contracts:
   from these spans (``OverlapSpans.add_span``), so overlap counters
   stay exact whether or not tracing is on.
 
+Profiler mirror: every span an enabled tracer records through
+``span()`` / ``timed()`` also opens a ``jax.profiler.TraceAnnotation``
+of the same name for its duration (``serve.step`` opens a
+``StepTraceAnnotation`` numbered by its ``step`` attribute), with its
+scalar enter-time attributes as event stats. Under
+``jax.profiler.start_trace`` the program's spans therefore land on the
+host plane of the profile, on the same clock as the device's ops. The
+annotation costs next to nothing while no profile is being taken; a
+disabled tracer opens none. ``jax.profiler`` is imported on the first
+recording span, so this module stays importable without jax.
+
 Thread model: each thread keeps its own span stack (parenting never
 crosses threads — the staging worker's prepass spans are roots on its
 own track), and the span list is lock-protected, so the multi-image
@@ -28,8 +39,8 @@ staging queue and concurrent serving submitters can all record into one
 tracer. Export to Chrome-trace/Perfetto JSON lives in
 ``repro.obs.export``.
 
-Zero-dep by design: stdlib only, importable from ``core``/``kernels``
-without cycles.
+Zero-dep by design: stdlib only (jax is imported lazily, for the
+profiler mirror), importable from ``core``/``kernels`` without cycles.
 """
 
 from __future__ import annotations
@@ -104,16 +115,41 @@ class Stopwatch:
         return self
 
 
-class _LiveSpan:
-    """Recording context manager: appends a Span to the tracer on exit."""
+_PROFILER = None                  # jax.profiler once imported; False: none
+_SCALARS = (int, float, str, bool)
 
-    __slots__ = ("_tracer", "_span")
+
+def _annotation(name: str, attrs: dict):
+    """The ``jax.profiler`` annotation mirroring one span, or None
+    without jax."""
+    global _PROFILER
+    if _PROFILER is None:
+        try:
+            import jax.profiler as profiler
+        except ImportError:
+            profiler = False
+        _PROFILER = profiler
+    if not _PROFILER:
+        return None
+    stats = {k: v for k, v in attrs.items() if isinstance(v, _SCALARS)}
+    if name == "serve.step" and "step" in stats:
+        step = stats.pop("step")
+        return _PROFILER.StepTraceAnnotation(name, step_num=step, **stats)
+    return _PROFILER.TraceAnnotation(name, **stats)
+
+
+class _LiveSpan:
+    """Recording context manager: appends a Span to the tracer on exit,
+    mirrored by a profiler annotation while it is open."""
+
+    __slots__ = ("_tracer", "_span", "_note")
 
     def __init__(self, tracer: Tracer, name: str, attrs: dict):
         th = threading.current_thread()
         self._tracer = tracer
         self._span = Span(name=name, ts=0.0, tid=th.ident or 0,
                           thread_name=th.name, attrs=attrs)
+        self._note = None
 
     def __enter__(self):
         tr = self._tracer
@@ -122,12 +158,17 @@ class _LiveSpan:
         sp.sid = tr._next_id()
         sp.parent = stack[-1] if stack else None
         stack.append(sp.sid)
+        self._note = _annotation(sp.name, sp.attrs)
+        if self._note is not None:
+            self._note.__enter__()
         sp.ts = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         sp = self._span
         sp.dur = time.perf_counter() - sp.ts
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] == sp.sid:
             stack.pop()
@@ -177,6 +218,21 @@ class Tracer:
         if not self.enabled:
             return Stopwatch(name, attrs)
         return _LiveSpan(self, name, attrs)
+
+    def record(self, name: str, dur: float, **attrs) -> None:
+        """Record a span that ends now after ``dur`` seconds, for work
+        that something else timed (jax's compile monitoring). It is
+        parented on this thread's open span and not mirrored: the
+        profiler's own events cover that work."""
+        if not self.enabled:
+            return
+        th = threading.current_thread()
+        stack = self._stack()
+        self._record(Span(name=name, ts=time.perf_counter() - dur,
+                          dur=dur, sid=self._next_id(),
+                          parent=stack[-1] if stack else None,
+                          tid=th.ident or 0, thread_name=th.name,
+                          attrs=attrs))
 
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration marker event (Chrome-trace ``ph: "i"``)."""

@@ -757,6 +757,7 @@ def _group_batch_prepass(
     interp: bool,
     tracer: Tracer | None = None,
     plan: ShardPlan | None = None,
+    segment: int = 0,
 ) -> tuple[_BatchGroupArtifacts, jax.Array]:
     """Batch-level prepass for one group: the stage-1 chain runs batched
     (one XLA dispatch per layer for all images), per-image composite
@@ -766,7 +767,8 @@ def _group_batch_prepass(
     backend everything after the digest stays on-device. With a shard
     ``plan`` the per-layer operands concatenate PER SHARD (each shard
     keeps its own ragged padding) — per-image schedules themselves are
-    built identically either way, so traces never depend on placement."""
+    built identically either way, so traces never depend on placement.
+    ``segment`` is the group's index in the partition (span attrs)."""
     tr = tracer if tracer is not None else get_tracer()
     n = planes.shape[0]
     device = cfg.schedule_backend == "device" and cfg.schedule == "alg1"
@@ -783,17 +785,20 @@ def _group_batch_prepass(
                    for j in range(group.n_layers)]
     plane = planes
     coords_layers: list = []
-    for j, node in enumerate(group.nodes):
-        p = convs[node.param_idx]
-        if isinstance(node, DeformNode):
-            offsets = conv2d(plane, p.w_off, p.b_off)
-            coords_layers.append(offsets_to_coords(
-                offsets.astype(jnp.float32), node.kernel_size,
-                node.variant, max_displacement))
-        else:
-            coords_layers.append(None)
-        if needs_plane[j]:
-            plane = _advance_dense_batch(plane, node, p, max_displacement)
+    with tr.span("prepass.stage1", group=segment, layers=group.n_layers,
+                 batch=n):
+        for j, node in enumerate(group.nodes):
+            p = convs[node.param_idx]
+            if isinstance(node, DeformNode):
+                offsets = conv2d(plane, p.w_off, p.b_off)
+                coords_layers.append(offsets_to_coords(
+                    offsets.astype(jnp.float32), node.kernel_size,
+                    node.variant, max_displacement))
+            else:
+                coords_layers.append(None)
+            if needs_plane[j]:
+                plane = _advance_dense_batch(plane, node, p,
+                                             max_displacement)
 
     def build_bundle(i: int) -> _ImageGroupSched:
         b_layers: list = []
@@ -1114,7 +1119,7 @@ def _run_graph_batch_fused(
             art, plane = _group_batch_prepass(
                 plane_in, seg, convs, grid, m, cfg, max_displacement,
                 cache, need_out_plane=deform_after[s], interp=interpret,
-                tracer=tr, plan=plan)
+                tracer=tr, plan=plan, segment=s)
         with pre_lock:
             if pre_state["epoch"] == s:
                 pre_state["plane"] = plane
@@ -1126,15 +1131,22 @@ def _run_graph_batch_fused(
 
     def execute(s: int, art):
         seg = segments[s]
+        kind = ("pool" if isinstance(seg, PoolNode) else
+                "upsample" if isinstance(seg, UpsampleNode) else "group")
+        # Host time to enqueue the segment's programs (dispatch is
+        # async: the device may run them later); trace assembly is not
+        # part of it.
+        with tr.span("exec.segment", segment=s, kind=kind):
+            if art is None:
+                exec_state["plane"] = apply_boundary_batch(
+                    exec_state["plane"], seg)
+            else:
+                exec_state["plane"], dispatches = _exec_group_batch_fused(
+                    exec_state["plane"], seg, convs, cfg, interpret, art,
+                    mesh=mesh, plan=plan)
         if art is None:
-            exec_state["plane"] = apply_boundary_batch(exec_state["plane"],
-                                                       seg)
             trace.boundary_bytes += n * boundary_bytes(seg, itemsize)
             return None
-        planes, dispatches = _exec_group_batch_fused(
-            exec_state["plane"], seg, convs, cfg, interpret, art,
-            mesh=mesh, plan=plan)
-        exec_state["plane"] = planes
         trace.batch_dispatches += dispatches
         trace.overlap.schedule_s += art.schedule_s
         trace.overlap.schedule_device_s += art.schedule_device_s

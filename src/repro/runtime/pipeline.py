@@ -99,9 +99,11 @@ def run_staged(n: int, prepass, execute, depth: int, overlap,
     tr = tracer if tracer is not None else get_tracer()
 
     def staged(i: int):
+        # On the staging worker too, spans opened through get_tracer()
+        # (packing, schedule kernels, lowerings) land in ``tr``.
         if faults is not None:
             faults.stall("worker_stall")
-        with tr.timed("prepass", unit=i) as sp:
+        with use_tracer(tr), tr.timed("prepass", unit=i) as sp:
             art = prepass(i)
         return art, sp
 

@@ -55,7 +55,9 @@ import numpy as np
 
 from repro.models import lm
 from repro.models.transformer import ModelConfig
-from repro.obs import MetricsRegistry, Tracer, get_tracer
+from repro.obs import (MetricsRegistry, Tracer, get_tracer,
+                       install_lowering_listener, jax_lowerings,
+                       use_tracer)
 from repro.serving.errors import (DeadlineExceededError, DrainTimeout,
                                   QueueFullError, RequestFailedError)
 
@@ -358,7 +360,8 @@ class DcnServingEngine:
         self._watchdog0 = staging_watchdog_failovers.count
         # Per-step serving timeline (filled only when the tracer is
         # enabled): step id, coalesced width, dispatch/DRAM accounting
-        # and the step's dispatch span walls — what bench_serving dumps.
+        # — what bench_serving dumps. The step's spans stay in the
+        # tracer, nested under its ``serve.step``.
         self.timeline: list[dict] = []
         # Continuous-batching state. The step config pins the coalesced
         # dispatch mode to batch_fused (the ragged batch grid handles
@@ -453,6 +456,12 @@ class DcnServingEngine:
                     and plan_cache_hits.count == hits_before):
                 # Fresh search (not a cache hit): surface its cost.
                 self._autotune_search_s = self.tuned_plan.search_s
+        # Lowerings (process-wide counter, like host_schedule_builds):
+        # the baseline is taken last, so ``compiles`` counts the
+        # programs lowered while serving, each also a ``jax.lower`` span
+        # when the tracer is enabled.
+        install_lowering_listener()
+        self._lowerings0 = jax_lowerings.count
 
     # Counter-backed views keep the pre-registry attribute API
     # (``eng.requests`` etc.) readable while the registry is the single
@@ -479,6 +488,13 @@ class DcnServingEngine:
         """Host-side ``TileSchedule`` builds since this engine was
         constructed (0 on the device scheduling hot path)."""
         return self._host_builds.count - self._host_builds0
+
+    @property
+    def compiles(self) -> int:
+        """Programs jax lowered (new executables: jitted functions and
+        eager operations at new shapes) since this engine was
+        constructed; process-wide counter, engine-relative delta."""
+        return jax_lowerings.count - self._lowerings0
 
     @property
     def requests_failed(self) -> int:
@@ -660,8 +676,10 @@ class DcnServingEngine:
             return_trace=True, schedule_cache=self.cache,
             tracer=self.tracer, shard_sizes=shard_sizes,
             tuned_plan=self.tuned_plan)
-        out = np.asarray(_apply_head(self.params, self.cfg, y,
-                                     self.cfg.name == "segnet"))
+        # The head, then the wait for the step's logits on the host.
+        with self.tracer.span("serve.fetch", images=len(images)):
+            out = np.asarray(_apply_head(self.params, self.cfg, y,
+                                         self.cfg.name == "segnet"))
         return out, trace
 
     def _shard_sizes(self, repl: list[int] | None):
@@ -796,6 +814,12 @@ class DcnServingEngine:
         Returns the requests that resolved this step — finished OR
         failed, each exactly once.
         """
+        # Lowerings on this thread (the head, eager ops outside the
+        # executor) land in the engine's tracer as ``jax.lower`` spans.
+        with use_tracer(self.tracer):
+            return self._step()
+
+    def _step(self) -> list[DcnRequest]:
         tr = self.tracer
         faults = self._faults
         if faults is not None:
@@ -829,7 +853,6 @@ class DcnServingEngine:
             return finished
         step_id = self._m_steps.count
         hits0 = self.cache.info()["image_hits"] if tr.enabled else 0
-        mark = len(tr) if tr.enabled else 0
         images = [req.x[j] for _, req, j in occupied]
         # Slot-ordered, and the slot->replica map is contiguous, so the
         # step batch is shard-contiguous by construction.
@@ -843,8 +866,6 @@ class DcnServingEngine:
             ssp.set(dispatches=dispatches, dram_bytes=dram,
                     failures=len(failures), degraded=degraded)
         if tr.enabled:
-            dispatch_spans = [s for s in tr.spans_since(mark)
-                              if s.name.startswith("dispatch.")]
             self.timeline.append({
                 "step": step_id,
                 "width": len(occupied),
@@ -856,9 +877,6 @@ class DcnServingEngine:
                 "image_hits": (self.cache.info()["image_hits"]
                                - hits0),
                 "schedule_backend": self._step_cfg.schedule_backend,
-                "dispatch_spans": [
-                    {"name": s.name, "dur_s": s.dur, **s.attrs}
-                    for s in dispatch_spans],
             })
         now = self._clock()
         with self._lock:
@@ -977,6 +995,7 @@ class DcnServingEngine:
                 "queue_depth": len(self._queue),
                 "steps": self.steps,
                 "host_schedule_builds": self.host_schedule_builds,
+                "compiles": self.compiles,
                 "latency": self.latency.summary(),
                 "max_queue": self.max_queue,
                 "queue_policy": self.queue_policy,
@@ -997,9 +1016,9 @@ class DcnServingEngine:
         """One machine-readable view of every engine metric: the
         registry counters/histograms plus gauges synced at call time
         (cache state + hit rates, queue/slot depths, overlap fractions,
-        the engine-relative ``host_schedule_builds`` delta). Every value
-        ``stats`` reports — and every counter the benchmark gates —
-        appears here under a stable name."""
+        the engine-relative ``host_schedule_builds`` and ``compiles``
+        deltas). Every value ``stats`` reports — and every counter the
+        benchmark gates — appears here under a stable name."""
         m = self.metrics
         with self._lock:
             self.cache.publish(m, prefix="schedule_cache")
@@ -1007,6 +1026,7 @@ class DcnServingEngine:
             m.gauge("serving.slots").set(self.n_slots)
             m.gauge("serving.host_schedule_builds").set(
                 self.host_schedule_builds)
+            m.gauge("serving.compiles").set(self.compiles)
             m.gauge("serving.watchdog_failovers").set(
                 self.watchdog_failovers)
             req = self._m_requests.count

@@ -21,9 +21,11 @@ from repro.core.deform import DeformableConvParams, randomize_offset_conv
 from repro.models.dcn_models import DcnNetConfig, init_dcn_net
 from repro.obs import (Histogram, MetricsRegistry, Span, Stopwatch,
                        Tracer, chrome_trace, default_registry, get_tracer,
-                       global_tracer, percentile, use_tracer,
+                       global_tracer, install_lowering_listener,
+                       jax_lowerings, percentile, use_tracer,
                        validate_chrome_trace, write_chrome_trace)
 from repro.runtime import GraphConfig, build_graph
+from repro.runtime.graph import FusedGroup, partition_graph_cached
 from repro.runtime.fused_exec import run_graph
 from repro.runtime.trace import OverlapSpans
 from repro.serving import DcnServingEngine
@@ -442,12 +444,9 @@ class TestServingTelemetry:
         assert len(eng.timeline) == len(steps) == eng.steps
         for entry in eng.timeline:
             assert {"step", "width", "wall_s", "dispatches",
-                    "dram_bytes", "image_hits", "schedule_backend",
-                    "dispatch_spans"} <= set(entry)
+                    "dram_bytes", "image_hits",
+                    "schedule_backend"} <= set(entry)
             assert entry["dispatches"] > 0 and entry["wall_s"] > 0
-            for dsp in entry["dispatch_spans"]:
-                assert dsp["name"].startswith("dispatch.")
-                assert dsp["dur_s"] >= 0.0
         doc = chrome_trace(tr)
         assert validate_chrome_trace(doc) == []
         # every serving step shows up on the engine-steps process
@@ -483,3 +482,167 @@ class TestServingTelemetry:
         assert len(tr) == 0
         assert eng.timeline == []
         assert eng.stats["requests"] == 4
+
+
+# ---------------------------------------------------------------------------
+# Profiler mirror, step-level spans, lowering counter
+# ---------------------------------------------------------------------------
+
+MIRRORED = ("serve.step", "prepass.wait", "prepass.stage1", "exec.segment",
+            "serve.fetch")
+
+
+def _one_step(dcn_setup, tracer, width=2, seed=0):
+    cfg, params = dcn_setup
+    eng = DcnServingEngine(params, cfg, graph=GraphConfig(tile=4),
+                           slots=4, tracer=tracer)
+    rng = np.random.default_rng(seed)
+    for _ in range(width):
+        eng.submit(rng.normal(size=(16, 16, 3)).astype(np.float32))
+    assert len(eng.step()) == width
+    return eng
+
+
+def _host_event_names(log_dir) -> set[str]:
+    from jax.profiler import ProfileData
+    path = next(log_dir.rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(path))
+    return {e.name for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+
+
+class TestProfilerMirror:
+    def test_enabled_tracer_annotates_profile(self, tmp_path):
+        tr = Tracer(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.timed("serve.step", step=7, width=2):
+                with tr.span("pack", dispatch="batch_fused", hits=None):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        from jax.profiler import ProfileData
+        pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+        evs = {e.name: dict(e.stats) for plane in pd.planes
+               if plane.name == "/host:CPU"
+               for line in plane.lines for e in line.events}
+        # bare span names; scalar attrs as stats, None left out
+        assert evs["serve.step"]["step_num"] == 7
+        assert evs["serve.step"]["width"] == 2
+        assert evs["pack"] == {"dispatch": "batch_fused"}
+
+    @pytest.mark.parametrize("enabled", [True, False])
+    def test_engine_step_spans_on_host_plane(self, dcn_setup, tmp_path,
+                                             enabled):
+        _one_step(dcn_setup, Tracer(enabled=True))        # compile first
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _one_step(dcn_setup, Tracer(enabled=enabled), seed=1)
+        finally:
+            jax.profiler.stop_trace()
+        found = _host_event_names(tmp_path) & set(MIRRORED)
+        assert found == (set(MIRRORED) if enabled else set())
+
+    def test_disabled_timed_opens_no_annotation(self):
+        tr = Tracer(enabled=False)
+        sw = tr.timed("serve.step", step=0)
+        assert isinstance(sw, Stopwatch)
+        assert tr.span("serve.fetch") is tr.span("exec.segment")
+
+
+class TestStepSpans:
+    def test_one_fetch_and_a_span_per_segment_and_group(self, dcn_setup):
+        tr = Tracer(enabled=True)
+        eng = _one_step(dcn_setup, tr)
+        segments = partition_graph_cached(
+            eng.net_graph, eng._step_cfg.onchip_budget_bytes)
+        groups = [s for s in segments if isinstance(s, FusedGroup)]
+        spans = tr.snapshot()
+
+        def named(name):
+            return [s for s in spans if s.name == name]
+
+        assert len(named("serve.fetch")) == 1
+        seg = named("exec.segment")
+        assert sorted(s.attrs["segment"] for s in seg) == \
+            list(range(len(segments)))
+        assert sum(s.attrs["kind"] == "group" for s in seg) == len(groups)
+        assert {s.attrs["kind"] for s in seg} <= {"group", "pool",
+                                                   "upsample"}
+        stage1 = named("prepass.stage1")
+        assert len(stage1) == len(groups)
+        assert [s.attrs["layers"] for s in stage1] == \
+            [g.n_layers for g in groups]
+        assert all(s.attrs["batch"] == 2 for s in stage1)
+        # stage 1 is not part of the schedule/pack spans the benchmark's
+        # prepass metric sums
+        by_sid = {s.sid: s for s in spans}
+        for s in stage1:
+            assert by_sid.get(s.parent) is None or by_sid[
+                s.parent].name not in ("prepass.schedule", "pack")
+        # the main thread's spans nest under the step
+        step = named("serve.step")[0]
+        assert named("serve.fetch")[0].tid == step.tid
+        assert all(s.tid == step.tid for s in seg)
+
+    def test_staging_worker_spans_reach_the_tracer(self, dcn_setup):
+        cfg, params = dcn_setup
+        x = jnp.asarray(np.random.default_rng(3).normal(
+            size=(2, 16, 16, 3)).astype(np.float32))
+        tr = Tracer(enabled=True)
+        run_graph(params["convs"], build_graph(cfg), x,
+                  config=GraphConfig(tile=4, dispatch="batch_fused",
+                                     staging_depth=2,
+                                     use_schedule_cache=False),
+                  tracer=tr)
+        main = threading.get_ident()
+        packed = [s for s in tr.snapshot()
+                  if s.name == "pack.batch_schedules"]
+        assert packed and all(s.tid != main for s in packed)
+
+
+class TestLowerings:
+    def test_first_step_lowers_repeat_step_does_not(self):
+        # A plane no other test serves: its first step must lower.
+        setup = _dcn_case(n_deform=1, img=24, seed=5)
+        cfg, params = setup
+        tr = Tracer(enabled=True)
+        eng = DcnServingEngine(params, cfg, graph=GraphConfig(tile=4),
+                               slots=4, tracer=tr)
+        rng = np.random.default_rng(0)
+
+        def step_lowerings():
+            mark = len(tr)
+            for _ in range(2):
+                eng.submit(rng.normal(size=(24, 24, 3)).astype(np.float32))
+            assert len(eng.step()) == 2
+            return [s for s in tr.spans_since(mark) if s.name == "jax.lower"]
+
+        first = step_lowerings()
+        assert first and all(s.dur >= 0.0 for s in first)
+        assert step_lowerings() == []
+        lowered = [s for s in tr.snapshot() if s.name == "jax.lower"]
+        assert eng.stats["compiles"] == len(lowered) == len(first)
+        assert eng.metrics_snapshot()["serving.compiles"] == len(first)
+
+    def test_listener_is_idempotent_and_counts_without_tracer(self):
+        install_lowering_listener()
+        install_lowering_listener()
+        x = jnp.arange(5.0)
+        c0 = jax_lowerings.count
+        tr = Tracer(enabled=False)
+        with use_tracer(tr):
+            jax.jit(lambda v: v * 3 + 1)(x)
+        # one program lowered, counted once, recorded nowhere
+        assert jax_lowerings.count - c0 == 1
+        assert len(tr) == 0
+
+    def test_record_parents_on_open_span(self):
+        tr = Tracer(enabled=True)
+        with tr.span("outer"):
+            tr.record("jax.lower", 0.25, what="x")
+        spans = {s.name: s for s in tr.snapshot()}
+        low = spans["jax.lower"]
+        assert low.parent == spans["outer"].sid
+        assert low.dur == 0.25 and low.attrs == {"what": "x"}
+        Tracer(enabled=False).record("jax.lower", 1.0)
