@@ -46,6 +46,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -283,22 +284,25 @@ def dispatch_arrays_from_klass(
     return oid, dep_tbl, cnt
 
 
-def tdt_dispatch_arrays(b: jax.Array, k_pad: int
-                        ) -> tuple[jax.Array, jax.Array]:
+def tdt_dispatch_arrays(b, k_pad: int):
     """Dense dispatch rows straight from a TDT (no scheduling): per output
     tile its dependent input tiles in ascending id order + counts. Used
     for interior fused-group layers, whose grid order is plane order.
-    All jnp — stays on device for the batch-fused handoff."""
-    bi = b.astype(jnp.int32)
+    A numpy TDT (the host scheduling backend) gives numpy rows; anything
+    else goes through jnp and stays on device for the batch-fused
+    handoff. Both sort stably: numpy's default sort is not, and ids
+    would then leave ascending order within a row."""
+    xp = np if isinstance(b, np.ndarray) else jnp
+    bi = xp.asarray(b).astype(xp.int32)
     n_out, n_in = bi.shape
-    order = jnp.argsort(1 - bi, axis=1)                    # deps first, asc
-    cnt = jnp.sum(bi, axis=1).astype(jnp.int32)
+    order = xp.argsort(1 - bi, axis=1, stable=True)        # deps first, asc
+    cnt = xp.sum(bi, axis=1).astype(xp.int32)
     if k_pad < n_in:
         order = order[:, :k_pad]
     elif k_pad > n_in:
-        order = jnp.pad(order, ((0, 0), (0, k_pad - n_in)))
-    slot = jax.lax.broadcasted_iota(jnp.int32, (n_out, k_pad), 1)
-    return jnp.where(slot < cnt[:, None], order, 0).astype(jnp.int32), cnt
+        order = xp.pad(order, ((0, 0), (0, k_pad - n_in)))
+    slot = xp.arange(k_pad)[None, :]
+    return xp.where(slot < cnt[:, None], order, 0).astype(xp.int32), cnt
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))

@@ -32,6 +32,13 @@ def coords_digest(coords: Any, grid: TileGrid) -> str:
     c = np.asarray(coords)
     r0 = np.clip(np.floor(c[..., 0]), 0, grid.h - 1).astype(np.int32)
     c0 = np.clip(np.floor(c[..., 1]), 0, grid.w - 1).astype(np.int32)
+    return floors_digest(r0, c0, grid)
+
+
+def floors_digest(r0: np.ndarray, c0: np.ndarray, grid: TileGrid) -> str:
+    """:func:`coords_digest` of coordinates whose clipped int32 floors
+    ``r0``/``c0`` were already taken (on the device, by the compiled
+    group prepass): the same bytes hashed, so the same key."""
     h = hashlib.sha1()
     h.update(repr(tuple(grid)).encode())
     h.update(np.ascontiguousarray(r0).tobytes())

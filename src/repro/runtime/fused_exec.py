@@ -17,7 +17,10 @@ pool/upsample boundary planes, matching
             schedule per fused group and pack the batched kernel
             operands. The prepass for image i+1 runs on a staging thread
             while image i executes on the device
-            (``GraphConfig.staging_depth``).
+            (``GraphConfig.staging_depth``). Under ``batch_fused`` the
+            prepass is per group for the whole batch, and its device
+            work is one compiled program per group
+            (``_group_prepass_program``).
   execute   two dispatch modes:
               * ``"batched"`` (default) — one batched kernel dispatch per
                 (group, layer segment): the group's schedule becomes the
@@ -46,8 +49,9 @@ tests/test_batched_dispatch.py pin the numerics vs the XLA reference.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -66,9 +70,10 @@ from repro.kernels.dcn_fused import (dcn_fused_batch,
 from repro.kernels.dcn_schedule import (tdt_dispatch_arrays,
                                         tdt_from_coords_device)
 from repro.kernels.ops import round_up
-from repro.obs import Tracer, get_tracer, use_tracer
+from repro.obs import Tracer, default_registry, get_tracer, use_tracer
 from repro.runtime.cache import (ScheduleCache, chain_digest, conv_digest,
-                                 coords_digest, default_schedule_cache)
+                                 coords_digest, default_schedule_cache,
+                                 floors_digest)
 from repro.runtime.graph import (DeformNode, FusedGroup, NetGraph, PoolNode,
                                  Segment, UpsampleNode, boundary_bytes,
                                  group_weight_bytes,
@@ -87,6 +92,13 @@ from repro.runtime.trace import (GroupTrace, LayerBufferStats, NetworkTrace,
                                  TileRecord)
 
 ONCHIP_BUDGET_BYTES = (128 + 256) * 1024   # paper Table I: input + output buf
+
+# Process-wide like core.scheduler.host_schedule_builds: the serving
+# engine keeps a construction-time baseline and reports its delta.
+prepass_programs = default_registry().counter(
+    "executor.prepass_programs",
+    help="fused-group batch prepasses served by the compiled prepass "
+         "program")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -744,6 +756,57 @@ class _BatchGroupArtifacts:
     schedule_device_s: float = 0.0
 
 
+class _DeformPrepass(NamedTuple):
+    """One DCN layer's products of the compiled group prepass, whole
+    batch (device arrays)."""
+
+    coords: jax.Array                     # (N, H, W, KK, 2) f32, read
+    #   only by the device scheduling backend's per-image TDT kernel
+    r0: jax.Array                         # (N, H, W, KK) int32 clipped
+    c0: jax.Array                         #   floors: the cache key's bytes
+    tdt: jax.Array                        # (N, T, T) bool per-image TDTs
+    idx: jax.Array                        # (N*T, p_pad, KK, 4) int32
+    coeff: jax.Array                      # (N*T, p_pad, KK, 4) f32
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "nodes", "grid", "p_pad", "needs_plane", "max_displacement"))
+def _group_prepass_program(planes, params, *, nodes, grid, p_pad,
+                           needs_plane, max_displacement):
+    """A fused group's whole batch prepass as ONE program: the stage-1
+    chain (offset conv, ``offsets_to_coords``, the dense advance) and,
+    per DCN layer, the clipped floors ``coords_digest`` hashes, the
+    per-image TDTs (``tdt_from_coords``) and the plane-order operands
+    (``pack_plane_operands``). ``params`` are arguments, not constants,
+    so no weights are baked into the program; ``nodes`` carry no param
+    index, so groups of one structure share the compiled program.
+    Returns (the advanced plane, or None when no layer advances it;
+    per layer a :class:`_DeformPrepass` or None)."""
+    n = planes.shape[0]
+    plane = planes
+    layers = []
+    for node, p, need in zip(nodes, params, needs_plane):
+        if isinstance(node, DeformNode):
+            offsets = conv2d(plane, p.w_off, p.b_off)
+            coords = offsets_to_coords(offsets.astype(jnp.float32),
+                                       node.kernel_size, node.variant,
+                                       max_displacement)
+            r0 = jnp.clip(jnp.floor(coords[..., 0]), 0, grid.h - 1)
+            c0 = jnp.clip(jnp.floor(coords[..., 1]), 0, grid.w - 1)
+            tdt = jax.vmap(lambda c: tdt_from_coords(c, grid, grid))(coords)
+            idx, coeff = jax.vmap(
+                lambda c: pack_plane_operands(c, grid, p_pad))(coords)
+            rows = (n * grid.num_tiles, p_pad, node.kernel_size ** 2, 4)
+            layers.append(_DeformPrepass(
+                coords, r0.astype(jnp.int32), c0.astype(jnp.int32), tdt,
+                idx.reshape(rows), coeff.reshape(rows)))
+        else:
+            layers.append(None)
+        if need:
+            plane = _advance_dense_batch(plane, node, p, max_displacement)
+    return (plane if any(needs_plane) else None), layers
+
+
 def _group_batch_prepass(
     planes: jax.Array,                    # (N, H, W, C) dense chain state
     group: FusedGroup,
@@ -759,16 +822,21 @@ def _group_batch_prepass(
     plan: ShardPlan | None = None,
     segment: int = 0,
 ) -> tuple[_BatchGroupArtifacts, jax.Array]:
-    """Batch-level prepass for one group: the stage-1 chain runs batched
-    (one XLA dispatch per layer for all images), per-image composite
-    schedules are built in dense form (cached — partial batch hits skip
-    scheduling for the hit images), and the per-layer batch operands are
-    concatenated with per-image base offsets. With the device scheduling
-    backend everything after the digest stays on-device. With a shard
-    ``plan`` the per-layer operands concatenate PER SHARD (each shard
-    keeps its own ragged padding) — per-image schedules themselves are
-    built identically either way, so traces never depend on placement.
-    ``segment`` is the group's index in the partition (span attrs)."""
+    """Batch-level prepass for one group: ONE compiled program
+    (:func:`_group_prepass_program`) runs the stage-1 chain and derives
+    every DCN layer's floors, TDTs and plane-order operands; one fetch
+    brings the TDTs and floors to the host; per-image composite
+    schedules are built in dense form (cached under the floors' digest
+    — partial batch hits skip scheduling for the hit images); the
+    per-layer batch operands are concatenated with per-image base
+    offsets in numpy and uploaded once per layer. With the device
+    scheduling backend the TDT and greedy kernels run per image on the
+    device and only the floors are fetched (for the cache key). With a
+    shard ``plan`` the per-layer operands concatenate PER SHARD (each
+    shard keeps its own ragged padding) — per-image schedules themselves
+    are built identically either way, so traces never depend on
+    placement. ``segment`` is the group's index in the partition (span
+    attrs)."""
     tr = tracer if tracer is not None else get_tracer()
     n = planes.shape[0]
     device = cfg.schedule_backend == "device" and cfg.schedule == "alg1"
@@ -779,48 +847,40 @@ def _group_batch_prepass(
     p_pad = tp if tp % bp == 0 else round_up(tp, cfg.block_p)
     last = group.n_layers - 1
 
-    needs_plane = [need_out_plane
-                   or any(isinstance(nd, DeformNode)
-                          for nd in group.nodes[j + 1:])
-                   for j in range(group.n_layers)]
-    plane = planes
-    coords_layers: list = []
+    needs_plane = tuple(need_out_plane
+                        or any(isinstance(nd, DeformNode)
+                               for nd in group.nodes[j + 1:])
+                        for j in range(group.n_layers))
     with tr.span("prepass.stage1", group=segment, layers=group.n_layers,
                  batch=n):
-        for j, node in enumerate(group.nodes):
-            p = convs[node.param_idx]
-            if isinstance(node, DeformNode):
-                offsets = conv2d(plane, p.w_off, p.b_off)
-                coords_layers.append(offsets_to_coords(
-                    offsets.astype(jnp.float32), node.kernel_size,
-                    node.variant, max_displacement))
-            else:
-                coords_layers.append(None)
-            if needs_plane[j]:
-                plane = _advance_dense_batch(plane, node, p,
-                                             max_displacement)
+        plane, layers = _group_prepass_program(
+            planes, [convs[nd.param_idx] for nd in group.nodes],
+            nodes=tuple(dataclasses.replace(nd, param_idx=0)
+                        for nd in group.nodes),
+            grid=grid, p_pad=p_pad, needs_plane=needs_plane,
+            max_displacement=max_displacement)
+    prepass_programs.inc()
+    if plane is None:
+        plane = planes
 
     def build_bundle(i: int) -> _ImageGroupSched:
-        b_layers: list = []
-        with tr.span("prepass.tdt", backend=cfg.schedule_backend,
-                     image=i):
-            for j, node in enumerate(group.nodes):
-                if coords_layers[j] is None:
-                    B = tdt_standard_conv(grid, grid, node.kernel_size)
-                    b_layers.append(jnp.asarray(B) if device else B)
-                elif device:
-                    b_layers.append(tdt_from_coords_device(
-                        coords_layers[j][i], grid, grid,
-                        interpret=interp))
-                else:
-                    b_layers.append(np.asarray(tdt_from_coords(
-                        coords_layers[j][i], grid, grid)))
         if device:
+            b_layers = [
+                jnp.asarray(tdt_standard_conv(grid, grid, nd.kernel_size))
+                if lay is None else
+                tdt_from_coords_device(lay.coords[i], grid, grid,
+                                       interpret=interp)
+                for nd, lay in zip(group.nodes, layers)]
             comp = compose_tdt_chain_device(b_layers)
             ds = schedule_arrays_device(comp, m, k_pad=k_pad,
                                         interpret=interp)
+            xp = jnp
         else:
-            comp = compose_tdt_chain([np.asarray(b) for b in b_layers])
+            b_layers = [
+                tdt_standard_conv(grid, grid, nd.kernel_size)
+                if tdt is None else tdt[i]
+                for nd, (tdt, _) in zip(group.nodes, fetched)]
+            comp = compose_tdt_chain(b_layers)
             if cfg.schedule == "alg1":
                 sched = schedule_tiles(comp, m)
             elif cfg.schedule == "sequential":
@@ -828,29 +888,38 @@ def _group_batch_prepass(
             else:
                 raise ValueError(f"unknown schedule: {cfg.schedule!r}")
             ds = DeviceSchedule.from_host(sched, t_out)
+            xp = np
         exec_scheds: list = []
         for j, node in enumerate(group.nodes):
             if not isinstance(node, DeformNode):
                 exec_scheds.append(None)
                 continue
-            dep_j, cnt_j = tdt_dispatch_arrays(jnp.asarray(b_layers[j]),
-                                               k_pad)
+            dep_j, cnt_j = tdt_dispatch_arrays(b_layers[j], k_pad)
             if j == last:
-                oid = jnp.asarray(ds.oid).reshape(-1)
-                sel = jnp.maximum(oid, 0)
+                oid = xp.asarray(ds.oid).reshape(-1)
+                sel = xp.maximum(oid, 0)
                 exec_scheds.append(DeviceSchedule(
                     oid, dep_j[sel],
-                    jnp.where(oid >= 0, cnt_j[sel], 0),
-                    jnp.zeros_like(oid)))
+                    xp.where(oid >= 0, cnt_j[sel], 0),
+                    xp.zeros_like(oid)))
             else:
-                ar = jnp.arange(t_out, dtype=jnp.int32)
+                ar = xp.arange(t_out, dtype=xp.int32)
                 exec_scheds.append(DeviceSchedule(
-                    ar, dep_j, cnt_j, jnp.zeros_like(ar)))
+                    ar, dep_j, cnt_j, xp.zeros_like(ar)))
         return _ImageGroupSched(b_layers, exec_scheds, ds)
 
     bundles, hits = [], []
     with tr.timed("prepass.schedule", backend=cfg.schedule_backend,
                   batch=n) as ssp:
+        with tr.span("prepass.tdt", backend=cfg.schedule_backend,
+                     batch=n):
+            # One fetch for the group: the TDTs Algorithm 1 reads on
+            # the host, the floors the cache key hashes.
+            fetched = jax.device_get([
+                (None, None) if lay is None else
+                (None if device else lay.tdt,
+                 None if cache is None else (lay.r0, lay.c0))
+                for lay in layers])
         for i in range(n):
             if cfg.faults is not None:
                 cfg.faults.check("prepass", image=i)
@@ -858,13 +927,10 @@ def _group_batch_prepass(
                 bundles.append(build_bundle(i))
                 hits.append(None)
                 continue
-            digests = []
-            for j, node in enumerate(group.nodes):
-                if coords_layers[j] is None:
-                    digests.append(conv_digest(node.kernel_size, grid))
-                else:
-                    digests.append(coords_digest(coords_layers[j][i],
-                                                 grid))
+            digests = [
+                conv_digest(nd.kernel_size, grid) if floors is None
+                else floors_digest(floors[0][i], floors[1][i], grid)
+                for nd, (_, floors) in zip(group.nodes, fetched)]
             key = (chain_digest(digests, grid), grid.th, grid.tw, m,
                    cfg.schedule, "dense")
             if cfg.faults is not None:
@@ -884,28 +950,23 @@ def _group_batch_prepass(
     layer_ops: list[_BatchLayerOps | None] = []
     with tr.span("pack", dispatch="batch_fused", batch=n,
                  layers=group.n_layers):
-        for j, node in enumerate(group.nodes):
-            if not isinstance(node, DeformNode):
+        for j, lay in enumerate(layers):
+            if lay is None:
                 layer_ops.append(None)
                 continue
-            kk = node.kernel_size ** 2
-            idx, coeff = jax.vmap(
-                lambda c: pack_plane_operands(c, grid, p_pad)
-            )(coords_layers[j])
-            idx = idx.reshape(n * t_out, p_pad, kk, 4)
-            coeff = coeff.reshape(n * t_out, p_pad, kk, 4)
             scheds = [bundles[i].exec_scheds[j] for i in range(n)]
             if plan is not None:
                 layer_ops.append(_BatchLayerOps(
                     None,
-                    stack_rows(idx, plan, t_out),
-                    stack_rows(coeff, plan, t_out),
+                    stack_rows(lay.idx, plan, t_out),
+                    stack_rows(lay.coeff, plan, t_out),
                     shard=shard_batch_schedules(scheds, t_out, t_out,
                                                 plan)))
             else:
                 layer_ops.append(_BatchLayerOps(
-                    pack_batch_schedules(scheds, t_out, t_out),
-                    idx, coeff))
+                    jax.device_put(pack_batch_schedules(scheds, t_out,
+                                                        t_out)),
+                    lay.idx, lay.coeff))
 
     art = _BatchGroupArtifacts(
         grid=grid, m=m, bundles=bundles, cache_hits=hits,

@@ -259,42 +259,46 @@ class BatchDispatch(NamedTuple):
 def pack_batch_schedules(scheds: list[DeviceSchedule], t_in: int,
                          t_out: int) -> BatchDispatch:
     """Batch-stacking path: concatenate per-image dense schedules into
-    one batch grid. Pure jnp over the ``DeviceSchedule`` arrays — device
-    schedules stay on-device end-to-end; host-built schedules (numpy
-    arrays) are uploaded as-is. All images must share the tile grid
-    (same uniform row count per image)."""
+    one batch grid over the ``DeviceSchedule`` arrays. Device schedules
+    go through jnp and stay on-device end-to-end; when every schedule is
+    host-built (numpy arrays) the assembly runs in numpy, op for op the
+    same, and returns numpy arrays for the caller to upload once. All
+    images must share the tile grid (same uniform row count per
+    image)."""
     if not scheds:
         raise ValueError("empty batch")
     n_rows = scheds[0].n_rows
     if any(s.n_rows != n_rows for s in scheds):
         raise ValueError("per-image schedules disagree on row count — "
                          "images in a batch must share the tile grid")
+    xp = np if all(isinstance(a, np.ndarray) for s in scheds
+                   for a in (s.oid, s.dep_tbl, s.dep_cnt)) else jnp
     k_pad = max(s.k_pad for s in scheds)
     rows, deps, cnts, oids, imgs = [], [], [], [], []
     with get_tracer().span("pack.batch_schedules", batch=len(scheds),
                            rows=n_rows):
         for i, s in enumerate(scheds):
-            oid_i = jnp.asarray(s.oid).reshape(-1)
-            dep_i = jnp.asarray(s.dep_tbl)
-            cnt_i = jnp.asarray(s.dep_cnt).reshape(-1)
+            oid_i = xp.asarray(s.oid).reshape(-1)
+            dep_i = xp.asarray(s.dep_tbl)
+            cnt_i = xp.asarray(s.dep_cnt).reshape(-1)
             if dep_i.shape[1] < k_pad:
-                dep_i = jnp.pad(dep_i,
-                                ((0, 0), (0, k_pad - dep_i.shape[1])))
+                dep_i = xp.pad(dep_i,
+                               ((0, 0), (0, k_pad - dep_i.shape[1])))
             valid = oid_i >= 0
             # Padded suffix rows repeat the image's last real dep so
             # their (skipped) grid steps issue no fresh DMA.
-            last_row = jnp.maximum(jnp.sum(valid) - 1, 0)
+            last_row = xp.maximum(xp.sum(valid) - 1, 0)
             last_dep = dep_i[last_row,
-                             jnp.maximum(cnt_i[last_row] - 1, 0)]
-            dep_i = jnp.where(valid[:, None], dep_i, last_dep)
-            rows.append(i * t_out + jnp.maximum(oid_i, 0))
+                             xp.maximum(cnt_i[last_row] - 1, 0)]
+            dep_i = xp.where(valid[:, None], dep_i, last_dep)
+            rows.append(i * t_out + xp.maximum(oid_i, 0))
             deps.append(i * t_in + dep_i)
             cnts.append(cnt_i)
             oids.append(oid_i)
-            imgs.append(jnp.full((n_rows,), i, jnp.int32))
+            imgs.append(xp.full((n_rows,), i, xp.int32))
         return BatchDispatch(
-            row_id=jnp.concatenate(rows).astype(jnp.int32),
-            dep_glb=jnp.concatenate(deps).astype(jnp.int32),
-            dep_cnt=jnp.concatenate(cnts).astype(jnp.int32),
-            oid=jnp.concatenate(oids).astype(jnp.int32),
-            img_id=jnp.concatenate(imgs))
+            row_id=xp.concatenate(rows).astype(xp.int32),
+            dep_glb=xp.concatenate(deps).astype(xp.int32),
+            dep_cnt=xp.concatenate(cnts).astype(xp.int32),
+            oid=xp.concatenate(oids).astype(xp.int32),
+            img_id=xp.concatenate(imgs))

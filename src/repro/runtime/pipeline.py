@@ -503,8 +503,8 @@ def _pipeline_batch_prepass(
             scheds.append(ds)
             hits.append(hit)
         if plan is None:
-            batch = pack_batch_schedules(scheds, grid.num_tiles,
-                                         grid.num_tiles)
+            batch = jax.device_put(pack_batch_schedules(
+                scheds, grid.num_tiles, grid.num_tiles))
             shard = None
         else:
             batch = None

@@ -293,6 +293,7 @@ class DcnServingEngine:
         from repro.runtime import (GraphConfig, LatencyStats, OverlapSpans,
                                    ScheduleCache, build_graph,
                                    clamp_tile_config)
+        from repro.runtime.fused_exec import prepass_programs
         from repro.runtime.pipeline import staging_watchdog_failovers
 
         if not isinstance(cfg, DcnNetConfig):
@@ -358,6 +359,8 @@ class DcnServingEngine:
         self._host_builds0 = host_schedule_builds.count
         self._watchdog = staging_watchdog_failovers
         self._watchdog0 = staging_watchdog_failovers.count
+        self._prepass_programs = prepass_programs
+        self._prepass_programs0 = prepass_programs.count
         # Per-step serving timeline (filled only when the tracer is
         # enabled): step id, coalesced width, dispatch/DRAM accounting
         # — what bench_serving dumps. The step's spans stay in the
@@ -495,6 +498,13 @@ class DcnServingEngine:
         eager operations at new shapes) since this engine was
         constructed; process-wide counter, engine-relative delta."""
         return jax_lowerings.count - self._lowerings0
+
+    @property
+    def prepass_programs(self) -> int:
+        """Fused-group batch prepasses served by the compiled prepass
+        program since this engine was constructed (process-wide
+        counter, engine-relative delta)."""
+        return self._prepass_programs.count - self._prepass_programs0
 
     @property
     def requests_failed(self) -> int:
@@ -996,6 +1006,7 @@ class DcnServingEngine:
                 "steps": self.steps,
                 "host_schedule_builds": self.host_schedule_builds,
                 "compiles": self.compiles,
+                "prepass_programs": self.prepass_programs,
                 "latency": self.latency.summary(),
                 "max_queue": self.max_queue,
                 "queue_policy": self.queue_policy,
@@ -1027,6 +1038,7 @@ class DcnServingEngine:
             m.gauge("serving.host_schedule_builds").set(
                 self.host_schedule_builds)
             m.gauge("serving.compiles").set(self.compiles)
+            m.gauge("serving.prepass_programs").set(self.prepass_programs)
             m.gauge("serving.watchdog_failovers").set(
                 self.watchdog_failovers)
             req = self._m_requests.count

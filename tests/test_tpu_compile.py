@@ -20,12 +20,15 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
+from repro.core.deform import DeformableConvParams
 from repro.core.tiles import TileGrid
 from repro.kernels.dcn_fused import (_dcn_fused_batch_jit,
                                      _dcn_fused_batch_sharded_jit,
                                      _dcn_fused_schedule_jit)
 from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
                                         tdt_from_coords_device)
+from repro.runtime.fused_exec import _group_prepass_program
+from repro.runtime.graph import DeformNode
 
 TILE = 8
 TP = TILE * TILE          # pixels per tile
@@ -145,3 +148,24 @@ def test_sharded_batch_kernel(topo):
              _shape(full, (C_OUT,), jnp.float32),
              mesh=mesh, axis="data", t_in=T_IN, kernel_size=3,
              block_p=128, interpret=False)
+
+
+@pytest.mark.parametrize("plane,c_in,advance", [(28, 256, True),
+                                                (14, 512, False)])
+def test_group_prepass_program(one_chip, plane, c_in, advance):
+    """The compiled prepass of a deformable group at VGG19-8's widths,
+    batch 4: offset conv, coordinates, floors, TDTs, plane-order
+    operands and (for a group a later deformable layer reads) the dense
+    advance. An XLA program: it holds no Pallas kernel."""
+    s = one_chip
+    f32 = jnp.float32
+    params = [DeformableConvParams(_shape(s, (3, 3, c_in, 2 * KK), f32),
+                                   _shape(s, (2 * KK,), f32),
+                                   _shape(s, (3, 3, c_in, C_OUT), f32),
+                                   _shape(s, (C_OUT,), f32))]
+    with jax.default_matmul_precision("highest"):
+        _group_prepass_program.lower(
+            _shape(s, (4, plane, plane, c_in), f32), params,
+            nodes=(DeformNode(0, c_in, C_OUT, plane, plane),),
+            grid=TileGrid(plane, plane, TILE, TILE), p_pad=TP,
+            needs_plane=(advance,), max_displacement=None).compile()
