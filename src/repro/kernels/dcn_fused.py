@@ -265,6 +265,19 @@ def _dcn_fused_schedule_jit(
 # Batch-fused dispatch: ONE pallas_call for the schedules of a whole batch.
 # ---------------------------------------------------------------------------
 
+# SMEM bytes one batch-fused call may give its scalar-prefetched tables
+# (row ids, dep table, dep counts); a TPU v5e core has 1 MiB of SMEM.
+BATCH_PREFETCH_SMEM_BYTES = 256 * 1024
+
+
+def batch_grid_chunk(g: int, k_pad: int) -> int:
+    """Grid rows per batch-fused call: all ``g`` when the tables of ``g``
+    rows fit :data:`BATCH_PREFETCH_SMEM_BYTES`, else the fewest equal
+    chunks that do (224² SegNet layers at batch 8: 6,272 rows)."""
+    fit = max(1, BATCH_PREFETCH_SMEM_BYTES // (4 * (k_pad + 2)))
+    calls = -(-g // fit)
+    return -(-g // calls)
+
 
 def _batch_kernel(row_ref, dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref,
                   w_ref, b_ref, o_ref, acc_ref,
@@ -378,30 +391,46 @@ def _dcn_fused_batch_jit(
         # index, so no DMA is issued for them.
         return (dep[gi, jnp.minimum(k, jnp.maximum(cnt[gi] - 1, 0))], 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(g, p // bp, k_pad),
-        in_specs=[
-            pl.BlockSpec((1, bp * kk, 4),
-                         lambda gi, j, k, row, dep, cnt: (row[gi], j, 0)),
-            pl.BlockSpec((1, bp * kk, 4),
-                         lambda gi, j, k, row, dep, cnt: (row[gi], j, 0)),
-            pl.BlockSpec((1, tp, c), x_index),
-            pl.BlockSpec((kk * c, o),
-                         lambda gi, j, k, row, dep, cnt: (0, 0)),
-            pl.BlockSpec((1, o), lambda gi, j, k, row, dep, cnt: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bp, o),
-                               lambda gi, j, k, row, dep, cnt: (gi, j, 0)),
-        scratch_shapes=[pltpu.VMEM((kk * bp, c), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_batch_kernel, tp=tp, kk=kk, k_pad=k_pad,
-                          t_in=t_in),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, p, o), x_tiles.dtype),
-        interpret=interpret,
-    )(row_id, dep_glb, dep_cnt, idx2, coeff2, x_tiles, w2, b2)
+    def call(row, dep, cnt):
+        rows = row.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(rows, p // bp, k_pad),
+            in_specs=[
+                pl.BlockSpec((1, bp * kk, 4),
+                             lambda gi, j, k, row, dep, cnt: (row[gi], j, 0)),
+                pl.BlockSpec((1, bp * kk, 4),
+                             lambda gi, j, k, row, dep, cnt: (row[gi], j, 0)),
+                pl.BlockSpec((1, tp, c), x_index),
+                pl.BlockSpec((kk * c, o),
+                             lambda gi, j, k, row, dep, cnt: (0, 0)),
+                pl.BlockSpec((1, o), lambda gi, j, k, row, dep, cnt: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, bp, o), lambda gi, j, k, row, dep, cnt: (gi, j, 0)),
+            scratch_shapes=[pltpu.VMEM((kk * bp, c), jnp.float32)],
+        )
+        return pl.pallas_call(
+            functools.partial(_batch_kernel, tp=tp, kk=kk, k_pad=k_pad,
+                              t_in=t_in),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, p, o), x_tiles.dtype),
+            interpret=interpret,
+        )(row, dep, cnt, idx2, coeff2, x_tiles, w2, b2)
+
+    # The three scalar-prefetched tables live in SMEM for the whole call,
+    # so a grid too long for it runs as several calls of equal length;
+    # the last is padded with dep_cnt == 0 rows, whose outputs are cut.
+    chunk = batch_grid_chunk(g, k_pad)
+    if chunk == g:
+        return call(row_id, dep_glb, dep_cnt)
+    pad = -g % chunk
+    row_id = jnp.pad(row_id, (0, pad))
+    dep_glb = jnp.pad(dep_glb, ((0, pad), (0, 0)))
+    dep_cnt = jnp.pad(dep_cnt, (0, pad))
+    return jnp.concatenate(
+        [call(row_id[a:a + chunk], dep_glb[a:a + chunk],
+              dep_cnt[a:a + chunk]) for a in range(0, g + pad, chunk)])[:g]
 
 
 # ---------------------------------------------------------------------------

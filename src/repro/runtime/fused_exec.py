@@ -79,9 +79,10 @@ from repro.runtime.graph import (DeformNode, FusedGroup, NetGraph, PoolNode,
                                  group_weight_bytes,
                                  partition_graph_cached)
 from repro.runtime.packing import (build_neighbour_tables,
-                                   pack_batch_schedules, pack_output_tile,
-                                   pack_plane_operands, pack_schedule_tiles,
-                                   plane_to_tiles, tiles_to_plane)
+                                   narrow_dep_slots, pack_batch_schedules,
+                                   pack_output_tile, pack_plane_operands,
+                                   pack_schedule_tiles, plane_to_tiles,
+                                   tiles_to_plane)
 from repro.runtime.pipeline import (resolve_interpret, run_staged,
                                     validate_dispatch_config)
 from repro.runtime.shard import (ShardPlan, allgather_nbytes,
@@ -99,6 +100,19 @@ prepass_programs = default_registry().counter(
     "executor.prepass_programs",
     help="fused-group batch prepasses served by the compiled prepass "
          "program")
+# Output tiles of the composite schedules built on cache misses (one
+# Algorithm-1 run per group and image), counted while the executor's
+# tracer is enabled, like the ``prepass.alg1`` span around each run.
+alg1_tiles = default_registry().counter(
+    "executor.alg1_tiles",
+    help="output tiles scheduled by Algorithm 1 on schedule-cache misses "
+         "(traced runs only)")
+
+# Least dep-table width of a batch-fused dispatch. At 2-px offsets and
+# 8x8 tiles an output tile reads 9-16 input tiles (SegNet-8 at 224²), so
+# 32 slots hold every batch and one kernel compiles per layer; groups of
+# at most 32 tiles keep their full width.
+DEP_SLOTS_FLOOR = 32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -871,24 +885,28 @@ def _group_batch_prepass(
                 tdt_from_coords_device(lay.coords[i], grid, grid,
                                        interpret=interp)
                 for nd, lay in zip(group.nodes, layers)]
-            comp = compose_tdt_chain_device(b_layers)
-            ds = schedule_arrays_device(comp, m, k_pad=k_pad,
-                                        interpret=interp)
-            xp = jnp
         else:
             b_layers = [
                 tdt_standard_conv(grid, grid, nd.kernel_size)
                 if tdt is None else tdt[i]
                 for nd, (tdt, _) in zip(group.nodes, fetched)]
-            comp = compose_tdt_chain(b_layers)
-            if cfg.schedule == "alg1":
-                sched = schedule_tiles(comp, m)
-            elif cfg.schedule == "sequential":
-                sched = sequential_schedule(comp)
+        with tr.span("prepass.alg1", group=segment, image=i, tiles=t_out):
+            if device:
+                comp = compose_tdt_chain_device(b_layers)
+                ds = schedule_arrays_device(comp, m, k_pad=k_pad,
+                                            interpret=interp)
             else:
-                raise ValueError(f"unknown schedule: {cfg.schedule!r}")
-            ds = DeviceSchedule.from_host(sched, t_out)
-            xp = np
+                comp = compose_tdt_chain(b_layers)
+                if cfg.schedule == "alg1":
+                    sched = schedule_tiles(comp, m)
+                elif cfg.schedule == "sequential":
+                    sched = sequential_schedule(comp)
+                else:
+                    raise ValueError(f"unknown schedule: {cfg.schedule!r}")
+                ds = DeviceSchedule.from_host(sched, t_out)
+        if tr.enabled:
+            alg1_tiles.inc(t_out)
+        xp = jnp if device else np
         exec_scheds: list = []
         for j, node in enumerate(group.nodes):
             if not isinstance(node, DeformNode):
@@ -964,8 +982,9 @@ def _group_batch_prepass(
                                                 plan)))
             else:
                 layer_ops.append(_BatchLayerOps(
-                    jax.device_put(pack_batch_schedules(scheds, t_out,
-                                                        t_out)),
+                    jax.device_put(narrow_dep_slots(
+                        pack_batch_schedules(scheds, t_out, t_out),
+                        DEP_SLOTS_FLOOR)),
                     lay.idx, lay.coeff))
 
     art = _BatchGroupArtifacts(

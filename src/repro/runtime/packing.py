@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.deform import bli_coefficients
-from repro.core.scheduler import DeviceSchedule
+from repro.core.scheduler import DeviceSchedule, pow2_pad
 from repro.core.tiles import TileGrid
 from repro.obs import get_tracer
 
@@ -302,3 +302,21 @@ def pack_batch_schedules(scheds: list[DeviceSchedule], t_in: int,
             dep_cnt=xp.concatenate(cnts).astype(xp.int32),
             oid=xp.concatenate(oids).astype(xp.int32),
             img_id=xp.concatenate(imgs))
+
+
+def narrow_dep_slots(batch: BatchDispatch, floor: int) -> BatchDispatch:
+    """A host-built batch's dep table cut to the slots its rows use: the
+    largest dep count rounded up to a power of two, at least ``floor``
+    and never wider than the table was. The kernel reads no slot past a
+    row's count, so it computes the same over fewer grid steps and less
+    SMEM; ``floor`` keeps the width, and with it the compiled kernel,
+    the same from one batch to the next. Device tables are returned as
+    they are: their largest count would have to wait for the device."""
+    if not isinstance(batch.dep_cnt, np.ndarray):
+        return batch
+    k_pad = batch.dep_glb.shape[1]
+    need = pow2_pad(int(batch.dep_cnt.max(initial=0)))
+    k = min(k_pad, max(floor, need))
+    if k == k_pad:
+        return batch
+    return batch._replace(dep_glb=np.ascontiguousarray(batch.dep_glb[:, :k]))

@@ -293,7 +293,7 @@ class DcnServingEngine:
         from repro.runtime import (GraphConfig, LatencyStats, OverlapSpans,
                                    ScheduleCache, build_graph,
                                    clamp_tile_config)
-        from repro.runtime.fused_exec import prepass_programs
+        from repro.runtime.fused_exec import alg1_tiles, prepass_programs
         from repro.runtime.pipeline import staging_watchdog_failovers
 
         if not isinstance(cfg, DcnNetConfig):
@@ -361,6 +361,8 @@ class DcnServingEngine:
         self._watchdog0 = staging_watchdog_failovers.count
         self._prepass_programs = prepass_programs
         self._prepass_programs0 = prepass_programs.count
+        self._alg1_tiles = alg1_tiles
+        self._alg1_tiles0 = alg1_tiles.count
         # Per-step serving timeline (filled only when the tracer is
         # enabled): step id, coalesced width, dispatch/DRAM accounting
         # — what bench_serving dumps. The step's spans stay in the
@@ -505,6 +507,13 @@ class DcnServingEngine:
         program since this engine was constructed (process-wide
         counter, engine-relative delta)."""
         return self._prepass_programs.count - self._prepass_programs0
+
+    @property
+    def alg1_tiles(self) -> int:
+        """Output tiles Algorithm 1 scheduled on schedule-cache misses
+        since this engine was constructed, counted while a tracer is
+        enabled (process-wide counter, engine-relative delta)."""
+        return self._alg1_tiles.count - self._alg1_tiles0
 
     @property
     def requests_failed(self) -> int:
@@ -1007,6 +1016,7 @@ class DcnServingEngine:
                 "host_schedule_builds": self.host_schedule_builds,
                 "compiles": self.compiles,
                 "prepass_programs": self.prepass_programs,
+                "alg1_tiles": self.alg1_tiles,
                 "latency": self.latency.summary(),
                 "max_queue": self.max_queue,
                 "queue_policy": self.queue_policy,
@@ -1039,6 +1049,7 @@ class DcnServingEngine:
                 self.host_schedule_builds)
             m.gauge("serving.compiles").set(self.compiles)
             m.gauge("serving.prepass_programs").set(self.prepass_programs)
+            m.gauge("serving.alg1_tiles").set(self.alg1_tiles)
             m.gauge("serving.watchdog_failovers").set(
                 self.watchdog_failovers)
             req = self._m_requests.count

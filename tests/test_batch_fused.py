@@ -32,14 +32,16 @@ from repro.core.scheduler import (DeviceSchedule, schedule_arrays_device,
 from repro.core.simulator import simulate_network, simulate_strategies
 from repro.core.tiles import (TileGrid, per_pixel_input_tiles,
                               tdt_from_coords)
+from repro.kernels import dcn_fused
 from repro.kernels.dcn_fused import dcn_fused_batch, dcn_fused_schedule
+from repro.kernels.dcn_schedule import tdt_dispatch_arrays
 from repro.models.dcn_models import DcnNetConfig, init_dcn_net
 from repro.runtime import (GraphConfig, PipelineConfig, ScheduleCache,
                            dcn_pipeline, pack_batch_schedules,
                            pack_plane_operands, pack_schedule_tiles,
                            run_graph, run_graph_dense)
 from repro.runtime.fused_exec import network_sim_specs
-from repro.runtime.packing import build_neighbour_tables
+from repro.runtime.packing import build_neighbour_tables, narrow_dep_slots
 from repro.serving import DcnServingEngine
 
 from tests.test_graph import _acceptance_case
@@ -434,3 +436,88 @@ class TestConfigValidation:
             PipelineConfig(dispatch="fused_batch")
         with pytest.raises(ValueError, match="dispatch"):
             GraphConfig(dispatch="mega")
+
+
+class TestLongBatchGrids:
+    """Grids as long as SegNet-8's 224² layers at batch 8 (6,272 rows):
+    the dep table is cut to the slots its rows use, and a grid whose
+    scalar-prefetched tables outgrow SMEM runs as several equal calls.
+    Both compute exactly what one call over the full table computes."""
+
+    def _case(self, n_imgs=3):
+        grid = TileGrid(24, 24, 4, 4)
+        t, tp = grid.num_tiles, 16
+        key = jax.random.PRNGKey(4)
+        rows = jnp.arange(24.0)[:, None, None]
+        cols = jnp.arange(24.0)[None, :, None]
+        centre = jnp.stack(jnp.broadcast_arrays(rows, cols), -1)
+        coords = jnp.stack([jnp.clip(
+            centre + 1.5 * jax.random.normal(jax.random.fold_in(key, i),
+                                             (24, 24, 9, 2)), 0.0, 23.0)
+            for i in range(n_imgs)])
+        # Plane-order rows over every tile, as the executor dispatches an
+        # interior layer: the table is as wide as the plane has tiles.
+        scheds = []
+        for c in coords:
+            dep, cnt = tdt_dispatch_arrays(
+                np.asarray(tdt_from_coords(c, grid, grid)),
+                scheduler.pow2_pad(t))
+            ar = np.arange(t, dtype=np.int32)
+            scheds.append(DeviceSchedule(ar, dep, cnt, np.zeros_like(ar)))
+        batch = pack_batch_schedules(scheds, t, t)
+        idx, coeff = jax.vmap(
+            lambda c: pack_plane_operands(c, grid, tp))(coords)
+        x = jax.random.normal(jax.random.fold_in(key, 9),
+                              (n_imgs * t, tp, 3))
+        w = jax.random.normal(jax.random.fold_in(key, 10), (9, 3, 5)) * 0.3
+        b = jax.random.normal(jax.random.fold_in(key, 11), (5,)) * 0.1
+
+        def run(bd):
+            return np.asarray(dcn_fused_batch(
+                x, jnp.asarray(bd.row_id), jnp.asarray(bd.dep_glb),
+                jnp.asarray(bd.dep_cnt), idx.reshape(n_imgs * t, tp, 9, 4),
+                coeff.reshape(n_imgs * t, tp, 9, 4), w, b, t_in=t,
+                interpret=True))
+        return batch, run
+
+    def test_narrowed_dep_table_computes_the_same(self):
+        batch, run = self._case()
+        k_pad = batch.dep_glb.shape[1]
+        need = int(batch.dep_cnt.max())
+        assert need < k_pad // 2        # offsets of ~1.5 px: few deps
+        narrow = narrow_dep_slots(batch, 1)
+        k = narrow.dep_glb.shape[1]
+        assert need <= k < 2 * need and k & (k - 1) == 0
+        np.testing.assert_array_equal(run(narrow), run(batch))
+        # The floor holds the width; it never widens the table.
+        assert narrow_dep_slots(batch, k_pad).dep_glb.shape[1] == k_pad
+        assert narrow_dep_slots(batch, 4 * k_pad) is batch
+        # Device tables stay as they are (no wait for their counts).
+        on_device = batch._replace(dep_cnt=jnp.asarray(batch.dep_cnt))
+        assert narrow_dep_slots(on_device, 1) is on_device
+
+    def test_calls_split_to_fit_smem_compute_the_same(self, monkeypatch):
+        batch, run = self._case()
+        g, k_pad = batch.dep_glb.shape
+        whole = run(batch)
+        # Room for 7 rows a call: 108 rows run as 16 calls of 7, the
+        # last padded with skipped rows.
+        monkeypatch.setattr(dcn_fused, "BATCH_PREFETCH_SMEM_BYTES",
+                            7 * 4 * (k_pad + 2))
+        assert dcn_fused.batch_grid_chunk(g, k_pad) == 7
+        jax.clear_caches()
+        try:
+            np.testing.assert_array_equal(run(batch), whole)
+        finally:
+            jax.clear_caches()
+
+    @pytest.mark.parametrize("g,k_pad,chunk", [
+        (64, 16, 64),          # VGG19-8's 28² layers at batch 4: one call
+        (1568, 32, 1568),      # SegNet-8's 112² layers at batch 8
+        (6272, 32, 1568),      # its 224² layer: four calls
+        (6272, 1024, 63),      # the full 784-slot table: 100 calls
+    ])
+    def test_grid_chunk(self, g, k_pad, chunk):
+        got = dcn_fused.batch_grid_chunk(g, k_pad)
+        assert got == chunk
+        assert got * 4 * (k_pad + 2) <= dcn_fused.BATCH_PREFETCH_SMEM_BYTES

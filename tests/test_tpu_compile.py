@@ -169,3 +169,40 @@ def test_group_prepass_program(one_chip, plane, c_in, advance):
             nodes=(DeformNode(0, c_in, C_OUT, plane, plane),),
             grid=TileGrid(plane, plane, TILE, TILE), p_pad=TP,
             needs_plane=(advance,), max_displacement=None).compile()
+
+
+def test_batch_kernel_at_segnet_224(one_chip):
+    """SegNet-8's 224² layer at batch 8: 6,272 grid rows. Its tables
+    (32 dep slots after narrowing) outgrow SMEM in one call, so the
+    program holds four equal calls of the kernel."""
+    s = one_chip
+    rows, t_in, c = 8 * 784, 784, 64
+    text = _compile(_dcn_fused_batch_jit,
+                    _shape(s, (rows, TP, c), jnp.float32),
+                    _shape(s, (rows,), jnp.int32),
+                    _shape(s, (rows, 32), jnp.int32),
+                    _shape(s, (rows,), jnp.int32),
+                    _shape(s, (rows, TP, KK, 4), jnp.int32),
+                    _shape(s, (rows, TP, KK, 4), jnp.float32),
+                    _shape(s, (KK, c, c), jnp.float32),
+                    _shape(s, (c,), jnp.float32),
+                    precision="highest", t_in=t_in, kernel_size=3,
+                    block_p=128, interpret=False)
+    assert text.count("_dcn_fused_batch_jit.") >= 4
+
+
+def test_group_prepass_program_at_segnet_224(one_chip):
+    """The compiled prepass of SegNet-8's last group (224², 64 -> 64,
+    784 tiles, batch 8): its TDTs are 8 x 784 x 784."""
+    s = one_chip
+    f32 = jnp.float32
+    params = [DeformableConvParams(_shape(s, (3, 3, 64, 2 * KK), f32),
+                                   _shape(s, (2 * KK,), f32),
+                                   _shape(s, (3, 3, 64, 64), f32),
+                                   _shape(s, (64,), f32))]
+    with jax.default_matmul_precision("highest"):
+        _group_prepass_program.lower(
+            _shape(s, (8, 224, 224, 64), f32), params,
+            nodes=(DeformNode(0, 64, 64, 224, 224),),
+            grid=TileGrid(224, 224, TILE, TILE), p_pad=TP,
+            needs_plane=(False,), max_displacement=None).compile()
