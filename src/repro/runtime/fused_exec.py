@@ -100,6 +100,10 @@ prepass_programs = default_registry().counter(
     "executor.prepass_programs",
     help="fused-group batch prepasses served by the compiled prepass "
          "program")
+exec_programs = default_registry().counter(
+    "executor.exec_programs",
+    help="fused-group batch executes served by the compiled execute "
+         "programs")
 # Output tiles of the composite schedules built on cache misses (one
 # Algorithm-1 run per group and image), counted while the executor's
 # tracer is enabled, like the ``prepass.alg1`` span around each run.
@@ -281,13 +285,18 @@ def _inter_capacity(cfg: GraphConfig, group: FusedGroup, node,
     return max(1, per_layer // (tp * node.c_out * dtype_bytes))
 
 
-def _tile_valid_mask(grid: TileGrid, tile: int) -> np.ndarray:
-    """(tp, 1) float mask: 1 inside the real H x W plane, 0 on padding."""
-    tr, tc = divmod(tile, grid.cols)
-    rr = np.arange(tr * grid.th, (tr + 1) * grid.th)
-    cc = np.arange(tc * grid.tw, (tc + 1) * grid.tw)
-    valid = (rr[:, None] < grid.h) & (cc[None, :] < grid.w)
-    return valid.reshape(-1, 1).astype(np.float32)
+@functools.lru_cache(maxsize=None)
+def _tile_valid_masks(grid: TileGrid) -> np.ndarray:
+    """(T, tp, 1) float32 masks of every tile: 1 inside the real H x W
+    plane, 0 on padding. They depend on the grid alone, so the stack is
+    built once per grid (read-only: every caller shares it)."""
+    r = np.arange(grid.rows * grid.th).reshape(grid.rows, 1, grid.th, 1)
+    c = np.arange(grid.cols * grid.tw).reshape(1, grid.cols, 1, grid.tw)
+    valid = (r < grid.h) & (c < grid.w)        # (rows, cols, th, tw)
+    masks = valid.reshape(grid.num_tiles, grid.th * grid.tw, 1)
+    masks = masks.astype(np.float32)
+    masks.flags.writeable = False
+    return masks
 
 
 def _assemble_halo(dep_arrays: list, deps: np.ndarray, grid: TileGrid,
@@ -670,8 +679,7 @@ def _run_group(
     dtype_bytes = x_g.dtype.itemsize
 
     x_tiles = plane_to_tiles(x_g, grid)
-    masks = [jnp.asarray(_tile_valid_mask(grid, t), x_g.dtype)
-             for t in range(grid.num_tiles)]
+    masks = [jnp.asarray(m, x_g.dtype) for m in _tile_valid_masks(grid)]
 
     exec_fn = (_exec_group_batched if cfg.dispatch == "batched"
                else _exec_group_per_tile)
@@ -783,6 +791,12 @@ class _DeformPrepass(NamedTuple):
     coeff: jax.Array                      # (N*T, p_pad, KK, 4) f32
 
 
+def _program_nodes(nodes) -> tuple:
+    """A group's nodes as a compiled program's static argument: without
+    their param index, so groups of one structure share the program."""
+    return tuple(dataclasses.replace(nd, param_idx=0) for nd in nodes)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "nodes", "grid", "p_pad", "needs_plane", "max_displacement"))
 def _group_prepass_program(planes, params, *, nodes, grid, p_pad,
@@ -869,8 +883,7 @@ def _group_batch_prepass(
                  batch=n):
         plane, layers = _group_prepass_program(
             planes, [convs[nd.param_idx] for nd in group.nodes],
-            nodes=tuple(dataclasses.replace(nd, param_idx=0)
-                        for nd in group.nodes),
+            nodes=_program_nodes(group.nodes),
             grid=grid, p_pad=p_pad, needs_plane=needs_plane,
             max_displacement=max_displacement)
     prepass_programs.inc()
@@ -994,6 +1007,67 @@ def _group_batch_prepass(
     return art, plane
 
 
+def _conv_chain(planes, params, nodes):
+    """Standard conv layers (+ ReLU) on the whole (N, H, W, C) plane."""
+    for node, p in zip(nodes, params):
+        planes = conv2d(planes, p["w"], p["b"])
+        if node.relu:
+            planes = jax.nn.relu(planes)
+    return planes
+
+
+def _plane_rows(planes, grid: TileGrid):
+    """(N, H, W, C) -> the kernel's (N*T, tp, C) tile rows."""
+    return jax.vmap(lambda pl: plane_to_tiles(pl, grid))(planes).reshape(
+        planes.shape[0] * grid.num_tiles, grid.th * grid.tw, -1)
+
+
+def _rows_plane(rows, grid: TileGrid):
+    """(N*T, tp, C) tile rows in (image, tile) order -> (N, H, W, C)."""
+    return jax.vmap(lambda ti: tiles_to_plane(ti, grid, grid.h, grid.w))(
+        rows.reshape(-1, grid.num_tiles, grid.th * grid.tw, rows.shape[-1]))
+
+
+@functools.partial(jax.jit, static_argnames=("nodes", "grid"))
+def _group_lead_program(planes, params, *, nodes, grid):
+    """A fused group's standard conv layers up to its first DCN layer,
+    on the whole plane; with a ``grid``, the result as that layer's
+    kernel rows. A conv-only group is this program alone (``grid``
+    None): plane in, plane out, no tiles and so no tile masks. Weights
+    are arguments and ``nodes`` carry no param index, as in
+    :func:`_group_prepass_program`."""
+    planes = _conv_chain(planes, params, nodes)
+    return planes if grid is None else _plane_rows(planes, grid)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "nodes", "grid", "n", "relu", "scatter", "to_rows"))
+def _group_post_program(y, oid, row_id, params, *, nodes, grid, n, relu,
+                        scatter, to_rows):
+    """What follows one DCN layer's kernel call in a fused group of
+    ``n`` images: its ReLU; the tile-valid mask of each row's output
+    tile, a constant of the program; for the group's last layer
+    (``scatter``) the scheduled rows back in (image, tile) order; then
+    the standard conv layers up to the group's next DCN layer, on the
+    whole plane. Returns that layer's kernel rows (``to_rows``) or the
+    group's output plane."""
+    t, tp = grid.num_tiles, grid.th * grid.tw
+    y = y[:, :tp]
+    if relu:
+        y = jax.nn.relu(y)
+    masks = jnp.asarray(_tile_valid_masks(grid), y.dtype)    # (T, tp, 1)
+    y = y * masks[jnp.maximum(oid, 0)]
+    if scatter:
+        # Ragged-padding rows fall into a dropped dump row.
+        target = jnp.where(oid >= 0, row_id, n * t)
+        y = jnp.zeros((n * t + 1, tp, y.shape[-1]),
+                      y.dtype).at[target].set(y)[:-1]
+    if to_rows and not nodes:
+        return y             # rows already in (image, tile) order
+    planes = _conv_chain(_rows_plane(y, grid), params, nodes)
+    return _plane_rows(planes, grid) if to_rows else planes
+
+
 def _exec_group_batch_fused(
     planes: jax.Array,                    # (N, H, W, C_in)
     group: FusedGroup,
@@ -1004,89 +1078,97 @@ def _exec_group_batch_fused(
     mesh=None,
     plan: ShardPlan | None = None,
 ) -> tuple[jax.Array, int]:
-    """Execute one fused group for the whole batch: ONE dispatch per
-    layer segment (the batch-fused kernel for DCN layers, one batched
-    XLA conv for standard layers). With ``mesh``/``plan`` each DCN
-    segment stacks its tile rows into per-shard slabs, dispatches the
-    shard_map kernel, and unstacks the scattered result — everything
-    else (conv segments, plane assembly) runs on the TRUE batch with
-    exactly the single-device shapes, so sharded results are bit-equal
-    to the unsharded run (XLA convs can change reduction order with
-    batch size; never giving them a padded pseudo-batch avoids that)."""
+    """Execute one fused group for the whole batch: ONE kernel dispatch
+    per DCN layer, everything around the kernels compiled. A conv-only
+    group is one program (:func:`_group_lead_program`); a group with
+    DCN layers is its lead program, then per DCN layer the batch-fused
+    kernel and :func:`_group_post_program`. No tile mask is uploaded.
+    With ``mesh``/``plan`` a group with DCN layers runs
+    :func:`_exec_group_sharded`. Returns (the group's output plane,
+    layer segments dispatched)."""
     n = planes.shape[0]
     if cfg.faults is not None:
         cfg.faults.check("dispatch", images=plan.n if plan else n)
+    deform = [j for j, nd in enumerate(group.nodes)
+              if isinstance(nd, DeformNode)]
+    if plan is not None and deform:
+        return (_exec_group_sharded(planes, group, convs, cfg, interpret,
+                                    art, mesh, plan), group.n_layers)
     grid = art.grid
-    h, w = grid.h, grid.w
+    nodes = _program_nodes(group.nodes)
+    params = [convs[nd.param_idx] for nd in group.nodes]
+    cuts = deform + [group.n_layers]
+    x = _group_lead_program(planes, params[:cuts[0]], nodes=nodes[:cuts[0]],
+                            grid=grid if deform else None)
+    for j, nxt in zip(cuts, cuts[1:]):
+        node, p, ops = group.nodes[j], params[j], art.layer_ops[j]
+        kk = node.kernel_size ** 2
+        y = dcn_fused_batch(
+            x, ops.batch.row_id, ops.batch.dep_glb, ops.batch.dep_cnt,
+            ops.idx, ops.coeff, p.w.reshape(kk, node.c_in, node.c_out), p.b,
+            t_in=grid.num_tiles, kernel_size=node.kernel_size,
+            block_p=cfg.block_p, interpret=interpret)
+        x = _group_post_program(
+            y, ops.batch.oid, ops.batch.row_id, params[j + 1:nxt],
+            nodes=nodes[j + 1:nxt], grid=grid, n=n, relu=node.relu,
+            scatter=j == group.n_layers - 1, to_rows=nxt < group.n_layers)
+    exec_programs.inc()
+    return x, group.n_layers
+
+
+def _exec_group_sharded(
+    planes: jax.Array,                    # (N, H, W, C_in)
+    group: FusedGroup,
+    convs: list,
+    cfg: GraphConfig,
+    interpret: bool,
+    art: _BatchGroupArtifacts,
+    mesh,
+    plan: ShardPlan,
+) -> jax.Array:
+    """A fused group with DCN layers over a mesh: each DCN segment
+    stacks its tile rows into per-shard slabs, dispatches the shard_map
+    kernel, and unstacks the scattered result — everything else (conv
+    segments, plane assembly) runs on the TRUE batch with exactly the
+    single-device shapes, so sharded results are bit-equal to the
+    unsharded run (XLA convs can change reduction order with batch
+    size; never giving them a padded pseudo-batch avoids that)."""
+    grid = art.grid
     tp = grid.th * grid.tw
     t = grid.num_tiles
-    masks_arr = jnp.stack(
-        [jnp.asarray(_tile_valid_mask(grid, ti), planes.dtype)
-         for ti in range(t)])                               # (T, tp, 1)
-    last = group.n_layers - 1
+    masks_arr = jnp.asarray(_tile_valid_masks(grid), planes.dtype)
 
-    flat = jax.vmap(
-        lambda p: plane_to_tiles(p, grid))(planes).reshape(n * t, tp, -1)
-    dispatches = 0
+    flat = _plane_rows(planes, grid)
     for j, node in enumerate(group.nodes):
         p = convs[node.param_idx]
         if isinstance(node, DeformNode):
             ops = art.layer_ops[j]
             kk = node.kernel_size ** 2
             w2 = p.w.reshape(kk, node.c_in, node.c_out)
-            if plan is not None:
-                sh = ops.shard
-                d = plan.n_shards
-                slab = plan.n_max * t
-                y = dcn_fused_batch_sharded(
-                    stack_rows(flat, plan, t), sh.row_id, sh.dep_glb,
-                    sh.dep_cnt, ops.idx, ops.coeff, w2, p.b, mesh=mesh,
-                    t_in=t, kernel_size=node.kernel_size,
-                    block_p=cfg.block_p, interpret=interpret)[:, :, :tp]
-                if node.relu:
-                    y = jax.nn.relu(y)
-                y = y * masks_arr[jnp.maximum(sh.oid, 0)]
-                # Scatter each shard's scheduled rows back to shard-
-                # local (image, tile) order — padding rows (ragged
-                # schedules or shard-size fill) land in a dropped per-
-                # shard dump row — then unstack to true batch rows.
-                target = jnp.where(sh.oid >= 0, sh.row_id, slab)
-                y_all = jnp.zeros((d, slab + 1, tp, node.c_out), y.dtype)
-                y_all = jax.vmap(lambda ya, tg, yy: ya.at[tg].set(yy))(
-                    y_all, target, y)
-                flat = unstack_rows(y_all[:, :-1], plan, t)
-            else:
-                y = dcn_fused_batch(
-                    flat, ops.batch.row_id, ops.batch.dep_glb,
-                    ops.batch.dep_cnt, ops.idx, ops.coeff, w2, p.b,
-                    t_in=t, kernel_size=node.kernel_size,
-                    block_p=cfg.block_p, interpret=interpret)[:, :tp]
-                if node.relu:
-                    y = jax.nn.relu(y)
-                y = y * masks_arr[jnp.maximum(ops.batch.oid, 0)]
-                if j == last:
-                    # Scatter scheduled rows back to (image, tile) order;
-                    # ragged-padding rows fall into a dropped dump row.
-                    target = jnp.where(ops.batch.oid >= 0,
-                                       ops.batch.row_id, n * t)
-                    y_all = jnp.zeros((n * t + 1, tp, node.c_out),
-                                      y.dtype)
-                    flat = y_all.at[target].set(y)[:-1]
-                else:
-                    flat = y         # rows already in (img, tile) order
-        else:
-            pl_j = jax.vmap(lambda ti: tiles_to_plane(ti, grid, h, w))(
-                flat.reshape(n, t, tp, node.c_in))
-            yp = conv2d(pl_j, p["w"], p["b"])
+            sh = ops.shard
+            d = plan.n_shards
+            slab = plan.n_max * t
+            y = dcn_fused_batch_sharded(
+                stack_rows(flat, plan, t), sh.row_id, sh.dep_glb,
+                sh.dep_cnt, ops.idx, ops.coeff, w2, p.b, mesh=mesh,
+                t_in=t, kernel_size=node.kernel_size,
+                block_p=cfg.block_p, interpret=interpret)[:, :, :tp]
             if node.relu:
-                yp = jax.nn.relu(yp)
-            flat = jax.vmap(
-                lambda pj: plane_to_tiles(pj, grid))(yp).reshape(
-                    n * t, tp, node.c_out)
-        dispatches += 1
-    out = jax.vmap(lambda ti: tiles_to_plane(ti, grid, h, w))(
-        flat.reshape(n, t, tp, group.c_out))
-    return out, dispatches
+                y = jax.nn.relu(y)
+            y = y * masks_arr[jnp.maximum(sh.oid, 0)]
+            # Scatter each shard's scheduled rows back to shard-local
+            # (image, tile) order — padding rows (ragged schedules or
+            # shard-size fill) land in a dropped per-shard dump row —
+            # then unstack to true batch rows.
+            target = jnp.where(sh.oid >= 0, sh.row_id, slab)
+            y_all = jnp.zeros((d, slab + 1, tp, node.c_out), y.dtype)
+            y_all = jax.vmap(lambda ya, tg, yy: ya.at[tg].set(yy))(
+                y_all, target, y)
+            flat = unstack_rows(y_all[:, :-1], plan, t)
+        else:
+            flat = _plane_rows(_conv_chain(_rows_plane(flat, grid), [p],
+                                           [node]), grid)
+    return _rows_plane(flat, grid)
 
 
 def _batch_fused_group_traces(

@@ -293,7 +293,8 @@ class DcnServingEngine:
         from repro.runtime import (GraphConfig, LatencyStats, OverlapSpans,
                                    ScheduleCache, build_graph,
                                    clamp_tile_config)
-        from repro.runtime.fused_exec import alg1_tiles, prepass_programs
+        from repro.runtime.fused_exec import (alg1_tiles, exec_programs,
+                                              prepass_programs)
         from repro.runtime.pipeline import staging_watchdog_failovers
 
         if not isinstance(cfg, DcnNetConfig):
@@ -361,6 +362,8 @@ class DcnServingEngine:
         self._watchdog0 = staging_watchdog_failovers.count
         self._prepass_programs = prepass_programs
         self._prepass_programs0 = prepass_programs.count
+        self._exec_programs = exec_programs
+        self._exec_programs0 = exec_programs.count
         self._alg1_tiles = alg1_tiles
         self._alg1_tiles0 = alg1_tiles.count
         # Per-step serving timeline (filled only when the tracer is
@@ -507,6 +510,13 @@ class DcnServingEngine:
         program since this engine was constructed (process-wide
         counter, engine-relative delta)."""
         return self._prepass_programs.count - self._prepass_programs0
+
+    @property
+    def exec_programs(self) -> int:
+        """Fused-group batch executes served by the compiled execute
+        programs since this engine was constructed (process-wide
+        counter, engine-relative delta)."""
+        return self._exec_programs.count - self._exec_programs0
 
     @property
     def alg1_tiles(self) -> int:
@@ -1016,6 +1026,7 @@ class DcnServingEngine:
                 "host_schedule_builds": self.host_schedule_builds,
                 "compiles": self.compiles,
                 "prepass_programs": self.prepass_programs,
+                "exec_programs": self.exec_programs,
                 "alg1_tiles": self.alg1_tiles,
                 "latency": self.latency.summary(),
                 "max_queue": self.max_queue,
@@ -1049,6 +1060,7 @@ class DcnServingEngine:
                 self.host_schedule_builds)
             m.gauge("serving.compiles").set(self.compiles)
             m.gauge("serving.prepass_programs").set(self.prepass_programs)
+            m.gauge("serving.exec_programs").set(self.exec_programs)
             m.gauge("serving.alg1_tiles").set(self.alg1_tiles)
             m.gauge("serving.watchdog_failovers").set(
                 self.watchdog_failovers)

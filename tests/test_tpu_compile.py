@@ -27,8 +27,10 @@ from repro.kernels.dcn_fused import (_dcn_fused_batch_jit,
                                      _dcn_fused_schedule_jit)
 from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
                                         tdt_from_coords_device)
-from repro.runtime.fused_exec import _group_prepass_program
-from repro.runtime.graph import DeformNode
+from repro.runtime.fused_exec import (_group_lead_program,
+                                      _group_post_program,
+                                      _group_prepass_program)
+from repro.runtime.graph import ConvNode, DeformNode
 
 TILE = 8
 TP = TILE * TILE          # pixels per tile
@@ -206,3 +208,39 @@ def test_group_prepass_program_at_segnet_224(one_chip):
             nodes=(DeformNode(0, 64, 64, 224, 224),),
             grid=TileGrid(224, 224, TILE, TILE), p_pad=TP,
             needs_plane=(False,), max_displacement=None).compile()
+
+
+@pytest.mark.parametrize("n,c_in,tiled", [(4, 64, False), (8, 64, True)])
+def test_group_lead_program_at_224(one_chip, n, c_in, tiled):
+    """A conv-only group's whole execute at 224² (64 -> 64): one conv
+    and its ReLU on the plane. With a grid and no conv before the
+    group's first DCN layer, the plane as SegNet-8's 224² kernel rows
+    (8 x 784 tiles)."""
+    s = one_chip
+    f32 = jnp.float32
+    nodes, params = (), []
+    if not tiled:
+        nodes = (ConvNode(0, c_in, 64, 224, 224),)
+        params = [{"w": _shape(s, (3, 3, c_in, 64), f32),
+                   "b": _shape(s, (64,), f32)}]
+    with jax.default_matmul_precision("highest"):
+        _group_lead_program.lower(
+            _shape(s, (n, 224, 224, c_in), f32), params, nodes=nodes,
+            grid=TileGrid(224, 224, TILE, TILE) if tiled else None
+        ).compile()
+
+
+@pytest.mark.parametrize("n,plane,c_out", [(4, 28, 512), (8, 224, 64)])
+def test_group_post_program(one_chip, n, plane, c_out):
+    """What follows a group's last DCN layer: ReLU, the tile-valid
+    masks as a constant, the scatter of the scheduled rows and the
+    untile, at VGG19-8's 28² layer (C 512, batch 4) and SegNet-8's 224²
+    layer (8 x 784 rows scattered)."""
+    s = one_chip
+    grid = TileGrid(plane, plane, TILE, TILE)
+    rows = n * grid.num_tiles
+    _group_post_program.lower(
+        _shape(s, (rows, TP, c_out), jnp.float32),
+        _shape(s, (rows,), jnp.int32), _shape(s, (rows,), jnp.int32), [],
+        nodes=(), grid=grid, n=n, relu=True, scatter=True,
+        to_rows=False).compile()
