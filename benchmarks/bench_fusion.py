@@ -71,7 +71,7 @@ def run_executor(csv=print, h: int = 16, w: int = 16, c: int = 16,
     # The planner's input-halo term (fusion.fused_tile_bytes, halo=2):
     # the component that models the packed input buffer specifically.
     modeled_input = (3 * tile) ** 2 * c * dtype_bytes
-    measured = trace.images[0].max_buffer_bytes
+    measured = trace.groups[0].max_buffer_bytes
     csv(f"fusion_xcheck,measured_packed_input_bytes={measured},"
         f"modeled_input_halo_bytes={modeled_input},"
         f"modeled_fused_tile_bytes={modeled_total},"

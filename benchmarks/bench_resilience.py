@@ -128,8 +128,8 @@ def run(csv=print, img: int = 13, n_deform: int = 2,
         return DcnServingEngine(params, cfg, **kw)
 
     # Warm every compile path the chaos run can reach: fused widths the
-    # coalesced/retry steps use, and the degraded per-image batched path
-    # (forced via one untagged fault — the jit cache is process-global,
+    # coalesced/retry steps use, and the degraded one-image-at-a-time
+    # path (forced via one untagged fault — the jit cache is process-global,
     # so this compile never lands mid-measurement).
     warm = engine()
     for w in (1, slots - 1, slots):

@@ -25,7 +25,8 @@ from repro.core.simulator import dram_energy, simulate_strategies
 from repro.core.tiles import TileGrid, per_pixel_input_tiles, tdt_from_coords
 from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
                                         tdt_from_coords_device)
-from repro.runtime import PipelineConfig, dcn_pipeline, resolve_interpret
+from repro.kernels.ops import resolve_interpret
+from repro.runtime import GraphConfig, dcn_pipeline
 
 from benchmarks.workloads import (NETWORKS, executor_case, measured_tdt,
                                   net_label)
@@ -77,7 +78,8 @@ def run_executor(csv=print, h: int = 24, w: int = 24, c: int = 16,
                  seed: int = 0):
     """Simulator-vs-executor cross-check on one real deformable layer.
 
-    Runs the tile-pipeline executor (repro.runtime) on a real batch and
+    Runs one deformable layer through the graph executor
+    (``repro.runtime.dcn_pipeline``) on a real batch and
     compares its *actual* packed-tile traffic against the traffic
     simulator's predictions for the same coordinates/grid/buffer:
 
@@ -103,15 +105,17 @@ def run_executor(csv=print, h: int = 24, w: int = 24, c: int = 16,
                                   dtype_bytes=dtype_bytes)
 
     sim = reports["scheduled"]
-    exec_fifo = trace.fifo_loads()
+    exec_fifo = sum(g.fifo_replay().loads for g in trace.groups)
     csv(f"executor_xcheck,sim_scheduled_loads={sim.tile_loads},"
         f"exec_fifo_loads={exec_fifo},"
         f"match={'yes' if sim.tile_loads == exec_fifo else 'NO'}")
     csv(f"executor_xcheck,sim_scheduled_bytes={sim.input_read_bytes},"
         f"exec_fifo_bytes={exec_fifo * tile_bytes},"
-        f"exec_packed_bytes_no_reuse={trace.packed_bytes},"
+        f"exec_packed_bytes_no_reuse="
+        f"{sum(g.packed_bytes for g in trace.groups)},"
         f"tdt_dep_count={int(B.sum())},"
-        f"exec_packed_tiles={trace.packed_tile_loads}")
+        f"exec_packed_tiles="
+        f"{sum(g.packed_tile_loads for g in trace.groups)}")
     return reports, trace
 
 
@@ -179,9 +183,9 @@ def run_backends(csv=print, h: int = 24, w: int = 24, c: int = 8,
         f"host_prepass_reduced={'yes' if reduced else 'NO'}")
 
     for backend in ("host", "device"):
-        cfg = PipelineConfig(tile=tile, buffer_tiles=m,
-                             use_schedule_cache=False,
-                             schedule_backend=backend)
+        cfg = GraphConfig(tile=tile, buffer_tiles=m,
+                          use_schedule_cache=False,
+                          schedule_backend=backend)
         dcn_pipeline(x, params, config=cfg)              # warm
         with Stopwatch() as sw:
             y, tr = dcn_pipeline(x, params, config=cfg, return_trace=True)
@@ -201,19 +205,19 @@ def run_backends(csv=print, h: int = 24, w: int = 24, c: int = 8,
 def run_batch_fused(csv=print, h: int = 16, w: int = 16, c: int = 8,
                     c_out: int = 8, tile: int = 8, buffer_tiles: int = 4,
                     batch: int = 4, repeats: int = 3, seed: int = 0):
-    """ISSUE 5 acceptance: whole-batch fused dispatch vs per-image
-    batched dispatch on one real deformable layer.
+    """Whole-batch fused dispatch vs the per-tile dispatch loop on one
+    real deformable layer.
 
     Measures, for both scheduling backends:
 
       * ``dispatches_per_batch`` — host-issued kernel dispatches for the
-        whole batch (batch-fused must be 1 for a single layer, vs
-        ``batch`` for per-image batched dispatch);
+        whole batch (batch-fused must be 1 for a single layer, vs one
+        per scheduled tile for per-tile dispatch);
       * ``host_prepass_residue_s`` — host wall time of the batch prepass.
         With ``schedule_backend="device"`` this is the zero-round-trip
         residue (digesting + async kernel launches: no host TDT, no
         Algorithm-1 loop, no ``TileSchedule`` reassembly);
-      * batch-fused vs per-image batched wall-clock.
+      * batch-fused vs per-tile wall-clock.
 
     Also checks the two dispatch modes agree numerically (match gate).
     """
@@ -233,28 +237,28 @@ def run_batch_fused(csv=print, h: int = 16, w: int = 16, c: int = 8,
 
     out = {}
     for backend in ("host", "device"):
-        y_b, tr_b, wall_b = best(PipelineConfig(
-            tile=tile, buffer_tiles=buffer_tiles, dispatch="batched",
+        y_p, tr_p, wall_p = best(GraphConfig(
+            tile=tile, buffer_tiles=buffer_tiles, dispatch="per_tile",
             schedule_backend=backend, use_schedule_cache=False))
-        y_f, tr_f, wall_f = best(PipelineConfig(
+        y_f, tr_f, wall_f = best(GraphConfig(
             tile=tile, buffer_tiles=buffer_tiles, dispatch="batch_fused",
             schedule_backend=backend, use_schedule_cache=False))
         err = float(jnp.max(jnp.abs(y_f.astype(jnp.float32)
-                                    - y_b.astype(jnp.float32))))
+                                    - y_p.astype(jnp.float32))))
         match = err < 1e-5
         residue = tr_f.overlap.prepass_s
         csv(f"batch_fused,backend={backend},batch={batch},"
             f"dispatches_per_batch={tr_f.dispatches_per_batch},"
-            f"batched_dispatches={tr_b.kernel_dispatches},"
+            f"per_tile_dispatches={tr_p.kernel_dispatches},"
             f"host_prepass_residue_s={residue:.6f},"
             f"batch_fused_wall_s={wall_f:.4f},"
-            f"batched_wall_s={wall_b:.4f},"
+            f"per_tile_wall_s={wall_p:.4f},"
             f"match={'yes' if match else 'NO'}")
         out[backend] = dict(dispatches_per_batch=tr_f.dispatches_per_batch,
-                            batched_dispatches=tr_b.kernel_dispatches,
+                            per_tile_dispatches=tr_p.kernel_dispatches,
                             host_prepass_residue_s=residue,
                             batch_fused_wall_s=wall_f,
-                            batched_wall_s=wall_b, match=match)
+                            per_tile_wall_s=wall_p, match=match)
     return out
 
 

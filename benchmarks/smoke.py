@@ -15,13 +15,14 @@ BENCH_scheduling.json carries the host-vs-device scheduling-backend
 numbers (``sched_host_s_per_img`` etc.). The run exits nonzero (failing
 the CI bench-smoke job) if:
 
-  * the batched dispatch count regresses to or above the per-tile
+  * the batch-fused dispatch count regresses to or above the per-tile
     baseline (ISSUE 3 gate);
   * the device scheduling backend is not bit-exact vs the host, or does
     not strictly reduce host scheduling time per image (ISSUE 4 gate);
-  * batch-fused dispatch (batch=4) does not hit exactly ONE kernel
-    dispatch per layer segment, or disagrees numerically with per-image
-    batched dispatch on either scheduling backend (ISSUE 5 gate);
+  * batch-fused dispatch does not hit exactly ONE kernel dispatch per
+    layer segment (below the same images run one at a time), or
+    disagrees numerically with per-tile dispatch on either scheduling
+    backend (ISSUE 5 gate);
   * continuous-batching serving (slot pool >= 4) does not beat the
     serve-one-at-a-time baseline by >= 1.5x requests/sec on the
     open-loop arrival benchmark (ISSUE 6 gate — BENCH_serving.json
@@ -53,8 +54,8 @@ the CI bench-smoke job) if:
     FRESH plan cache over the same directory (second-run disk hit);
   * ``--compare BASELINE_DIR`` is given (previous main-branch
     ``BENCH_*.json`` artifacts) and scheduled DRAM tile loads or a
-    dispatch count (batched per-image, batch-fused at batch>1, or
-    serving dispatches/step) regress more than 10% against the
+    dispatch count (batch-fused, or serving dispatches/step) regress
+    more than 10% against the
     baseline, or serving requests/sec or the serving schedule-cache
     image hit rate drops more than 10% below it (direction-aware:
     rps and hit rate are higher-is-better), or the chaos bench loses
@@ -123,7 +124,7 @@ def _record(payload: dict, label: str) -> dict | None:
 
 def _compare_baseline(baseline_dir: str, suites: dict) -> int:
     """CI bench-regression gate: scheduled DRAM tile loads and the
-    batched dispatch count must stay within 10% of the previous
+    batch-fused dispatch count must stay within 10% of the previous
     main-branch artifacts. A missing baseline (first run, expired
     artifact) is a warning, not a failure."""
     rc = 0
@@ -137,7 +138,7 @@ def _compare_baseline(baseline_dir: str, suites: dict) -> int:
         ("BENCH_scheduling.json", "scheduled DRAM tile loads",
          lambda p: int(_record(p, "fig16_layer")["scheduled_loads"]),
          "lower"),
-        ("BENCH_graph.json", "batched dispatch count",
+        ("BENCH_graph.json", "batch-fused dispatch count",
          lambda p: int(p["dispatch_count"]), "lower"),
         ("BENCH_graph.json", "batch-fused dispatch count (batch>1)",
          lambda p: int(p["batch_fused_dispatch_count"]), "lower"),
@@ -204,10 +205,10 @@ def _compare_baseline(baseline_dir: str, suites: dict) -> int:
 
 
 def _gate_graph(suites: dict) -> int:
-    """ISSUE 3 + 5 gates: the batched grid dispatch must stay strictly
-    below the per-tile baseline, and at batch=4 the whole-batch fused
-    path must issue exactly ONE kernel dispatch per layer segment,
-    strictly below the per-image batched count."""
+    """ISSUE 3 + 5 gates: the batch-fused grid dispatch must stay
+    strictly below the per-tile baseline, and the whole-batch fused path
+    must issue exactly ONE kernel dispatch per layer segment, strictly
+    below the same images run one at a time."""
     if "BENCH_graph.json" not in suites:
         return 0
     rc = 0
@@ -218,17 +219,18 @@ def _gate_graph(suites: dict) -> int:
         rc = 1
     else:
         per_tile = int(bench["per_tile_dispatches"])
-        batched = int(bench["batched_dispatches"])
-        graph_payload["dispatch_count"] = batched
+        fused = int(bench["batch_fused_dispatches"])
+        graph_payload["dispatch_count"] = fused
         graph_payload["per_tile_dispatch_count"] = per_tile
         graph_payload["host_overlap_frac"] = float(
             bench["host_overlap_frac"])
-        if batched >= per_tile:
-            print(f"ERROR: dispatch_count regressed: batched={batched} "
+        if fused >= per_tile:
+            print(f"ERROR: dispatch_count regressed: batch_fused={fused} "
                   f">= per_tile baseline={per_tile}")
             rc = 1
         if bench["dispatches_le_segments"] != "yes":
-            print("ERROR: batched dispatches exceed layer-segment bound")
+            print("ERROR: batch-fused dispatches exceed layer-segment "
+                  "bound")
             rc = 1
 
     bf = _record(graph_payload, "batch_fused_bench")
@@ -246,10 +248,10 @@ def _gate_graph(suites: dict) -> int:
                   f"one per layer segment ({bf['n_segments']}) at "
                   f"batch={bf['batch']}")
             rc = 1
-        if bf_dispatches >= int(bf["batched_dispatches"]):
+        if bf_dispatches >= int(bf["one_at_a_time_dispatches"]):
             print(f"ERROR: batch-fused dispatch count regressed: "
-                  f"{bf_dispatches} >= per-image batched "
-                  f"{bf['batched_dispatches']}")
+                  f"{bf_dispatches} >= one image at a time "
+                  f"{bf['one_at_a_time_dispatches']}")
             rc = 1
     return rc
 
@@ -257,8 +259,8 @@ def _gate_graph(suites: dict) -> int:
 def _gate_scheduling(suites: dict) -> int:
     """ISSUE 4 gate: the device scheduler must be bit-exact vs the host
     and strictly reduce the host-side scheduling time per image; the
-    pipeline batch-fused records must match batched numerics at one
-    dispatch per batch."""
+    single-layer batch-fused records must match per-tile numerics at
+    one dispatch per batch."""
     if "BENCH_scheduling.json" not in suites:
         return 0
     rc = 0
@@ -296,13 +298,13 @@ def _gate_scheduling(suites: dict) -> int:
         sched_payload[f"batch_fused_{r['backend']}_residue_s"] = float(
             r["host_prepass_residue_s"])
         if r["match"] != "yes":
-            print(f"ERROR: batch-fused != batched numerics "
+            print(f"ERROR: batch-fused != per-tile numerics "
                   f"(backend={r['backend']})")
             rc = 1
-        if int(r["dispatches_per_batch"]) >= int(r["batched_dispatches"]):
-            print(f"ERROR: pipeline batch-fused dispatches "
-                  f"({r['dispatches_per_batch']}) not below per-image "
-                  f"batched ({r['batched_dispatches']})")
+        if int(r["dispatches_per_batch"]) >= int(r["per_tile_dispatches"]):
+            print(f"ERROR: single-layer batch-fused dispatches "
+                  f"({r['dispatches_per_batch']}) not below per-tile "
+                  f"({r['per_tile_dispatches']})")
             rc = 1
     return rc
 

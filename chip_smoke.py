@@ -13,8 +13,8 @@ One chip (default):
   1. device check: the first device is a TPU and Pallas kernels compile
      (not interpret mode); otherwise exit 1 before any result;
   2. reference logits from ``dcn_net_apply(..., backend="xla")``;
-  3. ``DcnServingEngine`` with the default ``GraphConfig()`` (per-image
-     batched dispatch, host scheduling) answers one ``infer()``;
+  3. ``DcnServingEngine`` with the default ``GraphConfig()`` (batch-fused
+     dispatch, host scheduling) answers one ``infer()``;
   4. ``DcnServingEngine`` with ``dispatch="batch_fused"``,
      ``schedule_backend="device"`` and 4 slots serves requests of 1-3
      images through ``submit()``/``drain()``, twice (cold, then warm);

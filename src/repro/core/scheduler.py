@@ -24,7 +24,7 @@ the step loop becomes the kernel grid, the resident-set bitmask lives in
 VMEM, and the host only reassembles the emitted order — matching the
 paper's on-chip scheduler architecture. Both backends are bit-exact:
 they produce byte-identical ``TileSchedule``s on every input. On TPU the
-schedule orders the Pallas grid / DMA sequence (see DESIGN.md §2).
+schedule orders the Pallas grid / DMA sequence (``kernels.dcn_fused``).
 
 The module also provides the two ablation baselines of paper Fig. 14-16:
 ``sequential_schedule`` (W/ bit vector + W/O scheduling) and the naive
@@ -78,11 +78,11 @@ class TileSchedule:
 
     def dense(self, k_pad: int | None = None
               ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Schedule as dense arrays for batched grid dispatch.
+        """Schedule as dense arrays for the kernel grid.
 
-        The batched executor feeds the schedule to ONE ``pallas_call``
+        The batch-fused kernel runs the schedule as ONE ``pallas_call``
         whose leading grid dimension is the scheduled-tile index, so it
-        needs arrays, not Python lists:
+        needs arrays, not Python lists (:meth:`DeviceSchedule.from_host`):
 
           oid    (T,)        int32 — output tiles in execution order
           deps   (T, k_pad)  int32 — dependent input tiles in load order,
@@ -290,8 +290,8 @@ def sequential_schedule(B: np.ndarray) -> TileSchedule:
 class DeviceSchedule:
     """Algorithm-1 schedule as dense dispatch-ready arrays.
 
-    The batch-fused executors consume schedules in exactly the dense form
-    the batched kernel's scalar-prefetch machinery needs, so with
+    The batch-fused executor consumes schedules in exactly the dense form
+    the batch-fused kernel's scalar-prefetch machinery needs, so with
     ``schedule_backend="device"`` the greedy kernel's outputs flow here
     as device arrays end-to-end — no host reassembly, no Python
     ``TileSchedule`` on the hot path. All arrays have ``n_out`` rows

@@ -17,12 +17,13 @@ a ``(bp*KK, C) -> (bp, KK*C)`` reshape would move sublanes into lanes,
 which Mosaic cannot lower unless C is a multiple of 128.
 
 Two entry points: ``dcn_fused_tile`` computes ONE output tile per call
-(the per-tile dispatch loop), ``dcn_fused_schedule`` runs a whole
-Algorithm-1 tile schedule as a single ``pallas_call`` grid — the
-scheduled-tile index is the leading grid dimension and a
+(the per-tile dispatch loop), ``dcn_fused_batch`` runs the concatenated
+Algorithm-1 tile schedules of a whole batch as a single ``pallas_call``
+grid — the scheduled-tile index is the leading grid dimension and a
 scalar-prefetched dep table drives the input-tile DMA sequence, so the
 scheduled tiles stream back-to-back through the core with no per-tile
 host dispatch (the paper's §IV-C execution model).
+``dcn_fused_batch_sharded`` runs that grid per device of a mesh.
 """
 
 from __future__ import annotations
@@ -132,136 +133,6 @@ def _dcn_fused_tile_jit(
 
 
 # ---------------------------------------------------------------------------
-# Batched schedule-grid dispatch: ONE pallas_call for a whole tile schedule.
-# ---------------------------------------------------------------------------
-
-
-def _sched_kernel(dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref, w_ref, b_ref,
-                  o_ref, acc_ref, *, tp: int, kk: int, k_pad: int):
-    """One (scheduled tile, pixel block, dep slot) grid step.
-
-    dep_ref:   (T, k_pad) int32 scalar-prefetch dep table — consumed by the
-               x BlockSpec index map, not read here.
-    cnt_ref:   (T,) int32 scalar-prefetch true dep count per tile; slots
-               beyond it are padding and skip the matmul entirely (the x
-               index map clamps to the last real dep, so consecutive
-               padding slots keep the same block and the DMA is elided).
-    idx_ref:   (1, KK*bp, 4) int32 packed-buffer addresses of the tile,
-               tap-major (see :func:`_tap_major`)
-    coeff_ref: (1, KK*bp, 4) f32
-    x_ref:     (1, tp, C) — input tile ``dep[t, k]``, DMA'd by the grid
-    w_ref:     (KK*C, O)
-    b_ref:     (1, O)
-    o_ref:     (1, bp, O) — written on the last dep slot
-    acc_ref:   (KK*bp, C) f32 VMEM scratch — the deformed patch block
-
-    The BLI contraction is decomposed over dep slots: slot k owns packed
-    addresses [k*tp, (k+1)*tp), so its partial 4-hot matmul sees only the
-    one input tile the grid just fetched. The deformed patch matrix never
-    leaves VMEM (same §IV-D fusion as the per-tile kernel).
-    """
-    del dep_ref
-    ti = pl.program_id(0)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(k < cnt_ref[ti])
-    def _accumulate():
-        idx = idx_ref[0]
-        coeff = coeff_ref[0].astype(jnp.float32)
-        rows = idx.shape[0]                  # KK * bp
-        local = idx - k * tp                 # in [0, tp) iff owned by slot k
-        cols = jax.lax.broadcasted_iota(jnp.int32, (rows, tp), 1)
-        w_bli = jnp.zeros((rows, tp), jnp.float32)
-        for j in range(4):
-            onehot = (cols == local[:, j:j + 1]).astype(jnp.float32)
-            w_bli = w_bli + onehot * coeff[:, j:j + 1]
-        x = x_ref[0].astype(jnp.float32)     # (tp, C)
-        acc_ref[...] += jnp.dot(w_bli, x,
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(k == k_pad - 1)
-    def _flush():
-        o_ref[0] = _contract_taps(acc_ref[...], w_ref, b_ref,
-                                  kk).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("kernel_size", "block_p", "interpret"))
-def _dcn_fused_schedule_jit(
-    x_tiles: jax.Array,   # (T_in, tp, C_in) every input tile of the plane
-    dep_tbl: jax.Array,   # (T, k_pad) int32 dep table in schedule order
-    dep_cnt: jax.Array,   # (T,) int32 true dep count per scheduled tile
-    idx: jax.Array,       # (T, P, KK, 4) int32 packed-buffer addresses
-    coeff: jax.Array,     # (T, P, KK, 4) float BLI coefficients
-    w: jax.Array,         # (KK, C_in, C_out) main conv weights
-    b: jax.Array,         # (C_out,)
-    *,
-    kernel_size: int = 3,
-    block_p: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused Eq.2+3 over a whole tile schedule -> (T, P, C_out).
-
-    The batched form of :func:`dcn_fused_tile`: instead of one host
-    dispatch per scheduled output tile, the schedule IS the leading grid
-    dimension of a single ``pallas_call``. The scalar-prefetched dep table
-    drives the input-tile BlockSpec, so the grid's DMA sequence streams
-    exactly the Algorithm-1 scheduled tile loads through the PE array —
-    the paper's back-to-back tile execution, with zero per-tile Python
-    overhead. Row ``t`` of the result is the output of scheduled tile
-    ``t`` (the caller scatters rows by its schedule order).
-    """
-    t_in, tp, c = x_tiles.shape
-    t, p, kk, _ = idx.shape
-    k_pad = dep_tbl.shape[1]
-    o = w.shape[-1]
-    assert kk == kernel_size * kernel_size, (kk, kernel_size)
-    bp = min(block_p, p)
-    if p % bp:
-        raise ValueError(f"P={p} must tile by {bp}; pad upstream")
-    if t == 0:          # empty schedule: nothing to dispatch
-        return jnp.zeros((0, p, o), x_tiles.dtype)
-
-    idx2 = _tap_major(idx, bp)
-    coeff2 = _tap_major(coeff, bp)
-    w2 = w.reshape(kk * c, o)
-    b2 = b.reshape(1, o)
-
-    def x_index(ti, j, k, dep, cnt):
-        # Clamp padding slots to the last real dep: the block index then
-        # repeats across consecutive padding steps, so no DMA is issued
-        # for them (the kernel's pl.when skips their compute).
-        return (dep[ti, jnp.minimum(k, jnp.maximum(cnt[ti] - 1, 0))], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(t, p // bp, k_pad),
-        in_specs=[
-            pl.BlockSpec((1, bp * kk, 4),
-                         lambda ti, j, k, dep, cnt: (ti, j, 0)),
-            pl.BlockSpec((1, bp * kk, 4),
-                         lambda ti, j, k, dep, cnt: (ti, j, 0)),
-            pl.BlockSpec((1, tp, c), x_index),
-            pl.BlockSpec((kk * c, o), lambda ti, j, k, dep, cnt: (0, 0)),
-            pl.BlockSpec((1, o), lambda ti, j, k, dep, cnt: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bp, o),
-                               lambda ti, j, k, dep, cnt: (ti, j, 0)),
-        scratch_shapes=[pltpu.VMEM((kk * bp, c), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_sched_kernel, tp=tp, kk=kk, k_pad=k_pad),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, p, o), x_tiles.dtype),
-        interpret=interpret,
-    )(dep_tbl, dep_cnt, idx2, coeff2, x_tiles, w2, b2)
-
-
-# ---------------------------------------------------------------------------
 # Batch-fused dispatch: ONE pallas_call for the schedules of a whole batch.
 # ---------------------------------------------------------------------------
 
@@ -300,10 +171,12 @@ def _batch_kernel(row_ref, dep_ref, cnt_ref, idx_ref, coeff_ref, x_ref,
     x_ref:     (1, tp, C) — input tile ``dep[g, k]`` of image ``img``.
     acc_ref:   (KK*bp, C) f32 VMEM scratch.
 
-    Same §IV-D fusion as ``_sched_kernel``; the only difference is the
-    addressing: idx is global to the image's tile array, so slot k's
-    partial matmul localises it against the dep tile the grid fetched
-    (``idx - dep * tp``) instead of assuming slot-contiguous packing.
+    The BLI contraction is decomposed over dep slots: slot k's partial
+    4-hot matmul sees only the one input tile the grid just fetched.
+    idx is global to the image's tile array, so the slot localises it
+    against that tile (``idx - dep * tp``); addresses outside it match
+    no column. The deformed patch matrix never leaves VMEM (same §IV-D
+    fusion as the per-tile kernel).
     """
     g = pl.program_id(0)
     k = pl.program_id(2)
@@ -355,10 +228,10 @@ def _dcn_fused_batch_jit(
     """Fused Eq.2+3 over the concatenated schedules of a WHOLE BATCH ->
     (G, P, C_out), one row per batch-grid slot.
 
-    The batch-fused form of :func:`dcn_fused_schedule`: all N images'
-    Algorithm-1 schedules are concatenated (ragged-padded per image)
-    into one leading grid dimension, so a layer segment costs ONE kernel
-    dispatch per batch instead of one per image. Weights are shared
+    The grid form of :func:`dcn_fused_tile`: all N images' Algorithm-1
+    schedules are concatenated (ragged-padded per image) into one
+    leading grid dimension, so a layer segment costs ONE kernel dispatch
+    per batch instead of one per scheduled tile. Weights are shared
     across the grid; the per-image tile arrays are addressed through the
     scalar-prefetched global ids (``img * T_in + dep``), and ragged
     padding rows (``dep_cnt == 0``) skip compute with their DMAs elided
@@ -525,27 +398,6 @@ def dcn_fused_tile(x_tile, idx, coeff, w, b, *, kernel_size: int = 3,
         return _dcn_fused_tile_jit(x_tile, idx, coeff, w, b,
                                    kernel_size=kernel_size,
                                    block_p=block_p, interpret=interpret)
-
-
-def dcn_fused_schedule(x_tiles, dep_tbl, dep_cnt, idx, coeff, w, b, *,
-                       kernel_size: int = 3, block_p: int = 128,
-                       interpret: bool = False):
-    """Fused Eq.2+3 over a whole tile schedule -> (T, P, C_out)."""
-    sp = _span_dispatch("dispatch.batched", x_tiles,
-                        tiles=int(idx.shape[0]),
-                        c_out=int(w.shape[-1]))
-    if sp is None:
-        return _dcn_fused_schedule_jit(x_tiles, dep_tbl, dep_cnt, idx,
-                                       coeff, w, b,
-                                       kernel_size=kernel_size,
-                                       block_p=block_p,
-                                       interpret=interpret)
-    with sp:
-        return _dcn_fused_schedule_jit(x_tiles, dep_tbl, dep_cnt, idx,
-                                       coeff, w, b,
-                                       kernel_size=kernel_size,
-                                       block_p=block_p,
-                                       interpret=interpret)
 
 
 def dcn_fused_batch(x_tiles, row_id, dep_glb, dep_cnt, idx, coeff, w, b,

@@ -256,7 +256,7 @@ def dispatch_arrays_from_klass(
     """Device-side schedule -> dispatch handoff (zero host round-trip).
 
     Converts the greedy kernel's per-step class rows into the dense
-    operands the batched dispatch consumes, entirely as jnp ops on
+    operands the batch-fused dispatch consumes, entirely as jnp ops on
     device — the host never rebuilds a ``TileSchedule`` on this path:
 
       oid     (n_out,)       int32 — scheduled tile per step (-1 padding)

@@ -10,9 +10,10 @@ The forward pass selects an execution ``backend`` per deformable layer:
 
   * ``"xla"``      — reference path (repro.core.deform); differentiable.
   * ``"pallas"``   — whole-plane fused Pallas kernels (repro.kernels).
-  * ``"pipeline"`` — the scheduler-driven tile-pipeline executor
-                     (repro.runtime): TDT -> Algorithm-1 schedule ->
-                     packed-tile fused-kernel dispatches. Forward only.
+  * ``"pipeline"`` — each deformable layer alone through the graph
+                     executor (``repro.runtime.dcn_pipeline``): TDT ->
+                     Algorithm-1 schedule -> fused-kernel dispatch.
+                     Forward only.
   * ``"graph"``    — the network-graph executor with cross-layer tile
                      fusion (repro.runtime.fused_exec): the backbone is
                      partitioned into fused groups whose boundary planes
@@ -35,10 +36,9 @@ from repro.core.deform import (conv2d, deformable_conv2d,
                                init_deformable_conv)
 from repro.core.fusion import LayerShape
 from repro.kernels.ops import deformable_conv2d_pallas
-from repro.runtime.fused_exec import GraphConfig, run_graph
+from repro.runtime.fused_exec import GraphConfig, dcn_pipeline, run_graph
 from repro.runtime.graph import build_graph
-from repro.runtime.pipeline import (PipelineConfig, clamp_tile_config,
-                                    dcn_pipeline)
+from repro.runtime.staging import clamp_tile_config
 
 # (channels, n_convs) per VGG19 stage; maxpool after each stage.
 _VGG19_STAGES = ((64, 2), (128, 2), (256, 4), (512, 4), (512, 4))
@@ -122,15 +122,15 @@ def _pool_positions(cfg: DcnNetConfig) -> set[int]:
 
 def dcn_net_apply(params, cfg: DcnNetConfig, x, *, use_pallas: bool = False,
                   fused: bool = True, backend: str | None = None,
-                  pipeline: PipelineConfig | None = None,
+                  pipeline: GraphConfig | None = None,
                   graph: GraphConfig | None = None):
     """x: (N, H, W, C). Returns logits (N, classes) for vgg19 or per-pixel
     logits (N, H', W', classes) for segnet.
 
-    backend: "xla" (default), "pallas", "pipeline" (the tile-pipeline
-    executor, configured by ``pipeline``), or "graph" (the cross-layer
-    fused network executor, configured by ``graph``); overrides
-    ``use_pallas``.
+    backend: "xla" (default), "pallas", "pipeline" (each deformable
+    layer alone through the graph executor, configured by
+    ``pipeline``), or "graph" (the cross-layer fused network executor,
+    configured by ``graph``); overrides ``use_pallas``.
     """
     if backend is None:
         backend = "pallas" if use_pallas else "xla"
@@ -153,7 +153,7 @@ def dcn_net_apply(params, cfg: DcnNetConfig, x, *, use_pallas: bool = False,
     def run_conv(p, x, deform):
         if deform:
             if backend == "pipeline":
-                pcfg = pipeline or PipelineConfig(
+                pcfg = pipeline or GraphConfig(
                     tile=max(2, min(8, x.shape[1] // 2, x.shape[2] // 2)))
                 # The requested tile is an upper bound: deep-stage planes
                 # shrink below it, so clamp per layer (the raw executor

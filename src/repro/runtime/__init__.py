@@ -1,23 +1,24 @@
-"""Tile-pipeline + network-graph runtime — the paper's execution paths.
+"""The network-graph runtime — the paper's execution path.
 
-Per-layer pipeline (PR 1), per batch element and layer:
+Per deformable layer and batch:
 
   stage 1   offset conv -> sampling coordinates      (core.deform)
   TDT       coords -> tile dependency table          (core.tiles)
   schedule  Algorithm 1 / sequential ordering        (core.scheduler)
-  pack      halo/dependent input tiles + per-pixel
-            (idx, coeff) tensors, padded for shapes
-            not divisible by the tile size           (runtime.packing)
-  execute   fused BLI(+)conv Pallas kernel per
-            schedule entry, scattered back into the
-            (N, H, W, C_out) output                  (kernels.dcn_fused)
+  pack      per-pixel (idx, coeff) tensors and the
+            batch grid's dep tables, padded for
+            shapes not divisible by the tile size    (runtime.packing)
+  execute   fused BLI(+)conv Pallas kernel over the
+            batch's scheduled tiles, scattered back
+            into the (N, H, W, C_out) output         (kernels.dcn_fused)
 
-Network-graph executor (§IV-D network-wide): a graph IR over the model
-backbone (runtime.graph) is partitioned into cross-layer fused groups;
-each group runs ONE Algorithm-1 schedule over a composite TDT chained
-through its layers, with intermediate tiles confined to a bounded
-on-chip tile buffer (runtime.fused_exec). Host-side schedules are
-memoized in an LRU keyed on quantized coordinates (runtime.cache).
+A graph IR over the model backbone (runtime.graph) is partitioned into
+cross-layer fused groups (§IV-D network-wide); each group runs ONE
+Algorithm-1 schedule over a composite TDT chained through its layers
+(runtime.fused_exec), and one deformable layer is a one-node graph
+(``dcn_pipeline``). The staging queue overlaps prepass with execution
+(runtime.staging). Host-side schedules are memoized in an LRU keyed on
+quantized coordinates (runtime.cache).
 
 Executors emit traces (runtime.trace) whose byte counts are cross-checked
 against the DRAM-traffic simulator in benchmarks/bench_scheduling.py,
@@ -28,6 +29,7 @@ from repro.runtime.cache import ScheduleCache, default_schedule_cache
 from repro.runtime.fused_exec import (
     GraphConfig,
     TileBuffer,
+    dcn_pipeline,
     run_graph,
     run_graph_dense,
 )
@@ -50,15 +52,9 @@ from repro.runtime.packing import (
     pack_batch_schedules,
     pack_output_tile,
     pack_plane_operands,
-    pack_schedule_tiles,
     plane_to_tiles,
 )
-from repro.runtime.pipeline import (
-    PipelineConfig,
-    clamp_tile_config,
-    dcn_pipeline,
-    resolve_interpret,
-)
+from repro.runtime.staging import clamp_tile_config
 from repro.runtime.shard import (
     ShardPlan,
     plan_batch_shards,
@@ -71,7 +67,6 @@ from repro.runtime.trace import (
     LayerBufferStats,
     NetworkTrace,
     OverlapSpans,
-    PipelineTrace,
     TileRecord,
 )
 
@@ -82,11 +77,8 @@ __all__ = [
     "pack_batch_schedules",
     "pack_output_tile",
     "pack_plane_operands",
-    "pack_schedule_tiles",
     "plane_to_tiles",
-    "PipelineConfig",
     "dcn_pipeline",
-    "resolve_interpret",
     "ScheduleCache",
     "default_schedule_cache",
     "ShardPlan",
@@ -113,6 +105,5 @@ __all__ = [
     "LayerBufferStats",
     "NetworkTrace",
     "OverlapSpans",
-    "PipelineTrace",
     "TileRecord",
 ]

@@ -7,7 +7,7 @@ paper's boundary comparator (Fig. 9) decodes — so two inputs whose floors
 agree produce byte-identical TDTs and schedules. The cache key is a digest
 of that quantization (exact, not lossy: a floor flip changes the key), so
 repeated inputs — benchmark loops, serving replays — skip the rebuild
-entirely. Hit/miss counters surface on ``PipelineTrace``/``NetworkTrace``.
+entirely. Hit/miss counters surface on ``NetworkTrace``.
 """
 
 from __future__ import annotations

@@ -1,4 +1,5 @@
-"""Cross-layer fused network executor (paper §IV-D taken network-wide).
+"""Network executor with cross-layer tile fusion (paper §IV-D taken
+network-wide).
 
 Executes a :class:`~repro.runtime.graph.NetGraph` under the accelerator's
 cross-layer dataflow: inside each
@@ -9,41 +10,43 @@ tile loads (under the FIFO buffer model), group outputs, weights and
 pool/upsample boundary planes, matching
 ``core.simulator.simulate_network`` byte-for-byte:
 
-  prepass   per image, run the stage-1 chain densely (the paper's
-            pre-scheduler runs ahead of the PE array) and build one TDT
-            per layer — measured ``tdt_from_coords`` for DCN layers,
-            analytic ``tdt_standard_conv`` halos for standard convs —
-            then chain them (``compose_tdt``) into ONE Algorithm-1
-            schedule per fused group and pack the batched kernel
-            operands. The prepass for image i+1 runs on a staging thread
-            while image i executes on the device
-            (``GraphConfig.staging_depth``). Under ``batch_fused`` the
-            prepass is per group for the whole batch, and its device
-            work is one compiled program per group
-            (``_group_prepass_program``).
+  prepass   run the stage-1 chain densely (the paper's pre-scheduler
+            runs ahead of the PE array) and build one TDT per layer —
+            measured ``tdt_from_coords`` for DCN layers, analytic
+            ``tdt_standard_conv`` halos for standard convs — then chain
+            them (``compose_tdt``) into ONE Algorithm-1 schedule per
+            fused group and image. Under ``batch_fused`` the prepass is
+            per group for the whole batch, and its device work is one
+            compiled program per group (``_group_prepass_program``);
+            under ``per_tile`` it is per image. With ``staging_depth >
+            1`` the next unit's prepass runs on a staging thread
+            (``runtime.staging.run_staged``) while the current one
+            executes.
   execute   two dispatch modes:
-              * ``"batched"`` (default) — one batched kernel dispatch per
-                (group, layer segment): the group's schedule becomes the
-                leading grid dimension of a single ``pallas_call``
-                (``kernels.dcn_fused.dcn_fused_schedule``), with the
-                scalar-prefetched dep table driving the input-tile DMA
-                sequence; standard-conv segments run as one halo conv
-                over the assembled plane. Dispatches per group drop from
-                O(num_tiles x layers) to n_layers. Interior planes are
-                materialized as whole device arrays between segments
-                (recorded honestly in ``LayerBufferStats``
-                ``max_resident_bytes``) — the paper's bounded on-chip
-                intermediate buffer is modeled by the per_tile mode.
-              * ``"per_tile"`` — the PR 2 demand-driven loop: each
-                group-output tile pulls its producer tiles recursively
-                through a bounded recompute-on-evict :class:`TileBuffer`
-                (eviction costs FLOPs, never modeled DRAM).
+              * ``"batch_fused"`` (default) — the schedules of every
+                image in the batch concatenate into ONE kernel grid per
+                DCN layer (``kernels.dcn_fused.dcn_fused_batch``), with
+                the scalar-prefetched dep table driving the input-tile
+                DMA sequence; the standard convs, ReLUs, tile masks and
+                the scatter around the kernels are compiled programs per
+                group. Interior planes are whole device arrays between
+                segments (recorded in ``LayerBufferStats``
+                ``max_resident_bytes``). A single image is a batch of
+                one: ``dcn_pipeline`` and the serving engine's degraded
+                step run this path too.
+              * ``"per_tile"`` — the reference model of the paper's
+                bounded on-chip intermediate buffer: each group-output
+                tile pulls its producer tiles recursively through a
+                bounded recompute-on-evict :class:`TileBuffer` (eviction
+                costs FLOPs, never modeled DRAM), one kernel dispatch
+                per produced tile.
 
 Both modes execute the same Algorithm-1 schedule, whose group-input load
-order is what the trace records and the simulator prices — batching
-preserves it as the grid order, so the cross-check stays exact.
+order is what the trace records and the simulator prices — the batch
+grid keeps each image's schedule order, so the cross-check stays exact.
 benchmarks/bench_graph.py asserts it; tests/test_graph.py +
 tests/test_batched_dispatch.py pin the numerics vs the XLA reference.
+:func:`dcn_pipeline` runs one deformable layer as a one-node graph.
 """
 
 from __future__ import annotations
@@ -57,7 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.deform import conv2d, deformable_conv2d, offsets_to_coords
+from repro.core.deform import (DeformableConvParams, conv2d,
+                               deformable_conv2d, offsets_to_coords)
 from repro.core.scheduler import (DeviceSchedule, TileSchedule, pow2_pad,
                                   schedule_arrays_device, schedule_tiles,
                                   sequential_schedule)
@@ -66,10 +70,10 @@ from repro.core.tiles import (TileGrid, compose_tdt_chain,
                               tdt_standard_conv)
 from repro.kernels.dcn_fused import (dcn_fused_batch,
                                      dcn_fused_batch_sharded,
-                                     dcn_fused_schedule, dcn_fused_tile)
+                                     dcn_fused_tile)
 from repro.kernels.dcn_schedule import (tdt_dispatch_arrays,
                                         tdt_from_coords_device)
-from repro.kernels.ops import round_up
+from repro.kernels.ops import resolve_interpret, round_up
 from repro.obs import Tracer, default_registry, get_tracer, use_tracer
 from repro.runtime.cache import (ScheduleCache, chain_digest, conv_digest,
                                  coords_digest, default_schedule_cache,
@@ -81,10 +85,8 @@ from repro.runtime.graph import (DeformNode, FusedGroup, NetGraph, PoolNode,
 from repro.runtime.packing import (build_neighbour_tables,
                                    narrow_dep_slots, pack_batch_schedules,
                                    pack_output_tile, pack_plane_operands,
-                                   pack_schedule_tiles, plane_to_tiles,
-                                   tiles_to_plane)
-from repro.runtime.pipeline import (resolve_interpret, run_staged,
-                                    validate_dispatch_config)
+                                   plane_to_tiles, tiles_to_plane)
+from repro.runtime.staging import run_staged, validate_dispatch_config
 from repro.runtime.shard import (ShardPlan, allgather_nbytes,
                                  plan_batch_shards, resolve_shard_mesh,
                                  shard_batch_schedules, stack_rows,
@@ -135,13 +137,14 @@ class GraphConfig:
     interpret: bool | None = None         # None = auto (CPU -> interpret)
     onchip_budget_bytes: int = ONCHIP_BUDGET_BYTES  # drives group planning
     use_schedule_cache: bool = True
-    # "batched": one pallas_call grid per (group, layer segment) PER IMAGE.
     # "batch_fused": the concatenated schedules of all batch images as one
-    #   grid per layer segment — dispatches per segment drop from N to 1,
-    #   and with schedule_backend="device" the schedule arrays flow into
-    #   the dispatch operands with zero host round trip.
-    # "per_tile": PR 2 demand-driven per-tile dispatch loop.
-    dispatch: str = "batched"
+    #   kernel grid per DCN layer, everything around the kernels compiled
+    #   per group; with schedule_backend="device" the schedule arrays
+    #   flow into the dispatch operands with zero host round trip.
+    # "per_tile": one kernel dispatch per produced tile, intermediates in
+    #   bounded recompute-on-evict TileBuffers — the reference model of
+    #   the paper's on-chip intermediate buffer.
+    dispatch: str = "batch_fused"
     # "host": TDT scatter + Algorithm-1 loop in host numpy/Python.
     # "device": both as Pallas kernels (kernels.dcn_schedule), bit-exact
     # vs the host path — the staging thread shrinks to packing only.
@@ -152,7 +155,7 @@ class GraphConfig:
     staging_depth: int = 2
     # Staging-worker watchdog deadline (seconds); None = wait forever.
     # A staged prepass that misses it triggers failover to synchronous
-    # prepass for the rest of the run (see pipeline.run_staged).
+    # prepass for the rest of the run (see staging.run_staged).
     watchdog_s: float | None = None
     # Batch-dimension scale-out (batch_fused only): an explicit
     # jax.sharding.Mesh with a "data" axis, or data_parallel=D (builds a
@@ -322,19 +325,8 @@ def _assemble_halo(dep_arrays: list, deps: np.ndarray, grid: TileGrid,
 
 
 @dataclasses.dataclass
-class _LayerDispatch:
-    """One DCN layer's batched-grid operands, packed in the prepass."""
-
-    out_order: np.ndarray                 # (T,) grid order of output tiles
-    dep_tbl: np.ndarray                   # (T, k_pad) scalar-prefetch table
-    dep_cnt: np.ndarray                   # (T,) true dep count per tile
-    idx: np.ndarray                       # (T, p_pad, KK, 4)
-    coeff: np.ndarray                     # (T, p_pad, KK, 4)
-
-
-@dataclasses.dataclass
 class _GroupArtifacts:
-    """Prepass products for one fused group of one image."""
+    """Prepass products for one fused group of one image (per_tile)."""
 
     grid: TileGrid
     m: int                                # schedule buffer capacity
@@ -346,10 +338,6 @@ class _GroupArtifacts:
     # that ran through the device scheduling backend.
     schedule_s: float = 0.0
     schedule_device_s: float = 0.0
-    # Batched dispatch only: per-layer packed operands (None entries for
-    # conv layers). Packed on the staging thread so the per-image packing
-    # cost overlaps the previous image's execution.
-    packed: list[_LayerDispatch | None] | None = None
 
 
 def _group_schedule_artifacts(
@@ -451,40 +439,9 @@ def _group_schedule_artifacts(
         ssp.set(cached=hit)
     schedule_s = ssp.dur
 
-    # Pack the batched-grid operands here, on the staging thread. The
-    # schedule cache cannot cover this: idx follows the quantized coords
-    # (the cache key) but the BLI coefficients keep the fractional parts.
-    packed: list[_LayerDispatch | None] | None = None
-    if cfg.dispatch == "batched":
-        tp = grid.th * grid.tw
-        bp = min(cfg.block_p, tp)
-        p_pad = tp if tp % bp == 0 else round_up(tp, cfg.block_p)
-        oid_arr = np.asarray(sched.oid, np.int32)
-        last = group.n_layers - 1
-        packed = []
-        with tr.span("pack", dispatch="batched", layers=group.n_layers):
-            for j, node in enumerate(group.nodes):
-                if not isinstance(node, DeformNode):
-                    packed.append(None)
-                    continue
-                # Grid order: the Algorithm-1 schedule for the group's
-                # output layer; plane order for interior layers (their
-                # tiles never touch DRAM, so order is free).
-                out_order = (oid_arr if j == last
-                             else np.arange(grid.num_tiles,
-                                            dtype=np.int32))
-                dep_lists = [np.flatnonzero(b_layers[j][t])
-                             for t in out_order]
-                k_pad = pow2_pad(max((len(d) for d in dep_lists),
-                                     default=1))
-                dep_tbl, dep_cnt, idx, coeff = pack_schedule_tiles(
-                    nbs[j], grid, out_order, dep_lists, p_pad, k_pad)
-                packed.append(_LayerDispatch(out_order, dep_tbl, dep_cnt,
-                                             idx, coeff))
-
     art = _GroupArtifacts(
         grid=grid, m=m, b_layers=list(b_layers), nbs=nbs, sched=sched,
-        cache_hit=hit, packed=packed, schedule_s=schedule_s,
+        cache_hit=hit, schedule_s=schedule_s,
         schedule_device_s=(schedule_s
                            if cfg.schedule_backend == "device" else 0.0))
     return art, plane
@@ -605,66 +562,6 @@ def _exec_group_per_tile(
     return out, stats, dispatches
 
 
-def _exec_group_batched(
-    x_tiles: jax.Array,
-    group: FusedGroup,
-    convs: list,
-    cfg: GraphConfig,
-    interpret: bool,
-    art: _GroupArtifacts,
-    masks: list,
-    dtype_bytes: int,
-) -> tuple[jax.Array, list[LayerBufferStats], int]:
-    """One batched dispatch per layer segment: DCN layers run the whole
-    tile schedule as a single ``pallas_call`` grid (scalar-prefetched dep
-    table -> scheduled DMA order, operands packed in the prepass), conv
-    layers as one halo conv over the assembled plane; outputs scatter
-    back to tile order in one op."""
-    grid = art.grid
-    h, w = grid.h, grid.w
-    tp = grid.th * grid.tw
-    num = grid.num_tiles
-    masks_arr = jnp.stack(masks)                          # (T, tp, 1)
-    last = group.n_layers - 1
-
-    tiles = x_tiles
-    stats: list[LayerBufferStats] = []
-    dispatches = 0
-    for j, node in enumerate(group.nodes):
-        p = convs[node.param_idx]
-        if isinstance(node, DeformNode):
-            ld = art.packed[j]
-            kk = node.kernel_size ** 2
-            w2 = p.w.reshape(kk, node.c_in, node.c_out)
-            y = dcn_fused_schedule(
-                tiles, jnp.asarray(ld.dep_tbl), jnp.asarray(ld.dep_cnt),
-                jnp.asarray(ld.idx), jnp.asarray(ld.coeff), w2, p.b,
-                kernel_size=node.kernel_size, block_p=cfg.block_p,
-                interpret=interpret)[:, :tp]
-            if node.relu:
-                y = jax.nn.relu(y)
-            y = y * masks_arr[np.asarray(ld.out_order)]
-            if j == last:
-                # Scatter all scheduled outputs back to tile order at once.
-                tiles = jnp.zeros((num, tp, node.c_out), y.dtype)
-                tiles = tiles.at[jnp.asarray(ld.out_order)].set(y)
-            else:
-                tiles = y
-            computed = len(ld.out_order)
-        else:
-            plane = tiles_to_plane(tiles, grid, h, w)
-            yp = conv2d(plane[None], p["w"], p["b"])[0]
-            if node.relu:
-                yp = jax.nn.relu(yp)
-            tiles = plane_to_tiles(yp, grid)
-            computed = num
-        dispatches += 1
-        stats.append(LayerBufferStats(
-            kind=node.kind, tiles_computed=computed, recomputes=0,
-            max_resident_bytes=num * tp * node.c_out * dtype_bytes))
-    return tiles, stats, dispatches
-
-
 def _run_group(
     x_g: jax.Array,
     group: FusedGroup,
@@ -681,9 +578,7 @@ def _run_group(
     x_tiles = plane_to_tiles(x_g, grid)
     masks = [jnp.asarray(m, x_g.dtype) for m in _tile_valid_masks(grid)]
 
-    exec_fn = (_exec_group_batched if cfg.dispatch == "batched"
-               else _exec_group_per_tile)
-    y_tiles, layer_stats, dispatches = exec_fn(
+    y_tiles, layer_stats, dispatches = _exec_group_per_tile(
         x_tiles, group, convs, cfg, interpret, art, masks, dtype_bytes)
 
     tile_bytes = tp * c_in * dtype_bytes
@@ -1355,9 +1250,11 @@ def run_graph(
     XLA reference) to float tolerance; with ``return_trace`` additionally
     returns the :class:`NetworkTrace` of the executed DRAM traffic.
 
-    With ``staging_depth > 1`` (the default) image i+1's host prepass
-    runs on a worker thread while image i's kernels execute — the trace's
-    ``host_overlap_frac`` reports how much host time was hidden.
+    With ``staging_depth > 1`` (the default) the next unit's host prepass
+    — segment s+1 of the whole batch under ``batch_fused``, image i+1
+    under ``per_tile`` — runs on a worker thread while the current
+    one's kernels execute; the trace's ``host_overlap_frac`` reports
+    how much host time was hidden.
     ``schedule_cache`` overrides the process-wide cache (serving engines
     pass their own). ``tracer`` routes span tracing (``prepass.*``,
     ``pack``, ``dispatch.*``) into an enabled :class:`~repro.obs.Tracer`;
@@ -1476,6 +1373,45 @@ def run_graph(
                           watchdog_s=cfg.watchdog_s, faults=cfg.faults)
     y = jnp.stack(outs)
     return (y, trace) if return_trace else y
+
+
+def dcn_pipeline(
+    x: jax.Array,
+    params: DeformableConvParams,
+    *,
+    kernel_size: int = 3,
+    variant: str = "dcn2",
+    max_displacement: float | None = None,
+    tile: int | tuple[int, int] = 8,
+    buffer_tiles: int | None = None,
+    schedule: str = "alg1",
+    block_p: int = 128,
+    interpret: bool | None = None,
+    return_trace: bool = False,
+    config: GraphConfig | None = None,
+    tracer: Tracer | None = None,
+):
+    """One deformable convolution over a batch: (N,H,W,C) -> (N,H,W,O).
+
+    The layer runs through :func:`run_graph` as a one-node graph (a
+    :class:`DeformNode` without ReLU): stage-1 offsets -> TDT ->
+    Algorithm-1 schedule -> fused-kernel dispatch. Numerically matches
+    ``core.deform.deformable_conv2d`` (the XLA reference) to float
+    tolerance; with ``return_trace`` also returns the
+    :class:`NetworkTrace`, one :class:`GroupTrace` per image.
+
+    ``config`` overrides the individual executor keywords when given;
+    ``tracer`` is passed on to :func:`run_graph`.
+    """
+    cfg = config or GraphConfig(tile=tile, buffer_tiles=buffer_tiles,
+                                schedule=schedule, block_p=block_p,
+                                interpret=interpret)
+    h, w, c_in = x.shape[1:]
+    node = DeformNode(0, c_in, params.w.shape[-1], h, w, kernel_size,
+                      variant, relu=False)
+    return run_graph([params], NetGraph((node,), h, w, c_in), x,
+                     config=cfg, max_displacement=max_displacement,
+                     return_trace=return_trace, tracer=tracer)
 
 
 def network_sim_specs(trace: NetworkTrace) -> list[dict]:

@@ -1,4 +1,4 @@
-"""Host-side tile packing for the pipeline executor.
+"""Tile packing for the graph executor.
 
 The fused Pallas kernel (``kernels.dcn_fused``) consumes a flat packed
 input buffer ``x_packed (S, C)`` plus per-output-pixel ``(idx, coeff)``
@@ -132,54 +132,6 @@ def pack_output_tile(
         idx = np.pad(idx, ((0, p_pad - tp), (0, 0), (0, 0)))
         cfs = np.pad(cfs, ((0, p_pad - tp), (0, 0), (0, 0)))
     return idx, cfs
-
-
-def pack_schedule_tiles(
-    nb: NeighbourTables,
-    grid: TileGrid,
-    out_tiles,
-    dep_lists,
-    p_pad: int,
-    k_pad: int,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Group-level packing: the batched grid kernel's operands for a whole
-    schedule at once (``kernels.dcn_fused.dcn_fused_schedule``).
-
-    ``out_tiles``/``dep_lists`` are the schedule: per scheduled output tile
-    its dependent input tiles. Stacks :func:`pack_output_tile` over the
-    schedule and emits the dep table + counts the kernel's scalar-prefetch
-    machinery consumes:
-
-      dep_tbl (T, k_pad) int32  — dep tile ids, zero-padded; padding slots
-                                  are never addressed because packed
-                                  addresses only reach slot < len(deps),
-                                  and the kernel skips them via dep_cnt.
-                                  An empty dep list zeroes the whole coeff
-                                  row (its row contributes bias only —
-                                  schedules never contain dep-less tiles).
-      dep_cnt (T,)       int32  — true dep count per scheduled tile
-      idx     (T, p_pad, KK, 4) int32
-      coeff   (T, p_pad, KK, 4) float32
-    """
-    kk = nb.coeff.shape[2]
-    t = len(out_tiles)
-    dep_tbl = np.zeros((t, k_pad), np.int32)
-    dep_cnt = np.zeros((t,), np.int32)
-    idx = np.zeros((t, p_pad, kk, 4), np.int32)
-    coeff = np.zeros((t, p_pad, kk, 4), np.float32)
-    with get_tracer().span("pack.schedule_tiles", tiles=t, k_pad=k_pad):
-        for n, (tile, deps) in enumerate(zip(out_tiles, dep_lists)):
-            deps = [int(d) for d in deps]
-            if len(deps) > k_pad:
-                raise ValueError(f"{len(deps)} deps exceed k_pad={k_pad}")
-            if not deps:
-                continue      # all-zero coeff row: the dispatch contributes
-                              # bias only (schedules never emit such tiles)
-            i, c = pack_output_tile(nb, grid, int(tile), deps, p_pad)
-            idx[n], coeff[n] = i, c
-            dep_tbl[n, :len(deps)] = deps
-            dep_cnt[n] = len(deps)
-    return dep_tbl, dep_cnt, idx, coeff
 
 
 # ---------------------------------------------------------------------------

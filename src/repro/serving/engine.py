@@ -30,8 +30,9 @@ Resilience (ISSUE 8): requests are *isolated* — input validation at
 checked at admission and completion, a bounded queue with
 ``block``/``reject``/``shed-oldest`` backpressure, and per-step fault
 containment: a failed ``batch_fused`` step retries once with the
-offending slot evicted, then degrades to per-image ``batched`` dispatch
-so one poisoned image can never take down its step-mates. A failing
+offending slot evicted, then degrades to running its images one at a
+time (the same ``batch_fused`` path at width 1) so one poisoned image
+can never take down its step-mates. A failing
 request completes with ``DcnRequest.error`` set (``result()`` raises
 the typed ``RequestFailedError``) and is returned exactly once; all
 failure counters (``requests_failed``, ``deadline_expired``,
@@ -245,7 +246,7 @@ class DcnRequest:
 
 class DcnServingEngine:
     """Inference service for the paper's DCN networks over the graph
-    executor (cross-layer fused groups, batched tile-grid dispatch).
+    executor (cross-layer fused groups, batch-fused tile-grid dispatch).
 
     Each request is an image batch; the engine owns a
     :class:`~repro.runtime.cache.ScheduleCache` so per-request coords
@@ -295,7 +296,7 @@ class DcnServingEngine:
                                    clamp_tile_config)
         from repro.runtime.fused_exec import (alg1_tiles, exec_programs,
                                               prepass_programs)
-        from repro.runtime.pipeline import staging_watchdog_failovers
+        from repro.runtime.staging import staging_watchdog_failovers
 
         if not isinstance(cfg, DcnNetConfig):
             raise ValueError(
@@ -355,7 +356,8 @@ class DcnServingEngine:
             help="batch_fused steps retried after an execution fault")
         self._m_degraded = self.metrics.counter(
             "serving.degraded_steps",
-            help="steps degraded to per-image batched dispatch")
+            help="steps degraded to serving their images one at a "
+                 "time")
         self._host_builds = host_schedule_builds
         self._host_builds0 = host_schedule_builds.count
         self._watchdog = staging_watchdog_failovers
@@ -371,10 +373,10 @@ class DcnServingEngine:
         # — what bench_serving dumps. The step's spans stay in the
         # tracer, nested under its ``serve.step``.
         self.timeline: list[dict] = []
-        # Continuous-batching state. The step config pins the coalesced
-        # dispatch mode to batch_fused (the ragged batch grid handles
-        # whatever mix of slot images a step happens to coalesce) and is
-        # clamped once: serving images all share the config's plane.
+        # Continuous-batching state. The step config is clamped once:
+        # serving images all share the config's plane. Under the default
+        # batch_fused dispatch the ragged batch grid handles whatever mix
+        # of slot images a step happens to coalesce.
         self.n_slots = int(slots)
         self.max_queue = None if max_queue is None else int(max_queue)
         self.queue_policy = queue_policy
@@ -391,16 +393,15 @@ class DcnServingEngine:
         self.metrics.register("serving.latency_s", self.latency)
         self.last_trace = None
         self.last_step_faulted = False
-        self._step_cfg = clamp_tile_config(
-            dataclasses.replace(self.graph_cfg, dispatch="batch_fused"),
-            cfg.img_size, cfg.img_size)
-        # Degraded mode: per-image batched dispatch, serial staging — a
-        # fault in one image's dispatch cannot touch another's. Sharding
-        # is cleared too: "batched" rejects mesh=/data_parallel=, and a
-        # degraded step must not depend on collective health anyway.
+        self._step_cfg = clamp_tile_config(self.graph_cfg, cfg.img_size,
+                                           cfg.img_size)
+        # Degraded mode: the step's dispatch path one image at a time,
+        # serial staging — a fault in one image's run cannot touch
+        # another's. Sharding is cleared: a degraded step must not
+        # depend on collective health.
         self._degraded_cfg = dataclasses.replace(
-            self._step_cfg, dispatch="batched", staging_depth=1,
-            mesh=None, data_parallel=None)
+            self._step_cfg, staging_depth=1, mesh=None,
+            data_parallel=None)
         self._faults = self._step_cfg.faults
         # Scale-out: with a sharded step config (mesh=/data_parallel=)
         # the slot pool partitions contiguously over the mesh's data
@@ -727,18 +728,18 @@ class DcnServingEngine:
         Returns ``(outs, traces, failures, degraded)``: ``outs`` maps
         batch position -> output array, ``failures`` maps batch
         position -> exception, ``traces`` is the executor traces to
-        absorb, ``degraded`` marks a step that fell back to per-image
-        batched dispatch. ``repl`` is the per-position replica id of a
+        absorb, ``degraded`` marks a step that fell back to serving its
+        images one at a time. ``repl`` is the per-position replica id of a
         sharded engine (drives ``shard_sizes`` so shard placement
         follows slot placement, including across the evicted retry).
 
         Fault containment ladder: (1) the coalesced ``batch_fused`` run;
         (2) on an exception that names the offending image
         (``e.image``), retry ONCE with that slot evicted; (3) on an
-        unattributed exception or a failed retry, degrade to per-image
-        ``batched`` dispatch, capturing each image's exception
-        individually — one poisoned image can then never fail its
-        step-mates.
+        unattributed exception or a failed retry, degrade: run each image
+        alone (``batch_fused`` at width 1, unsharded), capturing each
+        image's exception individually — one poisoned image can then
+        never fail its step-mates.
         """
         n = len(images)
         try:

@@ -5,18 +5,18 @@ chaos bench gate or a regression test is useless if the failure pattern
 shifts run to run — so every injection decision here is a pure function
 of ``(seed, kind, call-or-step index)`` via a sha256 draw, never of wall
 time or a shared global RNG. The injector is threaded through
-``PipelineConfig.faults`` / ``GraphConfig.faults`` and consulted by the
-executors at four sites, plus one bench-side corruption helper:
+``GraphConfig.faults`` and consulted by the executor at four sites, plus
+one bench-side corruption helper:
 
 ======================  ====================================================
 kind                    where it fires
 ======================  ====================================================
-``prepass``             per-image schedule build (TDT + Algorithm-1) in
-                        both executors — raises :class:`FaultError`
-                        tagged with the image index.
-``dispatch``            kernel-dispatch entry of the batched /
-                        batch-fused exec paths — raises
-                        :class:`FaultError` (image picked
+``prepass``             per-image schedule build (TDT + Algorithm-1) —
+                        raises :class:`FaultError` tagged with the image
+                        index.
+``dispatch``            kernel-dispatch entry (per fused group under
+                        ``batch_fused``, per image under ``per_tile``) —
+                        raises :class:`FaultError` (image picked
                         deterministically when only the batch width is
                         known).
 ``worker_stall``        start of a staged prepass in ``run_staged`` —

@@ -12,7 +12,6 @@ from repro.tuning.autotune import (
     collect_layer_coords,
     representative_input,
     resolve_tuned_plan,
-    resolve_tuned_tile,
     tile_candidates,
 )
 from repro.tuning.plan_cache import (
@@ -40,6 +39,5 @@ __all__ = [
     "plan_key",
     "representative_input",
     "resolve_tuned_plan",
-    "resolve_tuned_tile",
     "tile_candidates",
 ]

@@ -408,72 +408,3 @@ def resolve_tuned_plan(convs, graph, *, autotune: str,
                          tracer=tracer, key=key)
     cache.put(key, plan)
     return plan
-
-
-def resolve_tuned_tile(coords, h: int, w: int, *, c_in: int,
-                       c_out: int, kernel_size: int, autotune: str,
-                       dtype_bytes: int,
-                       tile_hw: tuple[int, int],
-                       buffer_tiles: int | None, schedule: str,
-                       budget: int = 128,
-                       plan_cache_dir: str | None = None,
-                       plan_cache: PlanCache | None = None,
-                       tracer=None) -> tuple[int, int] | None:
-    """Single-layer tile-shape tuning for ``dcn_pipeline``.
-
-    The pipeline has one deformable layer and no partition to cut, so
-    the search degenerates to picking the tile shape with the least
-    simulated input traffic. Keyed on the layer geometry (not the
-    coords): the first resolution's coords act as the representative
-    input and the winner is cached for every later call — same
-    philosophy as the graph path, where plans deliberately generalize
-    across inputs with the same key.
-    """
-    if autotune not in AUTOTUNE_MODES:
-        raise ValueError(f"unknown autotune mode: {autotune!r}")
-    if autotune == "off":
-        return None
-    cache = plan_cache if plan_cache is not None \
-        else default_plan_cache(plan_cache_dir)
-    key = ("layer", int(h), int(w), int(c_in), int(c_out),
-           int(kernel_size), int(dtype_bytes),
-           int(tile_hw[0]), int(tile_hw[1]),
-           None if buffer_tiles is None else int(buffer_tiles),
-           str(schedule))
-    plan = cache.get(key)
-    if plan is not None:
-        g = plan.groups[0]
-        return (g.tile_h, g.tile_w)
-    if autotune == "cached-only":
-        return None
-    tr = tracer if tracer is not None else get_tracer()
-    with tr.timed("tuning.search", nodes=1, budget=budget) as sp:
-        best = None
-        evals = 0
-        th0, tw0 = min(tile_hw[0], h), min(tile_hw[1], w)
-        cands = [(th0, tw0)] + [
-            c for c in tile_candidates(h, w, tile_hw)
-            if c != (th0, tw0)]
-        for th, tw in cands:
-            if evals >= budget and best is not None:
-                break
-            grid = TileGrid(h, w, min(th, h), min(tw, w))
-            m = (grid.num_tiles if buffer_tiles is None
-                 else buffer_tiles)
-            b = np.asarray(tdt_from_coords(coords, grid, grid))
-            with tr.span("tuning.score", tile_h=grid.th,
-                         tile_w=grid.tw):
-                rep = simulate_group([b], grid, [(c_in, c_out)], 0, m,
-                                     dtype_bytes=dtype_bytes,
-                                     fused=True, schedule=schedule)
-            evals += 1
-            s = int(rep.total_dram_bytes)
-            if best is None or s < best[0]:
-                best = (s, grid.th, grid.tw)
-        sp.set(candidates=evals, dram_bytes=best[0])
-    plan = TunedPlan(key=key,
-                     groups=(TunedGroup(0, 1, best[1], best[2]),),
-                     dram_bytes=best[0], greedy_dram_bytes=best[0],
-                     candidates=evals, search_s=float(sp.dur))
-    cache.put(key, plan)
-    return (best[1], best[2])

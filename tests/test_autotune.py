@@ -26,7 +26,6 @@ from repro.runtime import (ConvNode, DeformNode, GraphConfig, NetGraph,
                            run_graph_dense)
 from repro.runtime.fused_exec import network_sim_specs
 from repro.runtime.graph import partition_graph_cached
-from repro.runtime.pipeline import PipelineConfig
 from repro.serving import DcnServingEngine
 from repro.tuning import (PlanCache, TunedGroup, TunedPlan,
                           autotune_plan, plan_cache_hits,
@@ -250,13 +249,15 @@ class TestPartitionMemoKeying:
 
 
 class TestConfigValidation:
-    @pytest.mark.parametrize("cls", [GraphConfig, PipelineConfig])
-    def test_invalid_mode_rejected(self, cls):
+    @pytest.mark.parametrize("dispatch", ["batch_fused", "per_tile"])
+    def test_invalid_mode_rejected(self, dispatch):
         with pytest.raises(ValueError, match="autotune"):
-            cls(autotune="aggressive")
+            GraphConfig(dispatch=dispatch, autotune="aggressive")
         with pytest.raises(ValueError, match="autotune_budget"):
-            cls(autotune="offline", autotune_budget=0)
-        cls(autotune="cached-only")  # valid modes construct fine
+            GraphConfig(dispatch=dispatch, autotune="offline",
+                        autotune_budget=0)
+        # valid modes construct fine
+        GraphConfig(dispatch=dispatch, autotune="cached-only")
 
 
 class TestServingAutotune:

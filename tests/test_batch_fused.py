@@ -7,10 +7,11 @@ with ``schedule_backend="device"`` the device scheduler's arrays flow
 directly into the dispatch operands — no host ``TileSchedule`` on the
 hot path. These tests pin down that:
 
-  * batch-fused == per-image batched == XLA reference numerics across
-    rect tiles, ragged grids, and both schedule backends;
+  * a batch == its images run one at a time (the serving engine's
+    degraded step) == XLA reference numerics across rect tiles, ragged
+    grids, and both schedule backends;
   * the per-image trace records (and therefore the executor-vs-simulator
-    DRAM cross-check) are EXACTLY those of per-image dispatch — the
+    DRAM cross-check) are EXACTLY those of the image run alone — the
     concatenated grid order is the concatenated schedule order;
   * batches mixing empty and full schedules pad per image with elided
     slots and still compute correctly;
@@ -33,13 +34,12 @@ from repro.core.simulator import simulate_network, simulate_strategies
 from repro.core.tiles import (TileGrid, per_pixel_input_tiles,
                               tdt_from_coords)
 from repro.kernels import dcn_fused
-from repro.kernels.dcn_fused import dcn_fused_batch, dcn_fused_schedule
+from repro.kernels.dcn_fused import dcn_fused_batch, dcn_fused_tile
 from repro.kernels.dcn_schedule import tdt_dispatch_arrays
 from repro.models.dcn_models import DcnNetConfig, init_dcn_net
-from repro.runtime import (GraphConfig, PipelineConfig, ScheduleCache,
-                           dcn_pipeline, pack_batch_schedules,
-                           pack_plane_operands, pack_schedule_tiles,
-                           run_graph, run_graph_dense)
+from repro.runtime import (GraphConfig, ScheduleCache, dcn_pipeline,
+                           pack_batch_schedules, pack_output_tile,
+                           pack_plane_operands, run_graph, run_graph_dense)
 from repro.runtime.fused_exec import network_sim_specs
 from repro.runtime.packing import build_neighbour_tables, narrow_dep_slots
 from repro.serving import DcnServingEngine
@@ -50,6 +50,12 @@ from tests.test_graph import _acceptance_case
 def _layer(key, c_in, c_out, variant="dcn2", offset_scale=0.7):
     p = init_deformable_conv(key, c_in, c_out, 3, variant)
     return randomize_offset_conv(p, jax.random.fold_in(key, 1), offset_scale)
+
+
+def _one_at_a_time(run, x):
+    """``run`` on each image of ``x`` alone, as the serving engine's
+    degraded step does."""
+    return [run(x[i:i + 1]) for i in range(x.shape[0])]
 
 
 class TestBatchFusedPipelineOracle:
@@ -66,56 +72,53 @@ class TestBatchFusedPipelineOracle:
         params = _layer(key, 5, 7)
         x = jax.random.normal(jax.random.fold_in(key, 2), (3, h, w, 5))
         y_ref = deformable_conv2d(x, params)
-        y_f, tr_f = dcn_pipeline(
-            x, params, return_trace=True,
-            config=PipelineConfig(tile=tile, dispatch="batch_fused",
-                                  schedule_backend=backend,
-                                  use_schedule_cache=False))
-        y_b = dcn_pipeline(
-            x, params,
-            config=PipelineConfig(tile=tile, use_schedule_cache=False))
+        cfg = GraphConfig(tile=tile, schedule_backend=backend,
+                          use_schedule_cache=False)
+        y_f, tr_f = dcn_pipeline(x, params, return_trace=True, config=cfg)
+        y_b = jnp.concatenate(_one_at_a_time(
+            lambda xi: dcn_pipeline(xi, params, config=cfg), x))
         np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_b),
                                    rtol=1e-5, atol=1e-5)
-        # ONE dispatch for the whole batch (vs one per image batched).
+        # ONE dispatch for the whole batch (vs one per image alone).
         assert tr_f.kernel_dispatches == 1
         assert tr_f.dispatches_per_batch == 1
-        assert all(im.dispatch == "batch_fused" for im in tr_f.images)
+        assert all(g.dispatch == "batch_fused" for g in tr_f.groups)
 
     def test_batch_of_one(self):
         key = jax.random.PRNGKey(9)
         params = _layer(key, 4, 6)
         x = jax.random.normal(jax.random.fold_in(key, 2), (1, 13, 13, 4))
         y_ref = deformable_conv2d(x, params)
-        y = dcn_pipeline(x, params,
-                         config=PipelineConfig(tile=4,
-                                               dispatch="batch_fused"))
+        y = dcn_pipeline(x, params, config=GraphConfig(tile=4))
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
 
     def test_records_identical_to_batched(self):
         """The per-image spans of the fused dispatch preserve each
         image's schedule order, so records — and the FIFO replay the
-        simulator cross-check consumes — are byte-identical."""
+        simulator cross-check consumes — are byte-identical to the
+        images run one at a time."""
         key = jax.random.PRNGKey(3)
         params = _layer(key, 4, 6)
         x = jax.random.normal(jax.random.fold_in(key, 2), (3, 13, 13, 4))
-        _, tr_b = dcn_pipeline(
-            x, params, return_trace=True,
-            config=PipelineConfig(tile=4, use_schedule_cache=False))
-        _, tr_f = dcn_pipeline(
-            x, params, return_trace=True,
-            config=PipelineConfig(tile=4, dispatch="batch_fused",
-                                  use_schedule_cache=False))
-        t = tr_f.images[0].grid.num_tiles
-        for i, (ib, im) in enumerate(zip(tr_b.images, tr_f.images)):
+        cfg = GraphConfig(tile=4, use_schedule_cache=False)
+        alone = _one_at_a_time(
+            lambda xi: dcn_pipeline(xi, params, return_trace=True,
+                                    config=cfg), x)
+        _, tr_f = dcn_pipeline(x, params, return_trace=True, config=cfg)
+        ib_all = [tr.groups[0] for _, tr in alone]
+        t = tr_f.groups[0].grid.num_tiles
+        for i, (ib, im) in enumerate(zip(ib_all, tr_f.groups)):
             assert [r.out_tile for r in ib.records] == \
                 [r.out_tile for r in im.records]
             assert [r.dep_tiles for r in ib.records] == \
                 [r.dep_tiles for r in im.records]
+            assert ib.batch_rows == (0, t)
             assert im.batch_rows == (i * t, (i + 1) * t)
-        assert tr_f.fifo_loads() == tr_b.fifo_loads()
+        assert (sum(g.fifo_replay().loads for g in tr_f.groups)
+                == sum(g.fifo_replay().loads for g in ib_all))
 
     def test_pipeline_fifo_equals_simulator(self):
         """Concatenated-schedule FIFO loads == sum of per-image simulator
@@ -127,9 +130,8 @@ class TestBatchFusedPipelineOracle:
         m = 2
         _, tr = dcn_pipeline(
             x, params, return_trace=True,
-            config=PipelineConfig(tile=8, buffer_tiles=m,
-                                  dispatch="batch_fused",
-                                  use_schedule_cache=False))
+            config=GraphConfig(tile=8, buffer_tiles=m,
+                               use_schedule_cache=False))
         from repro.core.deform import conv2d, offsets_to_coords
         offsets = conv2d(x, params.w_off, params.b_off)
         coords = offsets_to_coords(offsets.astype(jnp.float32), 3, "dcn2")
@@ -142,7 +144,7 @@ class TestBatchFusedPipelineOracle:
                 B, pp, grid, channels=4, c_out=4, kernel_size=3,
                 buffer_bytes=m * grid.tile_bytes(4, 4), dtype_bytes=4)
             sim_total += rep["scheduled"].tile_loads
-        assert tr.fifo_loads() == sim_total
+        assert sum(g.fifo_replay().loads for g in tr.groups) == sim_total
 
 
 class TestBatchFusedGraphOracle:
@@ -153,29 +155,30 @@ class TestBatchFusedGraphOracle:
         y_f = run_graph(convs, graph, x, config=GraphConfig(
             tile=4, dispatch="batch_fused", schedule_backend=backend,
             use_schedule_cache=False))
-        y_b = run_graph(convs, graph, x, config=GraphConfig(
-            tile=4, dispatch="batched", use_schedule_cache=False))
+        y_b = jnp.concatenate(_one_at_a_time(
+            lambda xi: run_graph(convs, graph, xi, config=GraphConfig(
+                tile=4, schedule_backend=backend,
+                use_schedule_cache=False)), x))
         np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_b),
                                    rtol=1e-5, atol=1e-5)
 
     def test_one_dispatch_per_segment_per_batch(self):
-        """ISSUE 5 acceptance: kernel dispatches per layer segment == 1
-        for the WHOLE batch (down from one per image)."""
+        """Kernel dispatches per layer segment == 1 for the WHOLE batch
+        (down from one per image run alone)."""
         convs, graph, x = _acceptance_case()
         x4 = jnp.concatenate([x, x[::-1]])          # batch of 4
-        _, tr_b = run_graph(convs, graph, x4,
-                            config=GraphConfig(tile=4, dispatch="batched"),
+        cfg = GraphConfig(tile=4)
+        alone = _one_at_a_time(
+            lambda xi: run_graph(convs, graph, xi, config=cfg,
+                                 return_trace=True), x4)
+        _, tr_f = run_graph(convs, graph, x4, config=cfg,
                             return_trace=True)
-        _, tr_f = run_graph(convs, graph, x4,
-                            config=GraphConfig(tile=4,
-                                               dispatch="batch_fused"),
-                            return_trace=True)
-        n_segments = sum(len(g.layer_stats)
-                         for g in tr_b.groups if g.image == 0)
+        n_segments = sum(len(g.layer_stats) for g in alone[0][1].groups)
         assert tr_f.dispatches_per_batch == n_segments
-        assert tr_b.kernel_dispatches == 4 * n_segments
+        assert sum(tr.kernel_dispatches for _, tr in alone) == \
+            4 * n_segments
         assert all(g.kernel_dispatches == 0 for g in tr_f.groups)
 
     def test_records_and_simulator_exact(self):
@@ -197,16 +200,17 @@ class TestBatchFusedGraphOracle:
 
     def test_records_identical_across_dispatch_modes(self):
         convs, graph, x = _acceptance_case(seed=2)
-        traces = {}
-        for disp in ("batched", "batch_fused"):
-            _, tr = run_graph(convs, graph, x,
-                              config=GraphConfig(tile=4, dispatch=disp,
-                                                 use_schedule_cache=False),
-                              return_trace=True)
-            traces[disp] = {(g.image, g.group): g for g in tr.groups}
-        assert traces["batched"].keys() == traces["batch_fused"].keys()
-        for k, gb in traces["batched"].items():
-            gf = traces["batch_fused"][k]
+        cfg = GraphConfig(tile=4, use_schedule_cache=False)
+        alone = _one_at_a_time(
+            lambda xi: run_graph(convs, graph, xi, config=cfg,
+                                 return_trace=True), x)
+        _, tr = run_graph(convs, graph, x, config=cfg, return_trace=True)
+        batch = {(g.image, g.group): g for g in tr.groups}
+        single = {(i, g.group): g for i, (_, tr_i) in enumerate(alone)
+                  for g in tr_i.groups}
+        assert single.keys() == batch.keys()
+        for k, gb in single.items():
+            gf = batch[k]
             assert [r.out_tile for r in gb.records] == \
                 [r.out_tile for r in gf.records]
             assert [r.dep_tiles for r in gb.records] == \
@@ -280,17 +284,18 @@ class TestRaggedBatchPadding:
         np.testing.assert_allclose(
             np.asarray(y[0]), np.broadcast_to(np.asarray(b), (tp, 5)),
             rtol=1e-6, atol=1e-6)
-        # Image 1's rows match the per-image batched schedule kernel.
+        # Image 1's rows match the per-tile kernel on the same schedule.
         nb = build_neighbour_tables(coords[1], grid)
-        dep_tbl, dep_cnt, idx1, cf1 = pack_schedule_tiles(
-            nb, grid, full.oid, full.iid, tp,
-            max(len(d) for d in full.iid))
-        y1 = dcn_fused_schedule(
-            x[1], jnp.asarray(dep_tbl), jnp.asarray(dep_cnt),
-            jnp.asarray(idx1), jnp.asarray(cf1), w, b, interpret=True)
+        y1 = []
+        for out_tile, deps in zip(full.oid, full.iid):
+            i1, c1 = pack_output_tile(nb, grid, out_tile, deps, tp)
+            y1.append(dcn_fused_tile(
+                x[1][jnp.asarray(deps)].reshape(len(deps) * tp, 3),
+                jnp.asarray(i1), jnp.asarray(c1), w, b, interpret=True))
         valid = np.asarray(batch.oid[t:]) >= 0
         np.testing.assert_allclose(np.asarray(y[t:][valid]),
-                                   np.asarray(y1), rtol=1e-5, atol=1e-5)
+                                   np.asarray(jnp.stack(y1)),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_pack_batch_schedules_rejects_mismatched_grids(self):
         s1 = DeviceSchedule(np.zeros(4, np.int32), np.zeros((4, 2), np.int32),
@@ -334,9 +339,8 @@ class TestDeviceScheduleHandoff:
         convs, graph, xg = _acceptance_case(seed=3)
 
         c0 = scheduler.host_schedule_builds.count
-        y = dcn_pipeline(x, params, config=PipelineConfig(
-            tile=4, dispatch="batch_fused", schedule_backend="device",
-            use_schedule_cache=False))
+        y = dcn_pipeline(x, params, config=GraphConfig(
+            tile=4, schedule_backend="device", use_schedule_cache=False))
         jax.block_until_ready(y)
         y = run_graph(convs, graph, xg, config=GraphConfig(
             tile=4, dispatch="batch_fused", schedule_backend="device",
@@ -346,12 +350,11 @@ class TestDeviceScheduleHandoff:
 
         # ... and the lazy trace path DOES assemble them (off hot path).
         _, tr = dcn_pipeline(x, params, return_trace=True,
-                             config=PipelineConfig(
-                                 tile=4, dispatch="batch_fused",
-                                 schedule_backend="device",
+                             config=GraphConfig(
+                                 tile=4, schedule_backend="device",
                                  use_schedule_cache=False))
         assert scheduler.host_schedule_builds.count > c0
-        assert all(im.records for im in tr.images)
+        assert all(g.records for g in tr.groups)
 
 
 class TestPartialBatchCacheHits:
@@ -427,13 +430,12 @@ class TestPartialBatchCacheHits:
 
 class TestConfigValidation:
     def test_batch_fused_accepted_everywhere(self):
-        assert PipelineConfig(dispatch="batch_fused").dispatch == \
-            "batch_fused"
+        assert GraphConfig().dispatch == "batch_fused"
         assert GraphConfig(dispatch="batch_fused").dispatch == "batch_fused"
 
     def test_unknown_dispatch_still_rejected(self):
         with pytest.raises(ValueError, match="dispatch"):
-            PipelineConfig(dispatch="fused_batch")
+            GraphConfig(dispatch="fused_batch")
         with pytest.raises(ValueError, match="dispatch"):
             GraphConfig(dispatch="mega")
 

@@ -1,11 +1,11 @@
-"""Batched tile-grid dispatch: oracle, trace-invariant and config tests.
+"""Tile-grid dispatch: oracle, trace-invariant and config tests.
 
-The batched executors (ISSUE 3) replace the per-tile Python dispatch loop
-with ONE ``pallas_call`` grid per (group, layer segment) — the Algorithm-1
-schedule becomes the grid order, the scalar-prefetched dep table the DMA
-sequence. These tests pin down that:
+The default ``batch_fused`` dispatch replaces the per-tile Python
+dispatch loop with ONE ``pallas_call`` grid per DCN layer — the
+Algorithm-1 schedule becomes the grid order, the scalar-prefetched dep
+table the DMA sequence. These tests pin down that:
 
-  * batched == per-tile == XLA reference numerics (rectangular tiles,
+  * grid == per-tile == XLA reference numerics (rectangular tiles,
     non-divisible shapes, multi-layer fused groups);
   * the executed trace still equals the DRAM simulator EXACTLY (the
     records are the schedule, which batching preserves);
@@ -21,14 +21,12 @@ import pytest
 
 from repro.core.deform import (deformable_conv2d, init_deformable_conv,
                                randomize_offset_conv)
-from repro.core.scheduler import schedule_tiles
+from repro.core.scheduler import DeviceSchedule, TileSchedule, schedule_tiles
 from repro.core.simulator import simulate_network
-from repro.core.tiles import TileGrid
-from repro.kernels.dcn_fused import dcn_fused_schedule
+from repro.kernels.dcn_fused import dcn_fused_batch
 from repro.models.dcn_models import DcnNetConfig, dcn_net_apply, init_dcn_net
-from repro.runtime import (GraphConfig, PipelineConfig, build_neighbour_tables,
-                           dcn_pipeline, pack_schedule_tiles, run_graph,
-                           run_graph_dense)
+from repro.runtime import (GraphConfig, dcn_pipeline, pack_batch_schedules,
+                           run_graph, run_graph_dense)
 from repro.runtime.fused_exec import network_sim_specs
 from repro.serving import DcnServingEngine
 
@@ -54,20 +52,20 @@ class TestBatchedPipelineOracle:
         y_ref = deformable_conv2d(x, params)
         y_b, tr_b = dcn_pipeline(
             x, params, return_trace=True,
-            config=PipelineConfig(tile=tile, use_schedule_cache=False))
+            config=GraphConfig(tile=tile, use_schedule_cache=False))
         y_p, tr_p = dcn_pipeline(
             x, params, return_trace=True,
-            config=PipelineConfig(tile=tile, dispatch="per_tile",
-                                  staging_depth=1,
-                                  use_schedule_cache=False))
+            config=GraphConfig(tile=tile, dispatch="per_tile",
+                               staging_depth=1,
+                               use_schedule_cache=False))
         np.testing.assert_allclose(np.asarray(y_b), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(y_b), np.asarray(y_p),
                                    rtol=1e-5, atol=1e-5)
-        # One batched grid dispatch per image vs one per schedule entry.
-        assert tr_b.kernel_dispatches == 2
+        # One grid dispatch for the batch vs one per schedule entry.
+        assert tr_b.kernel_dispatches == 1
         assert tr_p.kernel_dispatches == sum(
-            len(im.records) for im in tr_p.images)
+            len(g.records) for g in tr_p.groups)
         assert tr_b.kernel_dispatches < tr_p.kernel_dispatches
 
     def test_staging_depth_does_not_change_numerics(self):
@@ -75,7 +73,7 @@ class TestBatchedPipelineOracle:
         params = _layer(key, 4, 6)
         x = jax.random.normal(jax.random.fold_in(key, 2), (3, 13, 13, 4))
         outs = [dcn_pipeline(x, params,
-                             config=PipelineConfig(tile=4, staging_depth=d))
+                             config=GraphConfig(tile=4, staging_depth=d))
                 for d in (1, 2, 3)]
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
@@ -87,14 +85,14 @@ class TestBatchedPipelineOracle:
         x = jax.random.normal(jax.random.fold_in(key, 2), (3, 16, 16, 4))
         _, tr = dcn_pipeline(
             x, params, return_trace=True,
-            config=PipelineConfig(tile=8, use_schedule_cache=False))
+            config=GraphConfig(tile=8, use_schedule_cache=False))
         assert tr.overlap.prepass_s > 0
         assert 0.0 <= tr.host_overlap_frac <= 1.0
 
 
 class TestBatchedGraphOracle:
     @pytest.mark.parametrize("dispatch,depth", [
-        ("batched", 1), ("batched", 2), ("per_tile", 2),
+        ("batch_fused", 1), ("batch_fused", 2), ("per_tile", 2),
     ])
     def test_matches_dense_reference(self, dispatch, depth):
         convs, graph, x = _acceptance_case()
@@ -108,7 +106,7 @@ class TestBatchedGraphOracle:
     def test_batched_equals_per_tile(self):
         convs, graph, x = _acceptance_case(seed=3)
         y_b = run_graph(convs, graph, x, config=GraphConfig(
-            tile=4, dispatch="batched", use_schedule_cache=False))
+            tile=4, use_schedule_cache=False))
         y_p = run_graph(convs, graph, x, config=GraphConfig(
             tile=4, dispatch="per_tile", use_schedule_cache=False))
         np.testing.assert_allclose(np.asarray(y_b), np.asarray(y_p),
@@ -116,15 +114,14 @@ class TestBatchedGraphOracle:
 
     @pytest.mark.parametrize("buffer_tiles", [None, 4, 2])
     def test_trace_matches_simulator_exactly(self, buffer_tiles):
-        """ISSUE 3 acceptance: the batched path's executed trace still
-        agrees EXACTLY with the network simulator's FIFO replay — the
-        schedule order became the grid order, so the records are
-        byte-identical to the per-tile executor's."""
+        """The grid path's executed trace of one image agrees EXACTLY
+        with the network simulator's FIFO replay — the schedule order
+        became the grid order, so the records are byte-identical to the
+        per-tile executor's."""
         convs, graph, x = _acceptance_case()
         _, trace = run_graph(
             convs, graph, x[:1],
-            config=GraphConfig(tile=4, buffer_tiles=buffer_tiles,
-                               dispatch="batched"),
+            config=GraphConfig(tile=4, buffer_tiles=buffer_tiles),
             return_trace=True)
         sim = simulate_network(network_sim_specs(trace),
                                boundary_bytes=trace.boundary_bytes,
@@ -137,12 +134,12 @@ class TestBatchedGraphOracle:
     def test_records_identical_across_dispatch_modes(self):
         convs, graph, x = _acceptance_case(seed=1)
         traces = {}
-        for disp in ("batched", "per_tile"):
+        for disp in ("batch_fused", "per_tile"):
             _, tr = run_graph(convs, graph, x[:1],
                               config=GraphConfig(tile=4, dispatch=disp),
                               return_trace=True)
             traces[disp] = tr
-        for gb, gp in zip(traces["batched"].groups,
+        for gb, gp in zip(traces["batch_fused"].groups,
                           traces["per_tile"].groups):
             assert [r.out_tile for r in gb.records] == \
                 [r.out_tile for r in gp.records]
@@ -150,60 +147,79 @@ class TestBatchedGraphOracle:
                 [r.dep_tiles for r in gp.records]
 
     def test_dispatch_count_bounded_by_segments(self):
-        """ISSUE 3 acceptance: kernel dispatches per group <= number of
-        layer segments (was O(num_tiles x layers))."""
+        """Kernel dispatches of one image == its number of layer
+        segments (per_tile pays O(num_tiles x layers))."""
         convs, graph, x = _acceptance_case()
         _, tr_b = run_graph(convs, graph, x[:1],
-                            config=GraphConfig(tile=4, dispatch="batched"),
-                            return_trace=True)
+                            config=GraphConfig(tile=4), return_trace=True)
         _, tr_p = run_graph(convs, graph, x[:1],
                             config=GraphConfig(tile=4, dispatch="per_tile"),
                             return_trace=True)
-        for gt in tr_b.groups:
-            assert gt.kernel_dispatches <= len(gt.layer_stats)
+        assert tr_b.kernel_dispatches == sum(len(gt.layer_stats)
+                                             for gt in tr_b.groups)
         assert tr_b.kernel_dispatches < tr_p.kernel_dispatches
 
     def test_batched_is_default(self):
         convs, graph, x = _acceptance_case()
-        assert GraphConfig().dispatch == "batched"
-        assert PipelineConfig().dispatch == "batched"
+        assert GraphConfig().dispatch == "batch_fused"
+        with pytest.raises(ValueError, match="dispatch"):
+            GraphConfig(dispatch="batched")
         _, tr = run_graph(convs, graph, x[:1],
                           config=GraphConfig(tile=4), return_trace=True)
-        assert all(g.dispatch == "batched" for g in tr.groups)
+        assert all(g.dispatch == "batch_fused" for g in tr.groups)
 
 
 class TestEmptyScheduleAndPacking:
-    def test_fused_schedule_kernel_empty(self):
-        x_tiles = jnp.zeros((4, 16, 3))
-        dep_tbl = jnp.zeros((0, 2), jnp.int32)
-        dep_cnt = jnp.zeros((0,), jnp.int32)
-        idx = jnp.zeros((0, 16, 9, 4), jnp.int32)
-        coeff = jnp.zeros((0, 16, 9, 4), jnp.float32)
-        w = jnp.zeros((9, 3, 5))
-        b = jnp.zeros((5,))
-        y = dcn_fused_schedule(x_tiles, dep_tbl, dep_cnt, idx, coeff, w, b,
-                               interpret=True)
+    @staticmethod
+    def _run_batch(batch, t=4):
+        """The batch kernel over ``batch`` on random 4x4-tile operands
+        of one image with ``t`` tiles; returns (rows, bias)."""
+        key = jax.random.PRNGKey(0)
+        x = jax.random.normal(key, (t, 16, 3))
+        idx = jax.random.randint(jax.random.fold_in(key, 1),
+                                 (t, 16, 9, 4), 0, t * 16)
+        coeff = jax.random.uniform(jax.random.fold_in(key, 2),
+                                   (t, 16, 9, 4))
+        w = jax.random.normal(jax.random.fold_in(key, 3), (9, 3, 5))
+        b = jax.random.normal(jax.random.fold_in(key, 4), (5,))
+        y = dcn_fused_batch(x, jnp.asarray(batch.row_id),
+                            jnp.asarray(batch.dep_glb),
+                            jnp.asarray(batch.dep_cnt), idx, coeff, w, b,
+                            t_in=t, interpret=True)
+        return np.asarray(y), np.asarray(b)
+
+    def test_fused_batch_kernel_empty(self):
+        """An empty batch grid dispatches nothing."""
+        y = dcn_fused_batch(
+            jnp.zeros((4, 16, 3)), jnp.zeros((0,), jnp.int32),
+            jnp.zeros((0, 2), jnp.int32), jnp.zeros((0,), jnp.int32),
+            jnp.zeros((4, 16, 9, 4), jnp.int32),
+            jnp.zeros((4, 16, 9, 4), jnp.float32), jnp.zeros((9, 3, 5)),
+            jnp.zeros((5,)), t_in=4, interpret=True)
         assert y.shape == (0, 16, 5)
 
-    def test_pack_schedule_tiles_empty_schedule(self):
-        grid = TileGrid(8, 8, 4, 4)
-        coords = jnp.zeros((8, 8, 9, 2))
-        nb = build_neighbour_tables(coords, grid)
-        dep_tbl, dep_cnt, idx, coeff = pack_schedule_tiles(
-            nb, grid, [], [], 16, 2)
-        assert dep_tbl.shape == (0, 2)
-        assert dep_cnt.shape == (0,)
-        assert idx.shape == (0, 16, 9, 4)
+    def test_pack_batch_schedules_empty_schedule(self):
+        """A schedule with no steps packs to padding rows only, which
+        the kernel skips: every row is the bias."""
+        empty = DeviceSchedule.from_host(TileSchedule(oid=[], iid=[]), 4)
+        batch = pack_batch_schedules([empty], 4, 4)
+        assert batch.dep_glb.shape[0] == 4
+        assert batch.oid.tolist() == [-1] * 4
+        assert batch.dep_cnt.tolist() == [0] * 4
+        y, b = self._run_batch(batch)
+        np.testing.assert_array_equal(y, np.broadcast_to(b, y.shape))
 
-    def test_pack_schedule_tiles_empty_dep_row_zero_coeff(self):
-        grid = TileGrid(8, 8, 4, 4)
-        coords = jnp.zeros((8, 8, 9, 2))
-        nb = build_neighbour_tables(coords, grid)
-        dep_tbl, dep_cnt, idx, coeff = pack_schedule_tiles(
-            nb, grid, [0, 1], [[0, 1], []], 16, 2)
-        assert dep_cnt.tolist() == [2, 0]
-        assert coeff[1].sum() == 0.0
-        assert coeff[0].sum() > 0.0
+    def test_pack_batch_schedules_empty_dep_row_zero_coeff(self):
+        """A scheduled step with no deps contributes the bias only: its
+        BLI coefficients never reach the accumulator."""
+        sched = TileSchedule(oid=[0, 1], iid=[[0, 1], []])
+        batch = pack_batch_schedules(
+            [DeviceSchedule.from_host(sched, 4)], 4, 4)
+        assert batch.dep_cnt.tolist() == [2, 0, 0, 0]
+        assert batch.oid.tolist() == [0, 1, -1, -1]
+        y, b = self._run_batch(batch)
+        np.testing.assert_array_equal(y[1], np.broadcast_to(b, y[1].shape))
+        assert not np.allclose(y[0], b)
 
     def test_schedule_dense_roundtrip(self):
         B = np.zeros((4, 4), bool)
@@ -242,13 +258,13 @@ class TestConfigValidation:
         with pytest.raises(ValueError, match="dispatch"):
             GraphConfig(dispatch="warp")
         with pytest.raises(ValueError, match="dispatch"):
-            PipelineConfig(dispatch="warp")
+            GraphConfig(dispatch="batched")
 
     def test_bad_staging_depth_rejected(self):
         with pytest.raises(ValueError, match="staging_depth"):
             GraphConfig(staging_depth=0)
         with pytest.raises(ValueError, match="staging_depth"):
-            PipelineConfig(staging_depth=-1)
+            GraphConfig(dispatch="per_tile", staging_depth=-1)
 
     def test_graph_backend_clamps_small_images(self):
         """backend="graph" with the DEFAULT GraphConfig (tile=8) must

@@ -13,9 +13,8 @@ from repro.core.scheduler import (schedule_tiles, schedule_tiles_device,
 from repro.core.tiles import TileGrid, tdt_from_coords
 from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
                                         tdt_from_coords_device)
-from repro.runtime import (GraphConfig, PipelineConfig, ScheduleCache,
-                           build_graph, dcn_pipeline, run_graph,
-                           run_graph_dense)
+from repro.runtime import (GraphConfig, ScheduleCache, build_graph,
+                           dcn_pipeline, run_graph, run_graph_dense)
 from repro.runtime.cache import coords_digest
 
 
@@ -147,18 +146,18 @@ class TestDeviceBackendPipeline:
         ref = deformable_conv2d(x, params, 3, "dcn2")
         traces = {}
         for backend in ("host", "device"):
-            cfg = PipelineConfig(tile=8, buffer_tiles=2,
-                                 use_schedule_cache=False,
-                                 schedule_backend=backend)
+            cfg = GraphConfig(tile=8, buffer_tiles=2,
+                              use_schedule_cache=False,
+                              schedule_backend=backend)
             y, tr = dcn_pipeline(x, params, config=cfg, return_trace=True)
             np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                        rtol=1e-4, atol=1e-4)
             traces[backend] = tr
         # Bit-exact schedules -> identical executed tile records.
-        for im_h, im_d in zip(traces["host"].images,
-                              traces["device"].images):
+        for im_h, im_d in zip(traces["host"].groups,
+                              traces["device"].groups):
             assert im_h.records == im_d.records
-        assert traces["device"].images[0].schedule_backend == "device"
+        assert traces["device"].groups[0].schedule_backend == "device"
         assert traces["device"].schedule_device_frac == 1.0
         assert traces["host"].schedule_device_frac == 0.0
         assert traces["device"].overlap.schedule_s > 0
@@ -167,16 +166,16 @@ class TestDeviceBackendPipeline:
         from benchmarks.workloads import executor_case
         params, x = executor_case(16, 16, 4, 4, 2)
         ref = deformable_conv2d(x, params, 3, "dcn2")
-        cfg = PipelineConfig(tile=8, buffer_tiles=2, dispatch="per_tile",
-                             use_schedule_cache=False,
-                             schedule_backend="device")
+        cfg = GraphConfig(tile=8, buffer_tiles=2, dispatch="per_tile",
+                          use_schedule_cache=False,
+                          schedule_backend="device")
         y = dcn_pipeline(x, params, config=cfg)
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
 
     def test_invalid_backend_rejected(self):
         with pytest.raises(ValueError, match="schedule backend"):
-            PipelineConfig(schedule_backend="asic")
+            GraphConfig(dispatch="per_tile", schedule_backend="asic")
         with pytest.raises(ValueError, match="schedule backend"):
             GraphConfig(schedule_backend="asic")
 
@@ -249,8 +248,8 @@ class TestScheduleCacheTileShape:
         cache.clear()
         for tile in ((4, 4), (4, 8), (8, 8)):
             y = dcn_pipeline(x, params,
-                             config=PipelineConfig(tile=tile,
-                                                   buffer_tiles=2))
+                             config=GraphConfig(tile=tile,
+                                                buffer_tiles=2))
             np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                        rtol=1e-4, atol=1e-4)
         # Same coords under three tile shapes (16x16 plane, nothing is
@@ -259,7 +258,7 @@ class TestScheduleCacheTileShape:
         assert cache.info()["misses"] == 3 * x.shape[0]
         # ... while a genuine replay (same coords AND tile) hits.
         dcn_pipeline(x, params,
-                     config=PipelineConfig(tile=(4, 8), buffer_tiles=2))
+                     config=GraphConfig(tile=(4, 8), buffer_tiles=2))
         assert cache.info()["hits"] == x.shape[0]
 
     def test_graph_clamped_tiles_share_entries_legitimately(self):

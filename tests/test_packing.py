@@ -131,13 +131,13 @@ class TestScheduleCache:
                                    rtol=0, atol=0)
 
     def test_cache_disabled(self):
-        from repro.runtime import PipelineConfig
+        from repro.runtime import GraphConfig
         key = jax.random.PRNGKey(8)
         params = _layer(key, 4, 4)
         x = jax.random.normal(jax.random.fold_in(key, 2), (1, 8, 8, 4))
         _, tr_off = dcn_pipeline(
             x, params, return_trace=True,
-            config=PipelineConfig(tile=4, use_schedule_cache=False))
+            config=GraphConfig(tile=4, use_schedule_cache=False))
         assert tr_off.schedule_cache_hits == 0
         assert tr_off.schedule_cache_misses == 0
 

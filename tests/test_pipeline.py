@@ -19,7 +19,7 @@ from repro.core.deform import (conv2d, deformable_conv2d,
 from repro.core.simulator import simulate_strategies
 from repro.core.tiles import TileGrid, per_pixel_input_tiles, tdt_from_coords
 from repro.models.dcn_models import DcnNetConfig, dcn_net_apply, init_dcn_net
-from repro.runtime import PipelineConfig, dcn_pipeline
+from repro.runtime import GraphConfig, dcn_pipeline
 
 
 def _layer(key, c_in, c_out, variant="dcn2", offset_scale=0.5,
@@ -98,7 +98,7 @@ class TestPipelineOracle:
                                    rtol=1e-4, atol=1e-4)
 
 
-class TestPipelineTrace:
+class TestLayerTrace:
     def _run(self, h=16, w=16, tile=8, m=2, seed=0):
         key = jax.random.PRNGKey(seed)
         params = _layer(key, 4, 4, offset_scale=1.0)
@@ -111,7 +111,7 @@ class TestPipelineTrace:
 
     def test_schedule_covers_every_output_tile(self):
         _, trace, _, grid = self._run()
-        im = trace.images[0]
+        im = trace.groups[0]
         executed = sorted(r.out_tile for r in im.records)
         assert executed == list(range(grid.num_tiles))
 
@@ -127,14 +127,15 @@ class TestPipelineTrace:
                                   kernel_size=3,
                                   buffer_bytes=m * tile_bytes,
                                   dtype_bytes=4)
-        assert trace.fifo_loads() == rep["scheduled"].tile_loads
-        assert trace.packed_bytes == trace.packed_tile_loads * tile_bytes
+        (im,) = trace.groups
+        assert im.fifo_replay().loads == rep["scheduled"].tile_loads
+        assert im.packed_bytes == im.packed_tile_loads * tile_bytes
 
     def test_packed_deps_match_tdt(self):
         """Each dispatch packs exactly the TDT row of its output tile."""
         _, trace, coords, grid = self._run(h=13, w=13, tile=8)
         B = np.asarray(tdt_from_coords(coords, grid, grid))
-        for r in trace.images[0].records:
+        for r in trace.groups[0].records:
             assert (sorted(r.dep_tiles)
                     == np.flatnonzero(B[r.out_tile]).tolist())
 
@@ -147,7 +148,7 @@ class TestPipelineModelBackend:
         x = jax.random.normal(jax.random.PRNGKey(3), (1, 16, 16, 3))
         y_xla = dcn_net_apply(p, cfg, x, backend="xla", fused=False)
         y_pipe = dcn_net_apply(p, cfg, x, backend="pipeline",
-                               pipeline=PipelineConfig(tile=2))
+                               pipeline=GraphConfig(tile=2))
         np.testing.assert_allclose(np.asarray(y_pipe), np.asarray(y_xla),
                                    rtol=5e-3, atol=5e-3)
 

@@ -153,6 +153,38 @@ class TestRequestIsolation:
         assert s["requests_failed"] == 3
         assert s["degraded_steps"] >= 1
 
+    def test_degraded_step_runs_batch_fused_programs(self, dcn_setup):
+        """A step whose dispatch faults and whose retry faults too
+        degrades to its images one at a time through the compiled
+        batch-fused programs: the survivors equal a clean step's output,
+        and every group of every survivor ran its execute program."""
+        inj = FaultInjector(kinds=("dispatch",), rate=1.0, max_fires=2,
+                            seed=3)
+        eng = _engine(dcn_setup, slots=4, faults=inj)
+        xs = _images(3, seed=6)
+        reqs = [eng.submit(xs[i]) for i in range(3)]
+        eng.drain()
+        programs = eng.exec_programs    # process-wide counter: read now
+        assert inj.fired["dispatch"] == 2
+        s = eng.stats
+        assert s["step_retries"] == 1 and s["degraded_steps"] == 1
+        survivors = [i for i, r in enumerate(reqs) if not r.failed]
+        assert len(survivors) == 2
+        cfg, params = dcn_setup
+        clean = DcnServingEngine(params, cfg, graph=GraphConfig(tile=4),
+                                 slots=4)
+        clean_reqs = [clean.submit(xs[i]) for i in range(3)]
+        clean.drain()
+        assert clean.stats["steps"] == 1
+        for i in survivors:
+            np.testing.assert_allclose(reqs[i].result(),
+                                       clean_reqs[i].result(),
+                                       rtol=2e-4, atol=2e-4)
+        # The faulted step and its retry fail before any group executes;
+        # one clean step runs each group's program once.
+        assert clean.exec_programs > 0
+        assert programs == len(survivors) * clean.exec_programs
+
     def test_cache_miss_storm_correct_but_cold(self, dcn_setup):
         """A cache_miss storm (every key salted) forces rebuilds: image
         hits stay 0 where a replay would normally hit, and results stay

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.runtime import GraphConfig, PipelineConfig, plan_batch_shards
+from repro.runtime import GraphConfig, plan_batch_shards
 from repro.runtime.shard import (allgather_nbytes, shard_batch_schedules,
                                  stack_rows, unstack_rows)
 
@@ -171,15 +171,15 @@ class TestShardPack:
 class TestShardConfigValidation:
     def test_sharding_requires_batch_fused(self):
         with pytest.raises(ValueError, match="batch_fused"):
-            GraphConfig(dispatch="batched", data_parallel=2)
+            GraphConfig(dispatch="per_tile", data_parallel=2)
         with pytest.raises(ValueError, match="batch_fused"):
-            PipelineConfig(dispatch="per_tile", data_parallel=2)
+            GraphConfig(dispatch="per_tile", mesh=object())
 
     def test_data_parallel_bounds(self):
         with pytest.raises(ValueError, match="data_parallel"):
             GraphConfig(dispatch="batch_fused", data_parallel=0)
         # data_parallel=1 is the single-device no-op, any dispatch
-        GraphConfig(dispatch="batched", data_parallel=1)
+        GraphConfig(dispatch="per_tile", data_parallel=1)
 
     def test_shard_sizes_requires_sharded_config(self):
         from tests.test_graph import _acceptance_case
@@ -220,9 +220,8 @@ class TestShardedParity:
             from repro.core.deform import (deformable_conv2d,
                                            init_deformable_conv,
                                            randomize_offset_conv)
-            from repro.runtime import (GraphConfig, PipelineConfig,
-                                       dcn_pipeline, run_graph,
-                                       run_graph_dense)
+            from repro.runtime import (GraphConfig, dcn_pipeline,
+                                       run_graph, run_graph_dense)
             from tests.test_graph import _acceptance_case
             assert jax.device_count() == 4
 
@@ -233,11 +232,11 @@ class TestShardedParity:
             x = jax.random.normal(jax.random.fold_in(key, 2),
                                   (5, 13, 13, 5))
             y_ref = deformable_conv2d(x, params)
-            y0 = dcn_pipeline(x, params, config=PipelineConfig(
+            y0 = dcn_pipeline(x, params, config=GraphConfig(
                 tile=4, dispatch="batch_fused",
                 use_schedule_cache=False))
             for dp in (2, 4):
-                y = dcn_pipeline(x, params, config=PipelineConfig(
+                y = dcn_pipeline(x, params, config=GraphConfig(
                     tile=4, dispatch="batch_fused", data_parallel=dp,
                     use_schedule_cache=False))
                 assert np.array_equal(np.asarray(y), np.asarray(y0)), dp

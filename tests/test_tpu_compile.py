@@ -23,8 +23,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 from repro.core.deform import DeformableConvParams
 from repro.core.tiles import TileGrid
 from repro.kernels.dcn_fused import (_dcn_fused_batch_jit,
-                                     _dcn_fused_batch_sharded_jit,
-                                     _dcn_fused_schedule_jit)
+                                     _dcn_fused_batch_sharded_jit)
 from repro.kernels.dcn_schedule import (greedy_schedule_arrays,
                                         tdt_from_coords_device)
 from repro.runtime.fused_exec import (_group_lead_program,
@@ -95,8 +94,9 @@ def _fused_operands(s, rows, c_in):
 class TestFusedKernels:
     """C_in not a multiple of 128 used to fail Mosaic's shape cast."""
 
-    def test_batch_kernel(self, one_chip, c_in, precision):
-        n = 4
+    @pytest.mark.parametrize("n", [1, 4])
+    def test_batch_kernel(self, one_chip, c_in, precision, n):
+        """Width 1 is the serving engine's degraded step."""
         s = one_chip
         _compile(_dcn_fused_batch_jit,
                  _shape(s, (n * T_IN, TP, c_in), jnp.float32),
@@ -106,16 +106,6 @@ class TestFusedKernels:
                  *_fused_operands(s, n * T_IN, c_in),
                  precision=precision, t_in=T_IN, kernel_size=3,
                  block_p=128, interpret=False)
-
-    def test_schedule_kernel(self, one_chip, c_in, precision):
-        s = one_chip
-        _compile(_dcn_fused_schedule_jit,
-                 _shape(s, (T_IN, TP, c_in), jnp.float32),
-                 _shape(s, (T_IN, K_PAD), jnp.int32),
-                 _shape(s, (T_IN,), jnp.int32),
-                 *_fused_operands(s, T_IN, c_in),
-                 precision=precision, kernel_size=3, block_p=128,
-                 interpret=False)
 
 
 @pytest.mark.parametrize("plane", [28, 224])
