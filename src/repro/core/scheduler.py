@@ -1,11 +1,12 @@
 """Runtime tile scheduler — paper §IV-C, Algorithm 1, Fig. 10.
 
-Faithful implementation of the paper's bit-vector-based tile scheduling:
+The paper's bit-vector-based tile scheduling:
 
-  * ``output_tile_scheduling``  — greedily pick the un-executed output tile
+  * output tile scheduling — greedily pick the un-executed output tile
     whose input-tile dependency vector overlaps most with the current one
-    (hardware: AND + non-zero-bit adder tree + pipelined max comparator).
-  * ``input_tile_scheduling``   — order the dependent input tiles of the
+    (hardware: AND + non-zero-bit adder tree + pipelined max comparator;
+    ties go to the lowest tile id).
+  * input tile scheduling  — order the dependent input tiles of the
     *next* output tile in three priority classes:
        1. already resident on-chip            (loadedVec)   — reuse first,
        2. everything else                     (seqLoadVec)  — middle,
@@ -16,15 +17,21 @@ Faithful implementation of the paper's bit-vector-based tile scheduling:
     strategy is used for the input tile replacement for efficient hardware
     implementation").
 
-Two backends. The default host backend is a numpy reference of the
-paper's dedicated hardware block ("pre-scheduling" runs concurrently
-with the PE array); ``backend="device"`` runs the same greedy selection
-as a Pallas kernel (``kernels.dcn_schedule.greedy_schedule_arrays``) —
-the step loop becomes the kernel grid, the resident-set bitmask lives in
-VMEM, and the host only reassembles the emitted order — matching the
-paper's on-chip scheduler architecture. Both backends are bit-exact:
-they produce byte-identical ``TileSchedule``s on every input. On TPU the
-schedule orders the Pallas grid / DMA sequence (``kernels.dcn_fused``).
+Two backends. The default host backend computes the overlap matrix
+O = B·Bᵀ once per TDT (:func:`overlap_matrix`, a sparse integer product)
+and then reads one row of it a step: the next tile is the first argmax
+of ``O[curr]`` over the remaining tiles, and the input classes are
+decided on the next tile's few dependencies against per-tile FIFO load
+sequence numbers. Its cost is O(Σ_i |B[:, i]|² + n_out²) for O plus
+O(n_out + deps) a step — not the O(n_out · n_in) table sweep a step of
+the literal procedure. ``backend="device"`` runs the same greedy
+selection as a Pallas kernel
+(``kernels.dcn_schedule.greedy_schedule_arrays``) — the step loop
+becomes the kernel grid, the resident-set bitmask lives in VMEM, and the
+host only reassembles the emitted order — matching the paper's on-chip
+scheduler architecture. Both backends are bit-exact: they produce
+byte-identical ``TileSchedule``s on every input. On TPU the schedule
+orders the Pallas grid / DMA sequence (``kernels.dcn_fused``).
 
 The module also provides the two ablation baselines of paper Fig. 14-16:
 ``sequential_schedule`` (W/ bit vector + W/O scheduling) and the naive
@@ -33,6 +40,7 @@ per-pixel path lives in ``repro.core.simulator``.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -93,17 +101,18 @@ class TileSchedule:
         two (uniform packed-buffer geometry -> one kernel compilation).
         """
         t = len(self.oid)
-        k_max = max((len(d) for d in self.iid), default=1)
+        counts = np.array([len(d) for d in self.iid], np.int32).reshape(t)
+        k_max = int(counts.max()) if t else 1
         if k_pad is None:
             k_pad = pow2_pad(k_max)
         elif k_pad < k_max:
             raise ValueError(f"k_pad={k_pad} below max dep count {k_max}")
         oid = np.asarray(self.oid, np.int32).reshape(t)
         deps = np.zeros((t, k_pad), np.int32)
-        counts = np.zeros((t,), np.int32)
-        for n, d in enumerate(self.iid):
-            deps[n, :len(d)] = d
-            counts[n] = len(d)
+        # Row-major boolean fill: row n takes the next counts[n] ids.
+        deps[np.arange(k_pad) < counts[:, None]] = np.fromiter(
+            itertools.chain.from_iterable(self.iid), np.int32,
+            int(counts.sum()))
         return oid, deps, counts
 
 
@@ -132,36 +141,31 @@ class FifoBuffer:
         self.resident.add(tile)
         return False
 
-    def occupancy_vector(self, n: int) -> np.ndarray:
-        oc = np.zeros(n, dtype=bool)
-        oc[list(self.resident)] = True
-        return oc
-
 
 def _ids_of(vec: np.ndarray) -> list[int]:
     return np.flatnonzero(vec).tolist()
 
 
-def output_tile_scheduling(B: np.ndarray, os_mask: np.ndarray,
-                           curr_id: int) -> int:
-    """Algorithm 1, procedure output_tile_scheduling.
+def overlap_matrix(B: np.ndarray) -> np.ndarray:
+    """O = B·Bᵀ as exact int32 counts: ``O[a, b] = |B[a] & B[b]|``.
 
-    Picks the un-executed output tile with the largest dependency overlap
-    with ``curr_id``. Ties are broken by the lowest tile id (the paper's
-    pipelined comparator keeps the first maximum).
+    A sparse product: every input tile adds one to ``O[a, b]`` for each
+    pair of output tiles (a, b) that both depend on it, so the cost is
+    Σ_i |B[:, i]|² increments plus the n_out² table, not the dense
+    n_out² · n_in.
     """
-    overlap = (B & B[curr_id]).sum(axis=1)
-    overlap[~os_mask] = -1
-    return int(np.argmax(overlap))
-
-
-def input_tile_scheduling(B: np.ndarray, curr_id: int, next_id: int,
-                          oc: np.ndarray) -> list[int]:
-    """Algorithm 1, procedure input_tile_scheduling (3 priority classes)."""
-    loaded_vec = oc & B[next_id]
-    last_load_vec = B[curr_id] & B[next_id] & ~loaded_vec
-    seq_load_vec = B[next_id] & ~loaded_vec & ~last_load_vec
-    return _ids_of(loaded_vec) + _ids_of(seq_load_vec) + _ids_of(last_load_vec)
+    n_out = B.shape[0]
+    rows, cols = np.nonzero(B)
+    by_tile = np.argsort(cols, kind="stable")
+    tile, dependant = cols[by_tile], rows[by_tile]
+    size = np.bincount(tile, minlength=B.shape[1])
+    start = np.cumsum(size) - size           # each group's first entry
+    reps = size[tile]                        # pairs per entry
+    a = np.repeat(dependant, reps)
+    k = np.arange(a.size) - np.repeat(np.cumsum(reps) - reps, reps)
+    b = dependant[np.repeat(start[tile], reps) + k]
+    counts = np.bincount(a * n_out + b, minlength=n_out * n_out)
+    return counts.astype(np.int32).reshape(n_out, n_out)
 
 
 def schedule_tiles(B, buffer_tiles: int, backend: str = "host",
@@ -171,44 +175,76 @@ def schedule_tiles(B, buffer_tiles: int, backend: str = "host",
     B: (n_out, n_in) bool tile-dependency table (TDT). May be a device
        array (it stays on-device for ``backend="device"``).
     buffer_tiles: M, on-chip input-buffer capacity in tiles.
-    backend: "host" — the numpy reference loop below; "device" — the
-       Pallas greedy-selection kernel (bit-exact with the host loop; see
+    backend: "host" — the numpy loop below; "device" — the Pallas
+       greedy-selection kernel (bit-exact with the host loop; see
        :func:`schedule_tiles_device`). ``interpret`` only applies to the
        device backend (None = auto: interpret off-accelerator).
 
     Returns the output-tile execution order and the per-tile input-load
-    order. The on-chip occupancy OC used for the priority classes is
-    maintained with the same FIFO model the execution will use.
+    order. The on-chip occupancy used for the priority classes is the
+    same FIFO model (:class:`FifoBuffer`) the execution replays.
+
+    The host loop computes the overlap matrix once
+    (:func:`overlap_matrix`) and reads one row of it a step: the
+    scheduled tiles' columns are set to -1, so ``argmax(O[curr])`` is
+    the remaining tile with the largest ``|B[curr] & B[o]|``, lowest id
+    first, and ``O[curr, nxt]`` is the step's reuse overlap. FIFO
+    residency is a load sequence number per input tile: with load-only
+    insertion and oldest-first eviction, a tile is resident iff it was
+    loaded by one of the last M loads. A step thus costs O(n_out) for
+    the row plus O(deps of the next tile) for its input classes; O
+    costs O(Σ_i |B[:, i]|² + n_out²) once.
     """
     if backend == "device":
         return schedule_tiles_device(B, buffer_tiles, interpret=interpret)
     if backend != "host":
         raise ValueError(f"unknown schedule backend: {backend!r}")
+    if buffer_tiles < 1:
+        raise ValueError("buffer capacity must be >= 1 tile")
     B = np.asarray(B, dtype=bool)
     n_out, n_in = B.shape
-    os_mask = B.any(axis=1)  # output tiles that actually need inputs
-    buf = FifoBuffer(buffer_tiles)
+    n_deps = B.sum(axis=1)
+    ends = np.cumsum(n_deps).tolist()
+    cols = np.nonzero(B)[1].tolist()
+    deps = [cols[e - n:e] for e, n in zip(ends, n_deps.tolist())]
+
+    overlap = overlap_matrix(B)
+    overlap[:, n_deps == 0] = -1             # never scheduled
+    last_load = [-buffer_tiles - 1] * n_in   # resident iff >= loads - M
+    loads = 0
 
     # line 2: first output tile = the one requiring the most input tiles.
-    first = int(np.argmax(np.where(os_mask, B.sum(axis=1), -1)))
+    first = int(np.argmax(np.where(n_deps > 0, n_deps, -1)))
+    overlap[:, first] = -1
+    for t in deps[first]:
+        last_load[t] = loads
+        loads += 1
     oid = [first]
-    iid = [_ids_of(B[first])]
+    iid = [deps[first]]
     overlaps: list[int] = []
-    for t in iid[0]:
-        buf.touch(t)
-    os_mask[first] = False
 
-    while os_mask.any():
-        curr = oid[-1]
-        nxt = output_tile_scheduling(B, os_mask, curr)
-        oc = buf.occupancy_vector(n_in)
-        order = input_tile_scheduling(B, curr, nxt, oc)
+    curr = first
+    for _ in range(int((n_deps > 0).sum()) - 1):
+        row = overlap[curr]
+        nxt = int(row.argmax())
+        overlaps.append(int(row[nxt]))
+        overlap[:, nxt] = -1
+        shared = set(deps[curr])
+        resident_from = loads - buffer_tiles
+        loaded_ids, seq_ids, last_ids = [], [], []
+        for t in deps[nxt]:
+            if last_load[t] >= resident_from:
+                loaded_ids.append(t)
+            elif t in shared:
+                last_ids.append(t)
+            else:
+                seq_ids.append(t)
+        for t in seq_ids + last_ids:
+            last_load[t] = loads
+            loads += 1
         oid.append(nxt)
-        iid.append(order)
-        overlaps.append(int((B[curr] & B[nxt]).sum()))
-        for t in order:
-            buf.touch(t)
-        os_mask[nxt] = False
+        iid.append(loaded_ids + seq_ids + last_ids)
+        curr = nxt
 
     host_schedule_builds.bump()
     return TileSchedule(oid=oid, iid=iid, reuse_overlap=overlaps)
@@ -224,7 +260,7 @@ def assemble_device_schedule(oid_seq: np.ndarray, klass: np.ndarray,
     klass:   (n_out, n_in) int32 — per-step input priority class
              (0 loadedVec / 1 seqLoadVec / 2 lastLoadVec / 3 non-dep);
              the load order is ids(0) asc ++ ids(1) asc ++ ids(2) asc,
-             exactly ``input_tile_scheduling``'s three classes.
+             exactly the host loop's three classes.
     overlap: (n_out,) or (n_out, 1) int32 — per-step reuse overlap.
 
     This residual host work is O(total deps) bookkeeping — the O(T^2 *

@@ -262,7 +262,7 @@ def dispatch_arrays_from_klass(
       oid     (n_out,)       int32 — scheduled tile per step (-1 padding)
       dep_tbl (n_out, k_pad) int32 — dependent input tiles in LOAD order:
               class 0 (loaded) ids asc ++ class 1 (seq) asc ++ class 2
-              (last) asc — exactly ``input_tile_scheduling``'s order,
+              (last) asc — exactly the host loop's order,
               recovered with one stable argsort over the class row.
       dep_cnt (n_out,)       int32 — true dep count (0 on padded steps).
 
