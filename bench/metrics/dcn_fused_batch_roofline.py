@@ -1,7 +1,9 @@
 """Least time the chip could take for the fused kernel's calls in the
 window, over their measured device time, in %. Each call's least time is
 the larger of its FLOPs over the peak and its bytes over the bandwidth
-(``bench/kernels/dcn_fused_batch.py``)."""
+(``bench/kernels/dcn_fused_batch.py``). Over several chips the measured
+time is summed over every device plane and the least time is that of the
+whole step's work, so the share is the mean of the chips' shares."""
 
 import sys
 

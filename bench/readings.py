@@ -24,7 +24,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from bench import model, program, run  # noqa: E402
+from bench import program, run  # noqa: E402
 
 
 def control_reading(served: run.Served) -> float:
@@ -32,9 +32,10 @@ def control_reading(served: run.Served) -> float:
     worst = 0.0
     for img, _ in served.answers:
         x = jnp.asarray(served.mix.image(img)[None])
-        ref = np.asarray(model.reference(served.net, served.params, x))
-        low = np.asarray(model.reference(served.net, served.params, x,
-                                         "high"))
+        ref = np.asarray(served.ref.reference(served.net, served.params,
+                                              x))
+        low = np.asarray(served.ref.reference(served.net, served.params,
+                                              x, "high"))
         worst = max(worst, run.rel_err(low, ref))
     return worst
 
@@ -58,8 +59,10 @@ def main(argv=None) -> int:
         served = run.serve(found, seed, args.seconds, False, device)
         row = {"seed": seed, "answers": len(served.answers),
                "missing": served.missing, "failed": served.failed,
-               "program": run.compare(served.net, served.params,
-                                      served.mix, served.answers,
+               "replicas": served.replicas,
+               "program": run.compare(served.ref, served.net,
+                                      served.params, served.mix,
+                                      served.answers,
                                       found["config"]["precision"]),
                "control": control_reading(served)}
         print(json.dumps(row), flush=True)

@@ -4,13 +4,15 @@
         --trace <0|1>
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: its
-configuration file (``bench/configs``), its traffic mix
+configuration file (``bench/configs``), the plain reference that file
+names under ``reference`` (a module of ``Net.from_config``, ``layers``,
+``build_weights``, ``reference`` and ``forward_flops``), its traffic mix
 (``bench/traffic/<name>.json``, read by ``bench/traffic.py``) and, with
 ``--trace 1``, one reader per per-layer metric (``bench/metrics``).
 
 One run: device check (a TPU, Pallas compiled, enough chips, a known
 peak), set-up (weights on the device from the seed, the serving engine
-with its defaults, warm-up of every step width the mix drives), the
+the configuration states, warm-up of every step width the mix drives), the
 measured window, then the comparison with the plain reference that
 decides ``correct``. The last line of stdout is one JSON object; the
 numbers compared, each beside its limit, are also the last lines of
@@ -40,13 +42,17 @@ ROOT = os.path.dirname(BENCH_DIR)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import model, program, xtrace  # noqa: E402
+from bench import program, xtrace  # noqa: E402
 from bench.traffic import Mix  # noqa: E402
 
 # Seconds past the window's close that an open loop waits for the answers
 # still due before it counts them as missing.
 DRAIN_LIMIT_S = 60.0
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+# Keys a configuration's ``graph`` may hold. The replica count is a fact
+# of the deployment; every other executor setting is the program's own
+# to choose, so a configuration does not tune it.
+GRAPH_KEYS = ("data_parallel",)
 
 
 def log(msg: str) -> None:
@@ -71,6 +77,7 @@ def load_cell(root: str, name: str) -> dict:
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     with open(os.path.join(root, conf["file"])) as f:
         config = json.load(f)
+    check_config(root, config)
     metrics = {"end_to_end": [], "per_layer": []}
     for kind in metrics:
         for m in spec[kind]:
@@ -80,6 +87,40 @@ def load_cell(root: str, name: str) -> dict:
             "traffic": os.path.join(root, "bench", "traffic",
                                     cell["traffic"] + ".json"),
             "root": root}
+
+
+def check_config(root: str, config: dict) -> None:
+    """Refuse a configuration whose reference is missing or whose
+    ``graph`` holds a key other than ``GRAPH_KEYS``."""
+    name = config.get("name", "?")
+    ref = config.get("reference")
+    if not ref:
+        raise SystemExit(f"configuration {name!r} names no reference")
+    if not os.path.isfile(os.path.join(root, ref)):
+        raise SystemExit(f"configuration {name!r} names reference {ref!r}, "
+                         "which is not a file of the checkout")
+    extra = sorted(set(config.get("graph", {})) - set(GRAPH_KEYS))
+    if extra:
+        raise SystemExit(f"configuration {name!r}: graph keys {extra} are "
+                         f"not the deployment's to state; only "
+                         f"{list(GRAPH_KEYS)}")
+
+
+_references: dict = {}
+
+
+def reference_module(root: str, config: dict):
+    """The plain reference the configuration names, imported once."""
+    path = os.path.join(root, config["reference"])
+    if path not in _references:
+        name = "bench_reference_" + "".join(
+            c if c.isalnum() else "_" for c in config["reference"])
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+        _references[path] = mod
+    return _references[path]
 
 
 def load_reader(root: str, metric: str):
@@ -133,10 +174,12 @@ class Step:
 class Window:
     """What a run measured, handed to every per-layer reader."""
 
-    net: model.Net
+    layers: list                          # the reference's layers(net)
+    flops_per_image: int                  # the reference's forward_flops
     slots: int
     seconds: float
     steps: list[Step]
+    chips: int = 1                        # chips the cell uses
     window_s: float = 0.0
     images: int = 0                       # images served in the window
     cache: tuple[int, int] = (0, 0)       # schedule-cache hits, lookups
@@ -144,10 +187,6 @@ class Window:
     trace: xtrace.Trace | None = None
     trace_bounds: tuple[int, int] | None = None   # window, profiler ns
     peak: dict = dataclasses.field(default_factory=dict)
-
-    @property
-    def layers(self) -> list[model.Layer]:
-        return model.layers(self.net)
 
     def device_ops(self) -> list[xtrace.Event]:
         if self.trace is None or not self.trace.device_ops:
@@ -253,13 +292,14 @@ def rel_err(y: np.ndarray, ref: np.ndarray) -> float:
     return float(np.abs(y - ref).max() / np.abs(ref).max())
 
 
-def compare(net, params, mix: Mix, answers, precision: str) -> float:
+def compare(ref_mod, net, params, mix: Mix, answers,
+            precision: str) -> float:
     """Largest relative error of the served logits against the plain
     reference, one image at a time."""
     import jax.numpy as jnp
     worst = 0.0
     for img, y in answers:
-        ref = np.asarray(model.reference(
+        ref = np.asarray(ref_mod.reference(
             net, params, jnp.asarray(mix.image(img)[None]), precision))[0]
         worst = max(worst, rel_err(np.asarray(y), ref))
     return worst
@@ -287,13 +327,15 @@ def start_trace(log_dir: str):
 class Served:
     """One run's window, before the comparison."""
 
-    net: model.Net
+    ref: object                           # the reference module
+    net: object                           # its Net
     params: dict
     mix: Mix
     answers: list                         # (image id, served logits)
     attempted: int
     missing: int
     failed: int
+    replicas: list[int]                   # images each replica served
     metrics: dict
     device: dict
     breakdown: dict | None
@@ -304,22 +346,24 @@ def serve(found: dict, seed: int, seconds: float, trace: bool,
     """Set-up and the measured window of one run."""
     import jax
     cell, config = found["cell"], found["config"]
-    net = model.Net.from_config(config)
+    ref = reference_module(found["root"], config)
+    net = ref.Net.from_config(config)
     slots = int(config["slots"])
     with open(os.path.join(found["root"], "bench", "peaks.json")) as f:
         peak = json.load(f).get(device["kind"], {})
 
-    params, offsets_px = model.build_weights(net, seed)
+    params, offsets_px = ref.build_weights(net, seed)
     jax.block_until_ready(params)
     log(f"weights built at {time.perf_counter() - PROCESS_START:.2f} s; "
         f"mean |offset| px {np.round(np.asarray(offsets_px), 3).tolist()}")
     mix = Mix.from_file(found["traffic"], seed,
                         (net.img_size, net.img_size, net.in_channels))
-    engine = program.make_engine(net, params, slots, traced=trace)
+    engine = program.make_engine(config, params, traced=trace)
     warm_up(engine, mix, slots)
     log(f"warm-up done at {time.perf_counter() - PROCESS_START:.2f} s")
     failed0 = program.failures(engine)
     hits0, lookups0 = program.cache_counts(engine)
+    replicas0 = program.replica_images(engine)
     if engine.tracer.enabled:
         engine.tracer.clear()
     steps: list[Step] = []
@@ -360,7 +404,12 @@ def serve(found: dict, seed: int, seconds: float, trace: bool,
     metrics["setup_s"] = setup_s
     failed = program.failures(engine) - failed0 + missing
     hits, lookups = program.cache_counts(engine)
-    window = Window(net=net, slots=slots, seconds=seconds, steps=steps,
+    replicas = [b - a for a, b in zip(replicas0,
+                                      program.replica_images(engine))]
+    log(f"images served by each data replica: {replicas}")
+    window = Window(layers=ref.layers(net),
+                    flops_per_image=ref.forward_flops(net), slots=slots,
+                    seconds=seconds, steps=steps, chips=int(cell["chips"]),
                     window_s=t1 - t0, images=sum(s.width for s in steps),
                     cache=(hits - hits0, lookups - lookups0),
                     spans=program.spans(engine), peak=peak)
@@ -380,8 +429,8 @@ def serve(found: dict, seed: int, seconds: float, trace: bool,
             if v is not None:
                 metrics[name] = v
 
-    return Served(net, params, mix, answers, attempted, missing, failed,
-                  metrics, out_device, breakdown)
+    return Served(ref, net, params, mix, answers, attempted, missing,
+                  failed, replicas, metrics, out_device, breakdown)
 
 
 def run_cell(found: dict, seed: int, seconds: float, trace: bool,
@@ -391,13 +440,21 @@ def run_cell(found: dict, seed: int, seconds: float, trace: bool,
     s = serve(found, seed, seconds, trace, device)
     config = found["config"]
     limit = float(config["correct"]["max_rel_err"])
-    worst = compare(s.net, s.params, s.mix, s.answers, config["precision"])
+    t = time.perf_counter()
+    worst = compare(s.ref, s.net, s.params, s.mix, s.answers,
+                    config["precision"])
+    log(f"compared {len(s.answers)} answers in "
+        f"{time.perf_counter() - t:.2f} s")
     checks = {"max_rel_err": {"value": worst, "limit": limit},
               "answers_missing": {"value": s.missing, "limit": 0}}
+    if len(s.replicas) > 1:
+        # A replica that served nothing leaves the deployment's replicas.
+        checks["replicas_idle"] = {
+            "value": sum(1 for n in s.replicas if n == 0), "limit": 0}
     units = {m["name"]: m["unit"] for kind in found["metrics"].values()
              for m in kind}
     result = {
-        "correct": bool(worst <= limit and s.missing == 0),
+        "correct": all(c["value"] <= c["limit"] for c in checks.values()),
         "attempted": int(s.attempted),
         "failed": int(s.failed),
         "metrics": {k: {"value": float(v), "unit": units[k]}

@@ -23,7 +23,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from bench import model, program, run  # noqa: E402
+from bench import program, run  # noqa: E402
 from bench.traffic import Mix  # noqa: E402
 
 
@@ -44,12 +44,13 @@ def main(argv=None) -> int:
     with open(os.path.join(run.BENCH_DIR, "peaks.json")) as f:
         run.device_check(int(found["cell"]["chips"]), json.load(f))
     config = found["config"]
-    net = model.Net.from_config(config)
+    ref = run.reference_module(found["root"], config)
+    net = ref.Net.from_config(config)
     slots = int(config["slots"])
     with open(found["traffic"]) as f:
         spec = json.load(f)
-    params, _ = model.build_weights(net, args.seed)
-    engine = program.make_engine(net, params, slots, traced=False)
+    params, _ = ref.build_weights(net, args.seed)
+    engine = program.make_engine(config, params, traced=False)
     shape = (net.img_size, net.img_size, net.in_channels)
     run.warm_up(engine, Mix(spec, args.seed, shape), slots)
     for k, rate in enumerate(float(r) for r in args.rates.split(",")):

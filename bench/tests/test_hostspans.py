@@ -28,7 +28,9 @@ def load_metric(name):
 
 
 def window(bounds=None, spans=(), images=4):
-    w = run.Window(net=model.Net("vgg19", 8, "dcn2", 32, 10), slots=4,
+    net = model.Net("vgg19", 8, "dcn2", 32, 10)
+    w = run.Window(layers=model.layers(net),
+                   flops_per_image=model.forward_flops(net), slots=4,
                    seconds=1.0, steps=[run.Step(0.0, 1.0, images)],
                    window_s=1.0, images=images, spans=list(spans))
     w.trace_bounds = bounds

@@ -36,12 +36,13 @@ def test_label_at_takes_innermost_open_span():
     assert xtrace.label_at(notes, 200) == "outside"
 
 
-def window_with(events, steps, net=None):
+def window_with(events, steps, net=None, chips=1, planes=None):
     net = net or model.Net("segnet", 8, "dcn2", 224, 11)
-    w = run.Window(net=net, slots=8, seconds=1.0,
-                   steps=[run.Step(0.0, 1.0, n) for n in steps],
-                   window_s=1.0, images=sum(steps), peak=PEAK)
-    w.trace = xtrace.Trace({"/device:TPU:0": events}, [])
+    w = run.Window(layers=model.layers(net),
+                   flops_per_image=model.forward_flops(net), slots=8,
+                   seconds=1.0, steps=[run.Step(0.0, 1.0, n) for n in steps],
+                   chips=chips, window_s=1.0, images=sum(steps), peak=PEAK)
+    w.trace = xtrace.Trace(planes or {"/device:TPU:0": events}, [])
     w.trace_bounds = (0, 10**12)
     return w
 
@@ -75,11 +76,29 @@ def test_idle_share_by_hand():
     assert load_metric("device_idle_frac")(w) == pytest.approx(0.75)
 
 
+def test_replica_idle_frac_max_reads_the_idler_plane():
+    # Plane 0 busy 600 of 1000 ns, plane 1 busy 100: idle 0.4 and 0.9.
+    planes = {"/device:TPU:0": [E("a", 0, 400), E("b", 500, 200)],
+              "/device:TPU:1": [E("c", 100, 60), E("d", 150, 50)]}
+    w = window_with([], [8], chips=2, planes=planes)
+    w.trace_bounds = (0, 1000)
+    assert load_metric("replica_idle_frac_max")(w) == pytest.approx(0.9)
+    assert load_metric("device_idle_frac")(w) == pytest.approx(0.65)
+
+
+def test_mfu_is_a_share_of_the_cells_chips():
+    one = load_metric("mfu")(window_with([], [8]))
+    assert one == 100.0 * 8 * model.forward_flops(
+        model.Net("segnet", 8, "dcn2", 224, 11)) / 197e12
+    four = load_metric("mfu")(window_with([], [8], chips=4))
+    assert four == one / 4
+
+
 def test_readers_without_a_trace_return_nothing():
     w = window_with([], [8])
     w.trace, w.trace_bounds = None, None
     for name in ("device_idle_frac", "dcn_fused_batch_roofline",
-                 "dcn_fused_batch_ms_per_image"):
+                 "dcn_fused_batch_ms_per_image", "replica_idle_frac_max"):
         assert load_metric(name)(w) is None
 
 
